@@ -569,7 +569,10 @@ class _Handler(BaseHTTPRequestHandler):
             self._send_json(200, scanner.report(max_age_s=0 if refresh else None))
             return 200
         if path == "/status/device":
-            # device data-movement plane (util/pageheat + devicetiming):
+            # the resolved backend (util/backend: platform, device kind
+            # and count, per-device memory, codec + compile-cache state)
+            # under `backend`, then the device data-movement plane
+            # (util/pageheat + devicetiming):
             # per-kernel transfer bytes, the (block, column) page-heat
             # hot set with transfer amplification, and the ghost-LRU
             # what-if curve — "pinning the top N MB of compressed pages
@@ -594,8 +597,11 @@ class _Handler(BaseHTTPRequestHandler):
                 top = int(qs.get("top", ["50"])[0])
             except ValueError as e:
                 raise BadRequest(f"bad top: {e}") from e
-            self._send_json(200, pageheat.device_report(
-                budgets_bytes=budgets, top=top))
+            from tempo_tpu.util import backend
+
+            doc = pageheat.device_report(budgets_bytes=budgets, top=top)
+            doc["backend"] = backend.describe()
+            self._send_json(200, doc)
             return 200
         if path == "/status/standing":
             # operator view of the standing-query engine: registration

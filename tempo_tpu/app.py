@@ -41,7 +41,7 @@ from tempo_tpu.util import devicetiming  # noqa: F401 — registers the
 # device-dispatch histograms so /metrics exposes them from boot, not
 # from the first dispatch
 from tempo_tpu.standing import StandingConfig, StandingEngine
-from tempo_tpu.util import resource, slo, tracing
+from tempo_tpu.util import backend, resource, slo, tracing
 from tempo_tpu.vulture import VultureConfig
 
 log = logging.getLogger(__name__)
@@ -139,6 +139,16 @@ class App:
         from tempo_tpu.util.xla_cache import ensure_persistent_cache
 
         ensure_persistent_cache()  # daemon startup: arm the compile cache
+        # resolve the backend ONCE and say what every device gate will
+        # see: a process that found no chip must not be a silent one
+        b = backend.describe()
+        log.info(
+            "backend: platform=%s device_kind=%s device_count=%d pallas=%s "
+            "native_codec=%s default_codec=%s compile_cache_dir=%s",
+            b["platform"], b["device_kind"], b["device_count"], b["pallas"],
+            b["native_codec"], b["default_codec"],
+            b["compile_cache_dir"] or "<off>",
+        )
         self.cfg = cfg
         # (re)apply the overload budgets to the process-wide governor —
         # pools persist across App rebuilds (modules hold references),

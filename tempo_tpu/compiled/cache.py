@@ -45,6 +45,12 @@ compiled_compiles_total = metrics.counter(
     "jitted for the first time. Steady-state repeated-shape traffic "
     "holds this flat while hits climb — that flatness IS the tier",
 )
+compiled_errors_total = metrics.counter(
+    "tempo_tpu_compiled_errors_total",
+    "Compiled-tier executions that raised and were absorbed into the "
+    "interpreter fallback (answers stay bit-identical; a nonzero value "
+    "means a fused program does not run on this backend)",
+)
 compiled_evictions_total = metrics.counter(
     "tempo_tpu_compiled_evictions_total",
     "Compiled-tier evictions (shape entries + cached programs), from "

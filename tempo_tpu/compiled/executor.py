@@ -418,6 +418,7 @@ def try_query_range(db, tenant, plan, metas):
     try:
         wires = run_query_range(db, tenant, [plan], [lowered], metas)
     except Exception:
+        cache_mod.compiled_errors_total.inc()
         log.exception("compiled metrics failed; interpreter fallback")
         return None
     wires[0]["compiledShape"] = "hit" if hit else "miss"
@@ -451,6 +452,7 @@ def try_query_range_many(db, tenant, plans, metas):
                 db, tenant,
                 [m[1] for m in members], [m[2] for m in members], metas)
         except Exception:
+            cache_mod.compiled_errors_total.inc()
             log.exception("compiled metrics batch failed; fallback")
             continue
         for (i, _p, _lw, hit), wire in zip(members, wires):

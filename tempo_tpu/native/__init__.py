@@ -1,7 +1,9 @@
 """ctypes bindings for the native C++ codec library.
 
 The library is compiled on demand with g++ (cached next to the source,
-keyed by source hash) and loaded via ctypes — no pybind11 in this image.
+keyed by source hash AND build host: -march=native output only runs on
+the CPU it was built for, so a binary copied in from another machine is
+never loaded) and loaded via ctypes — no pybind11 in this image.
 All entry points hold no Python state and release the GIL for the
 duration of the C call (ctypes does this for us), so page encode/decode
 and k-way merge planning run concurrently with device work.
@@ -14,11 +16,15 @@ from __future__ import annotations
 
 import ctypes
 import hashlib
+import logging
 import os
+import platform
 import subprocess
 import threading
 
 import numpy as np
+
+log = logging.getLogger(__name__)
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "codec.cc")
@@ -41,10 +47,26 @@ def _check(r: int) -> int:
     return r
 
 
+def _host_tag() -> str:
+    """Identity of the machine a -march=native build is valid on: the
+    architecture plus the CPU feature flags the compiler targets."""
+    flags = ""
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    flags = line.split(":", 1)[1].strip()
+                    break
+    except OSError:
+        pass
+    return hashlib.sha256(f"{platform.machine()}|{flags}".encode()).hexdigest()[:8]
+
+
 def _build() -> str | None:
     with open(_SRC, "rb") as f:
         tag = hashlib.sha256(f.read()).hexdigest()[:16]
-    so = os.path.join(_DIR, f"_codec_{tag}.so")
+    host = _host_tag()
+    so = os.path.join(_DIR, f"_codec_{tag}_{host}.so")
     if os.path.exists(so):
         return so
     tmp = f"{so}.{os.getpid()}.tmp"  # pid-suffixed: concurrent first-use
@@ -53,19 +75,28 @@ def _build() -> str | None:
             _SRC, "-o", tmp, "-lz"]
     # images without the libzstd dev symlink still carry the runtime;
     # -l:libzstd.so.1 links it directly (codec.cc declares the ABI)
+    err: Exception | None = None
     for zstd_flag in ("-lzstd", "-l:libzstd.so.1"):
         try:
             subprocess.run(base + [zstd_flag], check=True,
                            capture_output=True, timeout=120)
             break
-        except Exception:
-            continue
+        except (OSError, subprocess.SubprocessError) as e:
+            err = e
     else:
-        return so if os.path.exists(so) else None  # a sibling may have won
+        if os.path.exists(so):  # a sibling may have won
+            return so
+        detail = getattr(err, "stderr", b"") or b""
+        log.warning("native codec build failed (%s %s): the default page "
+                    "codec degrades from zstd_shuffle to zlib", err,
+                    detail.decode("utf-8", "replace")[-500:])
+        return None
     os.replace(tmp, so)
-    # drop stale builds
+    # drop this host's stale builds (another host's stay: a shared
+    # checkout must not make two machines evict each other's binary)
     for f in os.listdir(_DIR):
-        if f.startswith("_codec_") and f.endswith(".so") and f != os.path.basename(so):
+        if (f.startswith("_codec_") and f.endswith(f"_{host}.so")
+                and f != os.path.basename(so)):
             try:
                 os.unlink(os.path.join(_DIR, f))
             except OSError:

@@ -8,6 +8,9 @@ One mesh, up to two axes:
 
 Mirrors how the reference splits work: windows are independent jobs
 (P5), ranges within a job share merge state.
+
+Every shard_map over this mesh passes check_vma=False: psum outputs are
+intentionally per-window, not fully replicated.
 """
 
 from __future__ import annotations
@@ -17,26 +20,6 @@ from jax.sharding import Mesh
 
 RANGE_AXIS = "range"
 WINDOW_AXIS = "window"
-
-
-def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """shard_map across jax versions: the replication-check kwarg was
-    renamed check_rep -> check_vma, and disabling it is required here
-    (psum outputs are intentionally per-window, not fully replicated).
-    Try newest spelling first, fall back per TypeError."""
-    try:
-        sm = jax.shard_map
-    except AttributeError:  # pragma: no cover - old jax
-        from jax.experimental.shard_map import shard_map as sm
-    for kw in ({"check_vma": False}, {"check_rep": False}):
-        try:
-            return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-        except TypeError:
-            continue
-    # no bare-call fallback: constructing WITH the replication check
-    # enabled would only fail later, deep inside the first jit trace —
-    # fail loudly here instead if jax renames the kwarg again
-    raise TypeError("no compatible shard_map signature found")
 
 
 def mesh_shape_for(n_devices: int) -> tuple[int, int]:

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from tempo_tpu.ops import bloom
-from tempo_tpu.parallel.mesh import RANGE_AXIS, WINDOW_AXIS, shard_map_compat
+from tempo_tpu.parallel.mesh import RANGE_AXIS, WINDOW_AXIS
 from tempo_tpu.util import metrics
 from tempo_tpu.util.devicetiming import timed_dispatch
 
@@ -88,11 +88,12 @@ def make_sharded_tag_scan(mesh, n_cols: int, max_codes: int = 64):
         return hit[None, None], total[None, None]
 
     return jax.jit(
-        shard_map_compat(
+        jax.shard_map(
             step,
             mesh=mesh,
             in_specs=(P(WINDOW_AXIS, RANGE_AXIS), P(), P(WINDOW_AXIS, RANGE_AXIS)),
             out_specs=(P(WINDOW_AXIS, RANGE_AXIS), P(WINDOW_AXIS)),
+            check_vma=False,
         )
     )
 
@@ -120,11 +121,12 @@ def make_sharded_bloom_test(mesh, p: bloom.BloomPlan):
         return local(words[0, 0], limbs)[None, None]
 
     return jax.jit(
-        shard_map_compat(
+        jax.shard_map(
             step,
             mesh=mesh,
             in_specs=(P(WINDOW_AXIS, RANGE_AXIS), P()),
             out_specs=P(WINDOW_AXIS, RANGE_AXIS),
+            check_vma=False,
         )
     )
 
@@ -162,11 +164,12 @@ def make_sharded_rle_scan(mesh, n_cols: int, max_codes: int, n_pad: int):
 
     spec = P(WINDOW_AXIS, RANGE_AXIS)
     return jax.jit(
-        shard_map_compat(
+        jax.shard_map(
             step,
             mesh=mesh,
             in_specs=(spec, spec, spec, spec),
             out_specs=(spec, P(WINDOW_AXIS)),
+            check_vma=False,
         )
     )
 
@@ -209,11 +212,12 @@ def make_sharded_batched_rle_scan(mesh, n_cols: int, max_codes: int,
 
     spec = P(WINDOW_AXIS, RANGE_AXIS)
     return jax.jit(
-        shard_map_compat(
+        jax.shard_map(
             step,
             mesh=mesh,
             in_specs=(spec, spec, spec, spec, spec),
             out_specs=(spec, P(WINDOW_AXIS)),
+            check_vma=False,
         )
     )
 
@@ -244,11 +248,12 @@ def make_sharded_tag_scan_per_shard(mesh, n_cols: int, max_codes: int = 64):
 
     spec = P(WINDOW_AXIS, RANGE_AXIS)
     return jax.jit(
-        shard_map_compat(
+        jax.shard_map(
             step,
             mesh=mesh,
             in_specs=(spec, spec, spec),
             out_specs=(spec, P(WINDOW_AXIS)),
+            check_vma=False,
         )
     )
 

@@ -3,13 +3,15 @@ JSON no matter how the run dies (round-4 lesson: a fast backend-init
 UNAVAILABLE escaped both the watchdog and the JSON error path and the
 round shipped `parsed: null`).
 
-Covers: probe fallback decisions, the failure artifact on a mid-run
-crash, and partial per-arm times surviving into the artifact.
+Covers: the no-fallback refusal (no TPU and no explicit
+JAX_PLATFORMS=cpu -> nonzero exit naming the platform found, never a
+per-chip value), the failure artifact on a mid-run crash, and partial
+per-arm times surviving into the artifact.
 """
 
+import importlib.util
 import json
 import os
-import subprocess
 import sys
 
 import pytest
@@ -18,49 +20,56 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 import bench  # noqa: E402
-from tempo_tpu.util import benchenv  # noqa: E402
 
 
-class _FakeProc:
-    def __init__(self, rc, stderr="", stdout=""):
-        self.returncode = rc
-        self.stderr = stderr
-        self.stdout = stdout
+def _last_json(out: str) -> dict:
+    return json.loads([l for l in out.splitlines() if l.strip()][-1])
 
 
-def test_probe_timeout_falls_back(monkeypatch):
+def test_refuses_a_non_tpu_platform_without_the_opt_in(monkeypatch, capsys):
+    """This process resolved to the CPU; with JAX_PLATFORMS unset that is
+    a missing chip, not a request: the run dies with the failure
+    artifact — platform named, value null — and no rep ever runs."""
     monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.delenv("TEMPO_TPU_FAULTS", raising=False)
+    monkeypatch.setattr(sys, "argv", ["bench.py"])
+    monkeypatch.setattr(bench, "build_inputs", lambda *a, **k: pytest.fail("a rep ran"))
+    with pytest.raises(SystemExit) as ei:
+        bench.main()
+    assert ei.value.code == 1
+    art = _last_json(capsys.readouterr().out)
+    assert art["value"] is None and art["vs_baseline"] is None
+    assert "NoAccelerator" in art["error"] and "'cpu', not 'tpu'" in art["error"]
 
-    def hang(*a, **k):
-        raise subprocess.TimeoutExpired(cmd=a[0], timeout=k.get("timeout"))
 
-    monkeypatch.setattr(benchenv.subprocess, "run", hang)
-    assert bench._probe_accelerator(0.1) is False
-
-
-def test_probe_init_failure_falls_back(monkeypatch):
+@pytest.mark.parametrize("rep", ["compiled", "ingest"])
+def test_standalone_reps_refuse_too(monkeypatch, capsys, rep):
     monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-    monkeypatch.setattr(
-        benchenv.subprocess, "run",
-        lambda *a, **k: _FakeProc(1, stderr="jax.errors.JaxRuntimeError: UNAVAILABLE"))
-    assert bench._probe_accelerator(0.1) is False
+    monkeypatch.setattr(sys, "argv", ["bench.py", rep])
+    with pytest.raises(SystemExit) as ei:
+        bench.main()
+    assert ei.value.code == 2
+    cap = capsys.readouterr()
+    assert "'cpu', not 'tpu'" in cap.err and not cap.out.strip()
 
 
-def test_probe_success(monkeypatch):
+def test_explicit_cpu_run_never_uses_the_per_chip_name():
+    assert bench._headline_metric("cpu") == (
+        "blocks_compacted_per_sec_cpu", "blocks/s (cpu)")
+    assert bench._headline_metric("tpu") == (
+        "blocks_compacted_per_sec_per_chip", "blocks/s/chip")
+
+
+def test_bench_suite_refuses_without_the_opt_in(monkeypatch, capsys):
+    spec = importlib.util.spec_from_file_location(
+        "bench_suite", os.path.join(REPO, "tools", "bench_suite.py"))
+    suite = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(suite)
     monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-    monkeypatch.setattr(benchenv.subprocess, "run",
-                        lambda *a, **k: _FakeProc(0, stdout="tpu\n"))
-    assert bench._probe_accelerator(0.1) is True
-
-
-def test_probe_skipped_when_cpu_pinned(monkeypatch):
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-
-    def explode(*a, **k):  # pragma: no cover - must not be called
-        raise AssertionError("probe subprocess spawned on a CPU-pinned run")
-
-    monkeypatch.setattr(benchenv.subprocess, "run", explode)
-    assert bench._probe_accelerator(0.1) is True
+    monkeypatch.setattr(sys, "argv", ["bench_suite.py", "ingest"])
+    assert suite.main() == 1
+    cap = capsys.readouterr()
+    assert "'cpu', not 'tpu'" in cap.err and not cap.out.strip()
 
 
 def test_self_tracing_guard_refuses(monkeypatch):
@@ -96,7 +105,11 @@ def test_midrun_crash_emits_artifact(monkeypatch, capsys):
     assert art["value"] is None
     assert art["vs_baseline"] is None
     assert "simulated UNAVAILABLE" in art["error"]
-    assert art["metric"] == "blocks_compacted_per_sec_per_chip"
+    # the crash came after the platform resolved: a CPU run's artifact
+    # carries its device tags and never the per-chip name
+    assert art["metric"] == "blocks_compacted_per_sec_cpu"
+    assert (art["platform"], art["device_kind"]) == ("cpu", "cpu")
+    assert art["device_count"] >= 1
 
 
 def test_partial_times_reach_artifact(monkeypatch, capsys):

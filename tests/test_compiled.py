@@ -206,6 +206,26 @@ class TestBitIdentity:
         assert d1 == d0  # nothing bound, nothing launched
 
 
+    def test_absorbed_errors_are_counted(self, corpus, fresh_cache,
+                                         monkeypatch):
+        """A fused program that raises still answers None (interpreter
+        fallback, unchanged) — and tempo_tpu_compiled_errors_total says
+        it happened, on the single and the batched entry."""
+        from tempo_tpu.compiled import executor
+
+        db, metas = corpus
+        plan = _plan("{ resource.service.name = `cart` } | rate()")
+
+        def boom(*a, **k):
+            raise RuntimeError("kernel does not compile here")
+
+        monkeypatch.setattr(executor, "run_query_range", boom)
+        e0 = cache_mod.compiled_errors_total.total()
+        assert compiled.try_query_range(db, "t", plan, metas) is None
+        assert compiled.try_query_range_many(db, "t", [plan], metas) == [None]
+        assert cache_mod.compiled_errors_total.total() == e0 + 2
+
+
 # ---------------------------------------------------------------------------
 # 2. shard invariance: partition + merge == one shot
 # ---------------------------------------------------------------------------

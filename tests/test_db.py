@@ -138,7 +138,7 @@ class TestCompactionEngine:
     def test_slow_compaction_job_warns(self, tmp_path, caplog, monkeypatch):
         """A job outliving slow_job_warn_s logs loudly and bumps the
         counter — the only defense against an uncancellable wedged
-        device call (PERF.md tunnel pathology). The job is made
+        device call. The job is made
         deterministically slow so the timer always fires first."""
         import logging
         import time as _time

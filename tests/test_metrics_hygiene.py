@@ -219,6 +219,7 @@ FAMILY_SERIES_BUDGETS = {
     "tempo_tpu_compiled_hits_total": 2,
     "tempo_tpu_compiled_misses_total": 2,
     "tempo_tpu_compiled_compiles_total": 2,
+    "tempo_tpu_compiled_errors_total": 2,
     "tempo_tpu_compiled_evictions_total": 2,
     # trace-graph analytics plane: label-less totals + a small kind enum
     # (dependencies | critical_path | walks) — edges/services must NEVER

@@ -65,3 +65,47 @@ class TestU64RangeScan:
         v = np.full(100, 5, np.uint64)
         got = np.asarray(pk.u64_range_scan(v, 0, 10, 1024))
         assert got[:100].all() and not got[100:].any()
+
+
+class TestBincountKernel:
+    """seg_bincount only takes the Pallas road on a TPU, so tier-1 never
+    ran `_bincount_kernel` at all: interpret it here, against
+    np.bincount, at the narrowest, a middle and the widest slot vector
+    the kernel is allowed (`_BC_MAX_SLOTS`). Weights above 256 are the
+    case a bf16-rounded MXU pass got wrong on the chip."""
+
+    @pytest.mark.parametrize("n_slots", [128, 4096, 32768])
+    @pytest.mark.parametrize("weighted", [False, True])
+    def test_matches_np_bincount(self, n_slots, weighted):
+        import jax.numpy as jnp
+
+        assert n_slots <= pk._BC_MAX_SLOTS
+        rng = np.random.default_rng(n_slots + weighted)
+        n = 4 * pk._BC_ROWS
+        slots = rng.integers(-2, n_slots, n).astype(np.int32)  # <0 = dropped
+        w = (rng.integers(1, 100_000, n) if weighted else np.ones(n)).astype(np.int32)
+        got = np.asarray(pk._bincount_call(
+            jnp.asarray(slots), jnp.asarray(w), n_slots, interpret=True))
+        live = slots >= 0
+        want = np.bincount(slots[live], weights=w[live], minlength=n_slots)
+        np.testing.assert_array_equal(got.astype(np.int64), want.astype(np.int64))
+
+    def test_device_road_pads_rows_to_pow2(self, monkeypatch):
+        """seg_bincount's accelerator branches pad the row count to a
+        power of two (pad rows are dropped slots), so stream lengths
+        that follow an ingest cut share one executable. Driven through
+        the XLA scatter branch, the one a non-TPU accelerator takes."""
+        monkeypatch.setattr(pk.backend, "platform", lambda: "gpu")
+        rng = np.random.default_rng(7)
+        before = pk._bincount_xla._cache_size()
+        for n in (300, 401, 512):  # all land on 512 rows
+            slots = rng.integers(-1, 200, n).astype(np.int32)
+            w = rng.integers(1, 5000, n).astype(np.int32)
+            got = pk.seg_bincount(slots, 200, weights=w)
+            live = slots >= 0
+            want = np.bincount(slots[live], weights=w[live], minlength=200)
+            np.testing.assert_array_equal(got, want.astype(np.int64))
+            got = pk.seg_bincount(slots, 200)
+            np.testing.assert_array_equal(
+                got, np.bincount(slots[live], minlength=200))
+        assert pk._bincount_xla._cache_size() == before + 1

@@ -341,6 +341,11 @@ class TestStatusDeviceEndpoint:
         # repeated queries => a residency budget saves transfer bytes
         assert curve[-1]["savedBytes"] > 0
         assert "transfer" in doc and "byKernel" in doc["transfer"]
+        # the resolved backend rides the same document (what the off-JAX
+        # chip smoke reads to learn the child's device)
+        be = doc["backend"]
+        assert be["platform"] == "cpu" and be["pallas"] == "interpret"
+        assert be["device_count"] == len(be["devices"]) >= 1
 
     def test_explicit_budgets_param(self, driven):
         _app, server, _tmp = driven
