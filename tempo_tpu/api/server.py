@@ -45,7 +45,7 @@ from tempo_tpu.modules.distributor import RateLimited
 from tempo_tpu.modules.ingester import MaxLiveTraces, TraceTooLarge
 from tempo_tpu.modules.queue import TooManyRequests
 from tempo_tpu.receivers import otlp
-from tempo_tpu.util import metrics, tracing
+from tempo_tpu.util import metrics, profiling, stagetimings, tracing
 from tempo_tpu.util.resource import ResourceExhausted
 
 VERSION = "0.1.0"
@@ -194,10 +194,14 @@ class _Handler(BaseHTTPRequestHandler):
         otelhttp middleware) and open one server span per request, so an
         instrumented client's push/query and our internal RPC hops land
         in one coherent trace."""
-        if (not tracing.TRACER.enabled or route in self._UNTRACED
-                or url.path.startswith("/kv/")):
+        if ((not tracing.TRACER.enabled and not profiling.capturing)
+                or route in self._UNTRACED or url.path.startswith("/kv/")):
             return self._handle(method, url)
-        with tracing.remote_context(self.headers.get(tracing.TRACEPARENT_HEADER)):
+        # while a device profiler capture runs the same root is an
+        # interval in its trace, and gives the request's annotations
+        # their shared `req` id
+        with tracing.remote_context(self.headers.get(tracing.TRACEPARENT_HEADER)), \
+                profiling.request_scope():
             with tracing.span(f"http/{method} {route}", route=route) as s:
                 code = self._handle(method, url)
                 if s is not None:
@@ -245,6 +249,47 @@ class _Handler(BaseHTTPRequestHandler):
         finally:
             _req_count.inc(method=method, route=route, status_code=str(code))
             _req_hist.observe(time.monotonic() - start, method=method, route=route)
+
+    def _ingest(self, path: str) -> int:
+        app = self.app
+        ct = self.headers.get("Content-Type", "")
+        body = self._body()
+        # columnar fast path: OTLP decodes straight into a SpanBatch
+        # and skips the object-trace detour entirely. Gated off when
+        # a forwarder tee needs object traces; non-OTLP protocols
+        # return None and take the object path below.
+        batch = None
+        try:
+            with tracing.span("receiver/decode", bytes=len(body)), \
+                    stagetimings.stage("decode"):
+                if getattr(app, "can_push_spans", None) and app.can_push_spans():
+                    batch = receivers.decode_http_columnar(path, ct, body)
+                if batch is None:
+                    traces = receivers.decode_http(path, ct, body)
+        except (ValueError, OSError, TypeError, AttributeError, KeyError) as e:
+            # wire/thrift/json decode errors and shape-invalid JSON
+            raise BadRequest(f"malformed payload: {e}") from e
+        try:
+            if batch is not None:
+                if batch.num_spans:
+                    app.push_spans(batch, org_id=self._org_id())
+            elif traces:
+                app.push_traces(traces, org_id=self._org_id())
+        except ValueError as e:
+            # distributor admission contract: ValueError = the
+            # request can never be admitted (e.g. one batch over
+            # the whole inflight budget) — client error, not 500
+            raise BadRequest(str(e)) from e
+        if path == receivers.OTLP_HTTP_PATH:
+            # OTLP/HTTP: response content type must match the request;
+            # empty ExportTraceServiceResponse = empty proto message
+            if "json" in ct:
+                self._send(200, b"{}")
+            else:
+                self._send(200, b"", "application/x-protobuf")
+            return 200
+        self._send(202, b"")
+        return 202
 
     def _handle(self, method: str, url) -> int:
         path = url.path.rstrip("/") or "/"
@@ -307,42 +352,11 @@ class _Handler(BaseHTTPRequestHandler):
             receivers.ZIPKIN_V1_PATH,
             receivers.JAEGER_THRIFT_PATH,
         ):
-            ct = self.headers.get("Content-Type", "")
-            body = self._body()
-            # columnar fast path: OTLP decodes straight into a SpanBatch
-            # and skips the object-trace detour entirely. Gated off when
-            # a forwarder tee needs object traces; non-OTLP protocols
-            # return None and take the object path below.
-            batch = None
-            try:
-                if getattr(app, "can_push_spans", None) and app.can_push_spans():
-                    batch = receivers.decode_http_columnar(path, ct, body)
-                if batch is None:
-                    traces = receivers.decode_http(path, ct, body)
-            except (ValueError, OSError, TypeError, AttributeError, KeyError) as e:
-                # wire/thrift/json decode errors and shape-invalid JSON
-                raise BadRequest(f"malformed payload: {e}") from e
-            try:
-                if batch is not None:
-                    if batch.num_spans:
-                        app.push_spans(batch, org_id=self._org_id())
-                elif traces:
-                    app.push_traces(traces, org_id=self._org_id())
-            except ValueError as e:
-                # distributor admission contract: ValueError = the
-                # request can never be admitted (e.g. one batch over
-                # the whole inflight budget) — client error, not 500
-                raise BadRequest(str(e)) from e
-            if path == receivers.OTLP_HTTP_PATH:
-                # OTLP/HTTP: response content type must match the request;
-                # empty ExportTraceServiceResponse = empty proto message
-                if "json" in ct:
-                    self._send(200, b"{}")
-                else:
-                    self._send(200, b"", "application/x-protobuf")
-                return 200
-            self._send(202, b"")
-            return 202
+            # the push's waterfall (kind="push"): decode here, admission /
+            # fan_out / live below in the distributor and the ingester,
+            # what no stage claims (body read, reply) in `other`
+            with stagetimings.observed("push"):
+                return self._ingest(path)
 
         # standing queries (tempo_tpu/standing): registration +
         # incremental reads + alert state, tenant-scoped. Served by

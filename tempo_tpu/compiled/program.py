@@ -103,7 +103,8 @@ def build_metrics_program(sig):
 
         return jax.vmap(one_dbp)(words, first_hi, first_lo, width)
 
-    def prog(t_s, valid, payloads, qargs, tb, nb):
+    # the name is the program's in a profiler trace: layer, then work
+    def compiled_query_range_counts(t_s, valid, payloads, qargs, tb, nb):
         def per_query(qa, tb_q, nb_q):
             hit = valid
             for i, cs in enumerate(colsig):
@@ -120,4 +121,4 @@ def build_metrics_program(sig):
 
         return jax.vmap(per_query, in_axes=(0, 0, 0))(qargs, tb, nb)
 
-    return jax.jit(prog)
+    return jax.jit(compiled_query_range_counts)

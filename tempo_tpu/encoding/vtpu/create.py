@@ -58,12 +58,12 @@ def _accum_step(plan: "bloom.BloomPlan", hp: "sketch.HLLPlan"):
     partials compose exactly)."""
     import jax
 
-    def step(words, regs, ids, valid):
+    def block_sketch_accumulate(words, regs, ids, valid):
         words = words | bloom.build(ids, plan, valid=valid)
         regs = sketch.hll_update(regs, ids, hp, valid=valid)
         return words, regs
 
-    return jax.jit(step, donate_argnums=(0, 1))
+    return jax.jit(block_sketch_accumulate, donate_argnums=(0, 1))
 
 
 @lru_cache(maxsize=64)
@@ -71,12 +71,12 @@ def _accum_finish(hp: "sketch.HLLPlan"):
     import jax
 
     @jax.jit
-    def fin(words, regs):
+    def block_sketch_finish(words, regs):
         est = sketch.hll_estimate(regs, hp)
         est_bits = jax.lax.bitcast_convert_type(est.astype(jnp.float32), jnp.uint32)
         return jnp.concatenate([words.reshape(-1), est_bits[None]])
 
-    return fin
+    return block_sketch_finish
 
 
 class DeviceSketchAccumulator:
@@ -173,7 +173,7 @@ def _sketch_step(plan: "bloom.BloomPlan", hp: "sketch.HLLPlan"):
     import jax
 
     @jax.jit
-    def step(ids, valid):
+    def block_sketch_build(ids, valid):
         words = bloom.build(ids, plan, valid=valid)
         regs = sketch.hll_update(sketch.hll_init(hp), ids, hp, valid=valid)
         est = sketch.hll_estimate(regs, hp)
@@ -183,7 +183,7 @@ def _sketch_step(plan: "bloom.BloomPlan", hp: "sketch.HLLPlan"):
         est_bits = jax.lax.bitcast_convert_type(est.astype(jnp.float32), jnp.uint32)
         return jnp.concatenate([words.reshape(-1), est_bits[None]])
 
-    return step
+    return block_sketch_build
 
 
 class BlockWriter:

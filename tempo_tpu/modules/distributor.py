@@ -21,7 +21,7 @@ from tempo_tpu.encoding.vtpu import format as fmt
 from tempo_tpu.model.columnar import SpanBatch
 from tempo_tpu.model.trace import traces_to_batch
 from tempo_tpu.ops import hashing
-from tempo_tpu.util import metrics, resource, tracing, usage
+from tempo_tpu.util import metrics, resource, stagetimings, tracing, usage
 
 log = logging.getLogger(__name__)
 
@@ -164,6 +164,19 @@ class Distributor:
             self._push_batch_traced(tenant, batch)
 
     def _push_batch_traced(self, tenant: str, batch: SpanBatch) -> None:
+        with stagetimings.stage("admission"):
+            size, gate = self._admit(tenant, batch)
+        try:
+            inflight_push_gauge.set(gate.used)
+            with stagetimings.stage("fan_out"):
+                self._fan_out(tenant, batch, size)
+        finally:
+            gate.sub(size)
+            inflight_push_gauge.set(gate.used)
+
+    def _admit(self, tenant: str, batch: SpanBatch):
+        """The rate limit and the inflight gate; returns (size, gate) with
+        `size` bytes added to the gate."""
         size = batch.nbytes()
         lim = self._limiter(tenant)
         # note: a batch larger than the tenant burst also lands here with
@@ -202,12 +215,7 @@ class Distributor:
                 f"({gate.used}/{gate.limit}); slow down",
                 retry_after_s=self.governor.retry_after_s(),
             )
-        try:
-            inflight_push_gauge.set(gate.used)
-            self._fan_out(tenant, batch, size)
-        finally:
-            gate.sub(size)
-            inflight_push_gauge.set(gate.used)
+        return size, gate
 
     def _fan_out(self, tenant: str, batch: SpanBatch, size: int) -> None:
         self.metrics.spans_received[tenant] = (
@@ -238,7 +246,12 @@ class Distributor:
                 # DoBatch's per-instance spans, distributor.go:389)
                 with tracing.span("distributor/push_replica",
                                   instance=instance_id, spans=sub.num_spans):
-                    client.push_segment(tenant, fmt.serialize_batch(sub))
+                    segment = fmt.serialize_batch(sub)
+                    # the ingester's share of the push's waterfall (in-process
+                    # client: deserialize + live-trace insert; a remote one:
+                    # the RPC's wall)
+                    with stagetimings.stage("live"):
+                        client.push_segment(tenant, segment)
             except resource.ResourceExhausted as e:  # ingester refused: overload
                 shed_errs.append(e)
                 errs.append(f"{instance_id}: {e}")

@@ -24,7 +24,7 @@ from dataclasses import dataclass
 from tempo_tpu.encoding.common import SearchRequest, SearchResponse, TraceSearchMetadata
 from tempo_tpu.model.trace import combine_traces
 from tempo_tpu.modules.worker import JobBroker, decode_trace_result
-from tempo_tpu.util import insights, metrics, resource, stagetimings, tracing, usage
+from tempo_tpu.util import insights, metrics, profiling, resource, stagetimings, tracing, usage
 
 log = logging.getLogger(__name__)
 
@@ -238,9 +238,12 @@ class Frontend:
         # — every query path funnels through this submit
         insights.note(shards=len(descs), traceparent=tp)
         now_ts = time.time()
+        # (4) while a device profiler capture runs, the request's
+        # annotation id, so the worker thread's intervals carry it too
+        req = profiling.current_req()
         descs = [
             {**d, "deadline": deadline_ts, "submitted_at": now_ts,
-             **({"traceparent": tp} if tp else {})}
+             **({"traceparent": tp} if tp else {}), **({"req": req} if req else {})}
             for d in descs
         ]
         groups = []
@@ -258,7 +261,9 @@ class Frontend:
         results: list = []
         terminal_errors: list = []  # never retried, never lost
         for attempt in range(self.cfg.max_retries + 1):
-            self._wait_groups(tenant, groups, timeout_s=deadline_ts - time.time())
+            # the frontend thread blocks here while workers run its jobs
+            with profiling.annotation("frontend/wait"):
+                self._wait_groups(tenant, groups, timeout_s=deadline_ts - time.time())
             # classify each group exactly once — a job finishing between
             # two passes must land in exactly one bucket
             failed = []

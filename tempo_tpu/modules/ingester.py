@@ -206,7 +206,9 @@ class TenantInstance:
         wal_pool = self.governor.pool("wal_head")
         wal_pool.add(cut_bytes)
         try:
-            with self.lock:
+            # the WAL is appended here, at the cut; a push (ingester/append)
+            # only reaches the live traces
+            with self.lock, tracing.span("ingester/wal_append", spans=batch.num_spans):
                 self.head.append(batch)
                 self.head._gov_bytes = getattr(self.head, "_gov_bytes", 0) + cut_bytes
                 # WAL segment identity of this cut (block id + segment
@@ -282,12 +284,8 @@ class TenantInstance:
                 # flush waterfall: device page encodes inside record
                 # kernel/transfer (util/devicetiming); the host remainder
                 # (merge-sort, host codecs, backend PUT) lands in "other"
-                with stagetimings.request() as flush_st:
-                    t0 = time.perf_counter()
+                with stagetimings.observed("flush"):
                     meta = self.db.write_wal_block(self.tenant, blk, block_id=blk.block_id)
-                    flush_st.add("other", max(
-                        0.0, time.perf_counter() - t0 - flush_st.total()))
-                    flush_st.observe("flush")
         except BaseException:
             with self.lock:
                 self._inflight.discard(blk.block_id)

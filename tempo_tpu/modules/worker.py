@@ -35,7 +35,7 @@ import weakref
 
 from tempo_tpu.encoding.common import SearchRequest, SearchResponse
 from tempo_tpu.modules.queue import RequestQueue
-from tempo_tpu.util import deadline, metrics, stagetimings, tracing, usage
+from tempo_tpu.util import deadline, metrics, profiling, stagetimings, tracing, usage
 
 log = logging.getLogger(__name__)
 
@@ -84,7 +84,8 @@ def execute_job(querier, tenant: str, desc: dict) -> dict:
                 st.add("queue_wait", queue_wait)
             t0 = time.perf_counter()
             try:
-                with tracing.remote_context(desc.get("traceparent")):
+                with tracing.remote_context(desc.get("traceparent")), \
+                        profiling.request_scope(desc.get("req", 0)):
                     with tracing.span(f"worker/{desc.get('kind')}", tenant=tenant):
                         out = _execute_job(querier, tenant, desc)
             finally:

@@ -135,10 +135,10 @@ def _rle_kernel():
     import jax.numpy as jnp
 
     @jax.jit
-    def change_mask(a2):
+    def page_encode_rle_change_mask(a2):
         return (a2[1:] != a2[:-1]).any(axis=1)
 
-    return change_mask
+    return page_encode_rle_change_mask
 
 
 @functools.lru_cache(maxsize=None)
@@ -156,7 +156,7 @@ def _dbp_kernel(widths: tuple, item_bits: int):
     zero = jnp.uint32(0)
 
     @jax.jit
-    def enc(lo_p, hi_p):
+    def page_encode_dbp(lo_p, hi_p):
         outs = []
         for c, w in enumerate(widths):
             lo, hi = lo_p[c], hi_p[c]
@@ -184,7 +184,7 @@ def _dbp_kernel(widths: tuple, item_bits: int):
             outs.append(_pack_lanes(jnp, z, w))
         return tuple(outs)
 
-    return enc
+    return page_encode_dbp
 
 
 @functools.lru_cache(maxsize=None)
@@ -193,10 +193,10 @@ def _pack_kernel(w: int):
     import jax.numpy as jnp
 
     @jax.jit
-    def pack(idx):
+    def page_encode_dct_pack(idx):
         return _pack_lanes(jnp, idx, w)
 
-    return pack
+    return page_encode_dct_pack
 
 
 # ---------------------------------------------------------------------------

@@ -102,6 +102,7 @@ def _in_set_call(cols_mat: jnp.ndarray, codes_mat: jnp.ndarray, interpret: bool)
         out_specs=pl.BlockSpec((_SUBLANES, tile), lambda i: (0, i),
                                memory_space=pltpu.VMEM),
         interpret=interpret,
+        name="scan_in_set",
     )(codes_mat, cols_mat.reshape(C, _SUBLANES, n8))
     return out.reshape(N)
 
@@ -179,6 +180,7 @@ def _range_call(hi: jnp.ndarray, lo: jnp.ndarray, bounds: jnp.ndarray, interpret
         ],
         out_specs=pl.BlockSpec((_SUBLANES, tile), lambda i: (0, i), memory_space=pltpu.VMEM),
         interpret=interpret,
+        name="scan_range",
     )(bounds, hi.reshape(_SUBLANES, n8), lo.reshape(_SUBLANES, n8))
     return out.reshape(N)
 
@@ -247,6 +249,7 @@ def _bincount_call(slots: jnp.ndarray, weights: jnp.ndarray, n_slots_pad: int,
         out_specs=pl.BlockSpec((1, n_slots_pad), lambda i: (0, 0),
                                memory_space=pltpu.VMEM),
         interpret=interpret,
+        name="seg_bincount",
     )(slots.reshape(N, 1), weights.astype(jnp.float32).reshape(N, 1))
     return out.reshape(n_slots_pad)
 

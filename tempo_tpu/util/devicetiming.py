@@ -51,7 +51,7 @@ import time
 
 import numpy as np
 
-from tempo_tpu.util import metrics, stagetimings, usage
+from tempo_tpu.util import metrics, profiling, stagetimings, usage
 
 dispatch_hist = metrics.histogram(
     "tempo_tpu_device_dispatch_seconds",
@@ -176,8 +176,16 @@ def timed_dispatch(kernel: str, fn, *args, ship: bool = True, **kwargs):
     transfer clock stays at zero, so all time lands in `kernel` exactly
     as before the split.
 
+    While a profiler capture runs the same two intervals are written
+    into its trace: `dispatch/<kernel>` around the whole call and a child
+    `transfer/<kernel>` around the ship; the remainder is the kernel wait.
+
     Returns fn's result after block_until_ready. Timing failures never
     mask the dispatch's own result or error."""
+    ann = None
+    if profiling.capturing:
+        ann = profiling.annotation(f"dispatch/{kernel}")
+        ann.__enter__()
     t0 = time.perf_counter()
     transfer_s = 0.0
     h2d = d2h = resident = 0
@@ -202,12 +210,13 @@ def timed_dispatch(kernel: str, fn, *args, ship: bool = True, **kwargs):
                 return leaf
 
             t_ship = time.perf_counter()
-            args, kwargs = jax.tree_util.tree_map(put, (args, kwargs))
-            if shipped:
-                # the ship isn't paid for until it materializes; closing
-                # the clock here keeps transfer EXCLUSIVE of kernel
-                jax.block_until_ready(shipped)
-                transfer_s = time.perf_counter() - t_ship
+            with profiling.annotation(f"transfer/{kernel}") if ann else profiling.NULL_CONTEXT:
+                args, kwargs = jax.tree_util.tree_map(put, (args, kwargs))
+                if shipped:
+                    # the ship isn't paid for until it materializes; closing
+                    # the clock here keeps transfer EXCLUSIVE of kernel
+                    jax.block_until_ready(shipped)
+                    transfer_s = time.perf_counter() - t_ship
         out = fn(*args, **kwargs)
         # never raises for plain numpy/scalar/pytree results, so any
         # exception here is a REAL device failure (faulted kernel, OOM)
@@ -219,6 +228,8 @@ def timed_dispatch(kernel: str, fn, *args, ship: bool = True, **kwargs):
         return out
     finally:
         dt = time.perf_counter() - t0
+        if ann is not None:
+            ann.__exit__(None, None, None)
         dispatch_hist.observe(dt, kernel=kernel)
         dispatch_total.inc(kernel=kernel)
         # transfer + kernel PARTITION the dispatch wall: stage sums keep

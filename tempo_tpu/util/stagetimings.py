@@ -17,6 +17,8 @@ with one bucket per pipeline stage:
                  block_until_ready minus the transfer stage
                  (util/devicetiming.timed_dispatch)
   merge          frontend-side partial merging across shards
+  fan_out        a push's distributor work: group by replica, serialize
+  live           a push's ingester work: deserialize, live-trace insert
   other          worker execution time not attributed to any stage
 
 plus a device dispatch count. Stage contexts are EXCLUSIVE: a nested
@@ -37,7 +39,7 @@ import contextvars
 import threading
 import time
 
-from tempo_tpu.util import metrics
+from tempo_tpu.util import metrics, profiling
 
 STAGES = (
     "queue_wait",
@@ -48,6 +50,8 @@ STAGES = (
     "transfer",
     "kernel",
     "merge",
+    "fan_out",
+    "live",
     "other",
 )
 
@@ -141,14 +145,20 @@ def request(acc: StageTimings | None = None):
         _active.reset(token)
 
 
-# shared no-op context for calls outside any request: the hot read path
-# enters stages unconditionally, so the inactive case must cost one
-# contextvar read, not a fresh generator (nullcontext is reentrant)
-_NULL_STAGE = contextlib.nullcontext()
+@contextlib.contextmanager
+def observed(kind: str):
+    """A request whose stages sum to its wall: what no stage claimed
+    lands in `other`, and a request that ends without raising publishes
+    its waterfall under `kind`."""
+    with request() as acc:
+        t0 = time.perf_counter()
+        yield acc
+        acc.add("other", max(0.0, time.perf_counter() - t0 - acc.total()))
+        acc.observe(kind)
 
 
 class _Stage:
-    __slots__ = ("acc", "name", "parent", "cell", "token", "t0")
+    __slots__ = ("acc", "name", "parent", "cell", "token", "t0", "ann")
 
     def __init__(self, acc, name):
         self.acc = acc
@@ -158,11 +168,18 @@ class _Stage:
         self.parent = _open_stage.get()
         self.cell = [0.0]  # seconds consumed by OUR nested stages
         self.token = _open_stage.set((self.name, self.cell))
+        # the same interval on the profiler's clock while a capture runs
+        self.ann = None
+        if profiling.capturing:
+            self.ann = profiling.annotation(f"stage/{self.name}")
+            self.ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dt = time.perf_counter() - self.t0
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
         _open_stage.reset(self.token)
         self.acc.add(self.name, max(0.0, dt - self.cell[0]))
         if self.parent is not None:
@@ -171,12 +188,14 @@ class _Stage:
 
 
 def stage(name: str):
-    """Attribute the wrapped work to `name` on the active accumulator
-    (shared no-op when none is active). Nested stages subtract from
-    their parent so time is counted exactly once."""
+    """Attribute the wrapped work to `name` on the active accumulator.
+    Nested stages subtract from their parent so time is counted exactly
+    once. The hot read path enters stages unconditionally, so outside any
+    request this costs one contextvar read and the shared no-op context,
+    not a fresh generator."""
     acc = _active.get()
     if acc is None:
-        return _NULL_STAGE
+        return profiling.NULL_CONTEXT
     return _Stage(acc, name)
 
 

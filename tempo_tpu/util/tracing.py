@@ -30,6 +30,7 @@ import time
 from dataclasses import dataclass
 
 from tempo_tpu.model.trace import KIND_INTERNAL, STATUS_ERROR, STATUS_OK, Span, Trace
+from tempo_tpu.util import profiling
 
 _current_span: contextvars.ContextVar = contextvars.ContextVar("tempo_current_span", default=None)
 
@@ -111,11 +112,6 @@ def remote_context(header: str | None):
         _current_span.reset(token)
 
 
-# shared no-op context for the disabled tracer (reentrant + shareable;
-# __enter__ yields None like a disabled span)
-_NULL_CTX = contextlib.nullcontext()
-
-
 class Tracer:
     """Minimal in-process tracer. Spans finish into `exporter(span_list)`
     per trace root; a None exporter disables all recording at ~zero
@@ -154,10 +150,13 @@ class Tracer:
 
     def span(self, name: str, **attrs):
         # hot paths call this unconditionally: the disabled tracer must
-        # cost one attribute check + a shared null context, not a fresh
-        # generator per call
+        # cost one attribute check + a shared null context
+        # (profiling.NULL_CONTEXT: `as s` binds None), not a fresh
+        # generator per call. While a device profiler capture runs the
+        # span is also an interval in ITS trace, under the same name
+        # (exporter off: only that)
         if not self.enabled:
-            return _NULL_CTX
+            return profiling.annotation(name)
         return self._span_cm(name, attrs)
 
     @contextlib.contextmanager
@@ -176,7 +175,8 @@ class Tracer:
         )
         token = _current_span.set(s)
         try:
-            yield s
+            with profiling.annotation(name):
+                yield s
             s.status_code = STATUS_OK
         except BaseException as e:
             # the span must SAY what failed before it finishes: status

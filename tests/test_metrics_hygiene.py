@@ -139,14 +139,23 @@ FAMILY_SERIES_BUDGETS = {
     # method x route x status on the HTTP server
     "tempo_request_duration_seconds_total": 600,
     "tempo_request_duration_seconds": 200,
-    # stage x kind waterfall
-    "tempo_tpu_query_stage_seconds": 64,
+    # stage x kind waterfall (11 stages; kinds: the five query kinds,
+    # flush, standing, push — no kind records every stage)
+    "tempo_tpu_query_stage_seconds": 80,
     "tempo_tpu_query_device_dispatches_total": 8,
     # kernel-labelled device timing + the data-movement plane
     # (direction enum x kernel labels; kernels are code-literal strings)
     "tempo_tpu_device_dispatch_seconds": 32,
     "tempo_tpu_device_dispatches_total": 32,
     "tempo_tpu_device_transfer_bytes_total": 96,
+    # what device profiler captures found (util/profiling): kernels are
+    # code-literal strings, and `host` is an annotation's name: span
+    # names are code literals over kernel labels and ROUTE TEMPLATES
+    # (never a raw path, a tenant or a block id), `seam` its prefix
+    "tempo_tpu_profile_device_idle_seconds_total": 192,
+    "tempo_tpu_profile_dispatch_wall_seconds_total": 32,
+    "tempo_tpu_profile_dispatch_device_seconds_total": 32,
+    "tempo_tpu_profile_dispatches_total": 32,
     # page-heat ledger: label-less totals + a bounded budget-fraction
     # enum on the what-if gauges (block/column must NEVER become labels
     # here; per-page data belongs on /status/device)

@@ -657,3 +657,303 @@ class TestHTTPPropagation:
             client.close()
             srv.stop()
             app.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# the seams on the profiler's clock, and a capture that reduces itself
+# ---------------------------------------------------------------------------
+
+MS = 1e6  # the reducer's inputs are nanoseconds
+
+
+class TestReduceCapture:
+    """util/profiling.reduce_capture on synthetic intervals: no device,
+    no profiler."""
+
+    # one 100 ms window; thread 0 serves a request, thread 1 is its worker
+    ANNOTATIONS = [
+        (0, "http/GET /api/search", 10 * MS, 80 * MS),
+        (0, "frontend/wait", 20 * MS, 60 * MS),
+        (1, "worker/search_blocks", 25 * MS, 50 * MS),
+        (1, "stage/fetch", 30 * MS, 10 * MS),
+        (1, "dispatch/pallas_in_set", 45 * MS, 20 * MS),
+        (1, "transfer/pallas_in_set", 46 * MS, 4 * MS),
+    ]
+    PROGRAMS = [
+        ("jit__scan(123456789)", 52 * MS, 6 * MS),      # inside the dispatch
+        ("jit__scan(987654321)", 64 * MS, 3 * MS),      # 1 ms inside, 2 ms past its end
+        ("jit_block_sketch_build(42)", 95 * MS, 2 * MS),  # no dispatch near it
+    ]
+    OPS = [(52 * MS, 6 * MS), (64 * MS, 3 * MS), (95 * MS, 2 * MS)]
+
+    def reduce(self, **kw):
+        from tempo_tpu.util.profiling import reduce_capture
+
+        args = dict(window_ns=100 * MS, annotations=self.ANNOTATIONS,
+                    programs=self.PROGRAMS, ops=self.OPS)
+        args.update(kw)
+        return reduce_capture(**args)
+
+    def test_partition_sums_to_idle_exactly(self):
+        s = self.reduce()
+        assert s["device"]["busy_s"] == pytest.approx(0.011)
+        assert s["device"]["idle_s"] == pytest.approx(0.089)
+        assert sum(s["idle"].values()) == pytest.approx(s["device"]["idle_s"], rel=1e-12)
+        assert all(v >= 0 for v in s["idle"].values())
+
+    def test_idle_is_blamed_on_the_innermost_annotation(self):
+        idle = self.reduce()["idle"]
+        # 0-10 ms and 90-100 ms less the 2 ms the last program ran
+        assert idle["none"] == pytest.approx(0.018)
+        # the root's self time: 10-20 and 80-90 ms
+        assert idle["http/GET /api/search"] == pytest.approx(0.020)
+        assert idle["stage/fetch"] == pytest.approx(0.010)
+        # the dispatch's self time less what the device ran inside it:
+        # 45-46 and 50-65 ms, busy 52-58 and 64-65 ms
+        assert idle["dispatch/pallas_in_set"] == pytest.approx(0.009)
+        assert idle["transfer/pallas_in_set"] == pytest.approx(0.004)
+        # worker self time: 25-30, 40-45, 65-75 ms, busy 65-67 ms
+        assert idle["worker/search_blocks"] == pytest.approx(0.018)
+
+    def test_a_waiting_thread_is_blamed_only_when_no_other_works(self):
+        idle = self.reduce()["idle"]
+        # the frontend waits 20-80 ms; the worker is inside an annotation
+        # 25-75 ms of it, so only 20-25 and 75-80 ms fall to the wait
+        assert idle["frontend/wait"] == pytest.approx(0.010)
+
+    def test_working_threads_share_an_idle_instant_equally(self):
+        s = self.reduce(annotations=[(0, "stage/decode", 0, 40 * MS),
+                                     (1, "stage/fetch", 20 * MS, 40 * MS),
+                                     (2, "frontend/wait", 0, 100 * MS)],
+                        programs=[], ops=[])
+        assert s["idle"]["stage/decode"] == pytest.approx(0.030)  # 20 alone + half of 20
+        assert s["idle"]["stage/fetch"] == pytest.approx(0.030)
+        assert s["idle"]["frontend/wait"] == pytest.approx(0.040)  # 60-100 ms
+        assert s["idle"]["none"] == 0.0
+        assert s["alignment"]["share"] is None  # no program ran
+
+    def test_device_seconds_inside_a_dispatch_never_pass_its_wall(self):
+        s = self.reduce()
+        row = s["dispatch"]["pallas_in_set"]
+        assert row["count"] == 1
+        assert row["wall_s"] == pytest.approx(0.020)
+        assert row["transfer_s"] == pytest.approx(0.004)
+        # 6 ms of the first run + the 1 ms of the second that lies inside
+        assert row["device_s"] == pytest.approx(0.007)
+        # a program longer than its dispatch is clipped to it
+        s = self.reduce(programs=[("jit__scan(1)", 40 * MS, 50 * MS)], ops=[(40 * MS, 50 * MS)])
+        row = s["dispatch"]["pallas_in_set"]
+        assert row["device_s"] == pytest.approx(row["wall_s"])
+
+    def test_program_names_lose_their_hash_and_split_by_dispatch(self):
+        programs = {(r["program"], r["inside"]): r for r in self.reduce()["device"]["programs"]}
+        assert programs[("jit__scan", "dispatch")]["runs"] == 2
+        assert programs[("jit__scan", "dispatch")]["device_s"] == pytest.approx(0.009)
+        assert programs[("jit_block_sketch_build", "none")]["runs"] == 1
+
+    def test_alignment_counts_runs_at_or_just_after_a_dispatch(self):
+        a = self.reduce()["alignment"]
+        assert (a["runs"], a["aligned"], a["early"]) == (3, 2, 0)
+        # a run that starts within 1 ms after its dispatch closed still counts
+        a = self.reduce(programs=[("jit__scan(1)", 65.5 * MS, 1 * MS)])["alignment"]
+        assert a["share"] == 1.0
+        a = self.reduce(programs=[("jit__scan(1)", 66.5 * MS, 1 * MS)])["alignment"]
+        assert (a["share"], a["early"]) == (0.0, 0)
+        # one the device stamped just before its dispatch opened does not:
+        # it is counted apart, as `early` (the device's stamps lead)
+        s = self.reduce(programs=[("jit__scan(1)", 44.5 * MS, 0.25 * MS)])
+        assert (s["alignment"]["share"], s["alignment"]["early"]) == (0.0, 1)
+        assert s["device"]["programs"][0]["inside"] == "none"
+        # early, but it reaches into the dispatch: aligned, and not early
+        a = self.reduce(programs=[("jit__scan(1)", 44.5 * MS, 1 * MS)])["alignment"]
+        assert (a["share"], a["early"]) == (1.0, 0)
+        a = self.reduce(programs=[("jit__scan(1)", 42 * MS, 1 * MS)])["alignment"]
+        assert (a["share"], a["early"]) == (0.0, 0)
+
+    def test_what_lies_outside_the_window_is_clipped_away(self):
+        # the window is the interval the seams were armed in: a run, an
+        # operation or an annotation that crosses its edge counts only
+        # for its part inside, and one wholly outside not at all
+        s = self.reduce(
+            annotations=[(0, "stage/fetch", -10 * MS, 30 * MS),
+                         (0, "stage/merge", 120 * MS, 5 * MS)],
+            programs=[("jit__scan(1)", -5 * MS, 8 * MS), ("jit__scan(2)", 98 * MS, 6 * MS),
+                      ("jit__scan(3)", 101 * MS, 1 * MS)],
+            ops=[(-5 * MS, 8 * MS), (98 * MS, 6 * MS), (101 * MS, 1 * MS)])
+        assert s["device"]["busy_s"] == pytest.approx(0.005)  # 0-3 and 98-100 ms
+        assert s["idle"]["stage/fetch"] == pytest.approx(0.017)  # 0-20 ms less 3 busy
+        assert "stage/merge" not in s["idle"]
+        assert sum(s["idle"].values()) == pytest.approx(s["device"]["idle_s"], rel=1e-12)
+        [row] = s["device"]["programs"]
+        assert (row["runs"], row["device_s"]) == (2, pytest.approx(0.005))
+        assert s["alignment"]["runs"] == 2
+
+    def test_without_a_device_plane_only_the_host_side_is_reduced(self):
+        s = self.reduce(programs=None, ops=None)
+        assert s["device"] is None and s["idle"] is None and s["alignment"] is None
+        assert s["dispatch"]["pallas_in_set"]["count"] == 1
+
+
+class TestSeamsWithoutACapture:
+    def test_stage_outside_a_request_is_the_shared_null_context(self):
+        from tempo_tpu.util import profiling
+
+        assert not profiling.capturing
+        assert stagetimings.stage("fetch") is profiling.NULL_CONTEXT
+
+    def test_span_without_an_exporter_is_the_shared_null_context(self):
+        from tempo_tpu.util import profiling
+
+        assert tracing.TRACER.exporter is None
+        assert tracing.span("tempodb/find", tenant="t") is profiling.NULL_CONTEXT
+        assert profiling.annotation("frontend/wait") is profiling.NULL_CONTEXT
+        assert profiling.request_scope() is profiling.NULL_CONTEXT
+        assert profiling.current_req() == 0
+
+
+def _get_json(url, timeout=60):
+    import json
+    import urllib.request
+
+    with urllib.request.urlopen(url, timeout=timeout) as r:
+        return json.loads(r.read())
+
+
+class TestCaptureOnTheCPU:
+    def test_capture_holds_the_seams_and_reduces_itself(self, tmp_path):
+        """One capture through /status/profile/device while a search, a
+        push and a dispatch run: the three seams' intervals are in the
+        profiler's own host plane, the reply carries `summary`, and with
+        no device plane it says so instead of inventing device numbers."""
+        import urllib.request
+
+        from jax.profiler import ProfileData
+
+        from tempo_tpu.api.server import TempoServer
+        from tempo_tpu.receivers import otlp
+        from tempo_tpu.util import profiling
+        from tempo_tpu.util.devicetiming import timed_dispatch
+
+        app = make_app(tmp_path, query_workers=1)
+        srv = TempoServer(app).start()
+        reply = {}
+        try:
+            app.push_traces(synth.make_traces(16, seed=51))
+            app.sweep_all(immediate=True)
+            t = threading.Thread(target=lambda: reply.update(
+                _get_json(srv.url + "/status/profile/device?seconds=1.5")))
+            t.start()
+            deadline = time.monotonic() + 10
+            while not profiling.capturing and time.monotonic() < deadline:
+                time.sleep(0.01)
+            assert profiling.capturing
+            _get_json(srv.url + "/api/search?limit=5")
+            body = otlp.encode_traces_request(synth.make_traces(4, seed=52))
+            req = urllib.request.Request(
+                srv.url + "/v1/traces", data=body,
+                headers={"Content-Type": "application/x-protobuf"})
+            assert urllib.request.urlopen(req).status == 200
+            with stagetimings.request():
+                assert timed_dispatch("unit-capture", lambda x: x + 1, 1) == 2
+            t.join(timeout=60)
+        finally:
+            srv.stop()
+            app.shutdown()
+        assert not profiling.capturing
+        assert reply["supported"], reply
+        assert "hint" not in reply
+        summary = reply["summary"]
+        assert "error" not in summary, summary
+        assert summary["device"] is None and summary["idle"] is None
+        assert summary["dispatch"]["unit-capture"]["count"] == 1
+        # the armed interval (the sleep of 1.5 s), not the profiler's window
+        assert 1.5 <= summary["window_s"] < 2.5
+        # and the events themselves, read back from the trace on disk
+        [xplane] = [f for f in reply["files"] if f.endswith(".xplane.pb")]
+        profile = ProfileData.from_file(reply["dir"] + "/" + xplane)
+        host = next(p for p in profile.planes if p.name == "/host:CPU")
+        by_req: dict = {}
+        for line in host.lines:
+            for e in line.events:
+                stats = dict(e.stats)
+                if "req" in stats:
+                    by_req.setdefault(e.name, set()).add(stats["req"])
+        names = set(by_req)
+        assert "http/GET /api/search" in names, sorted(names)
+        assert "http/POST /v1/traces" in names
+        assert "dispatch/unit-capture" in names and "transfer/unit-capture" in names
+        assert "capture/armed" in names
+        assert any(n.startswith("stage/") for n in names), sorted(names)
+        assert {"frontend/search", "frontend/wait", "receiver/decode", "distributor/push",
+                "ingester/append"} <= names, sorted(names)
+        # one request, one id: the search's root, its frontend wait and
+        # its worker's job (another thread) carry the same nonzero `req`
+        search_req = by_req["http/GET /api/search"]
+        assert len(search_req) == 1 and 0 not in search_req
+        assert search_req <= by_req["frontend/wait"]
+        assert search_req <= by_req["worker/search_blocks"] | by_req.get(
+            "worker/search_recent", set())
+        # the Python tracer is off: a capture this short stays small
+        assert sum(len(list(ln.events)) for ln in host.lines) < 20000
+
+
+class TestPushWaterfall:
+    def test_push_stages_sum_to_the_handlers_wall(self, tmp_path):
+        import urllib.request
+
+        from tempo_tpu.api.server import TempoServer
+        from tempo_tpu.receivers import otlp
+
+        app = make_app(tmp_path)
+        srv = TempoServer(app).start()
+        hist = stagetimings.stage_seconds_hist
+        stages = ("decode", "admission", "fan_out", "live", "other")
+
+        def push(n_before):
+            """One push; its wall at the client, once the handler has
+            published its stages (it does so after the reply is sent)."""
+            t0 = time.perf_counter()
+            urllib.request.urlopen(req).read()
+            wall = time.perf_counter() - t0
+            deadline = time.monotonic() + 10
+            while (hist.count(stage="other", kind="push") <= n_before
+                   and time.monotonic() < deadline):
+                time.sleep(0.005)
+            return wall
+
+        try:
+            body = otlp.encode_traces_request(synth.make_traces(256, seed=53))
+            req = urllib.request.Request(
+                srv.url + "/v1/traces", data=body,
+                headers={"Content-Type": "application/x-protobuf"})
+            push(hist.count(stage="other", kind="push"))  # warm: imports, first-touch paths
+            before = {s: hist.sum(stage=s, kind="push") for s in stages}
+            wall = push(hist.count(stage="other", kind="push"))
+            grew = {s: hist.sum(stage=s, kind="push") - before[s] for s in stages}
+        finally:
+            srv.stop()
+            app.shutdown()
+        assert grew["decode"] > 0 and grew["fan_out"] > 0 and grew["live"] > 0
+        # the client's wall adds the connection; the handler's runs on a
+        # little after the reply is sent
+        assert sum(grew.values()) == pytest.approx(wall, rel=0.10)
+
+
+class TestJitCompileCount:
+    def test_a_new_shape_counts_once_and_a_repeat_not_at_all(self):
+        import jax
+        import numpy as np
+
+        from tempo_tpu.util import xla_cache
+
+        xla_cache.ensure_persistent_cache()
+        f = jax.jit(lambda x: x * 3 + 1)
+        total = xla_cache.jit_compiles_total.total
+        n0 = total()
+        f(np.ones(7, np.float32))
+        assert total() == n0 + 1
+        f(np.ones(7, np.float32))
+        assert total() == n0 + 1
+        f(np.ones(9, np.float32))
+        assert total() == n0 + 2
+        assert xla_cache.jit_compiles_total.total(source="backend") >= 2
