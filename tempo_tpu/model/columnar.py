@@ -65,6 +65,10 @@ ATTR_COLUMNS = {
     "attr_num": (np.float64, None),  # numeric value otherwise
 }
 
+# bytes one row holds across its columns: what nbytes() sums, per row
+SPAN_ROW_BYTES = sum(np.dtype(d).itemsize * (w or 1) for d, w in SPAN_COLUMNS.values())
+ATTR_ROW_BYTES = sum(np.dtype(d).itemsize * (w or 1) for d, w in ATTR_COLUMNS.values())
+
 
 class Dictionary:
     """Append-only string dictionary; code 0 is always the empty string.
@@ -114,6 +118,9 @@ class Dictionary:
 
     def __getitem__(self, code: int) -> str:
         return self.entries[code]
+
+    def nbytes(self) -> int:
+        return sum(len(e) for e in self.entries)
 
     def remap_onto(self, other: "Dictionary") -> np.ndarray:
         """Merge self's entries into `other`; return old->new code table.
@@ -300,8 +307,7 @@ class SpanBatch:
     def nbytes(self) -> int:
         n = sum(v.nbytes for v in self.cols.values())
         n += sum(v.nbytes for v in self.attrs.values())
-        n += sum(len(e) for e in self.dictionary.entries)
-        return n
+        return n + self.dictionary.nbytes()
 
     def end_unix_nano(self) -> np.ndarray:
         return self.cols["start_unix_nano"] + self.cols["duration_nano"]

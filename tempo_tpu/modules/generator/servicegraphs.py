@@ -12,6 +12,7 @@ BASELINE.json north-star metric for this processor.
 
 from __future__ import annotations
 
+import threading
 import time
 
 import numpy as np
@@ -55,9 +56,15 @@ class ServiceGraphsProcessor:
         self.hll = sketch.hll_init(sketch.HLLPlan(12))
         self.cm = sketch.cm_init(sketch.CMPlan())
         self._edge_keys: list = []
+        # one lock around push: the pairing stores, the sketches and the
+        # counts are read and rewritten by every concurrent pusher
+        self._lock = threading.Lock()
 
     def push(self, batch, now: float | None = None) -> None:
-        now = now or time.time()
+        with self._lock:
+            self._push_locked(batch, now or time.time())
+
+    def _push_locked(self, batch, now: float) -> None:
         c = batch.cols
         d = batch.dictionary
         kinds = c["kind"]

@@ -5,10 +5,10 @@ mutex-guarded live-trace map, CutCompleteTraces:240, CutBlockIfReady:275,
 CompleteBlock:308, flush queues flush.go:124-360, WAL replay
 ingester.go:328, Limiter limiter.go:22).
 
-Array-first twist: a live trace is a list of columnar segments (what the
-distributor sent), so cutting traces to the WAL is batch concatenation,
-and completing a block is the engine's sorted-batch write — object trees
-never appear on the write path.
+Array-first twist: a push is grouped by trace once and a live trace is a
+list of row ranges of those grouped batches, so cutting traces to the WAL
+is batch concatenation (one batch a push), and completing a block is the
+engine's sorted-batch write — object trees never appear on the write path.
 """
 
 from __future__ import annotations
@@ -21,7 +21,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from tempo_tpu.encoding.vtpu import format as fmt
-from tempo_tpu.model.columnar import SpanBatch
+from tempo_tpu.model.columnar import ATTR_ROW_BYTES, SPAN_ROW_BYTES, SpanBatch
 from tempo_tpu.model.trace import Trace, batch_to_traces, combine_traces
 from tempo_tpu.util import metrics, resource, stagetimings, tracing, usage
 from tempo_tpu.util.flushqueues import ExclusiveQueues, FlushOp
@@ -46,6 +46,12 @@ pushes_refused_total = metrics.counter(
     "tempo_ingester_pushes_refused_total",
     "Pushes refused at critical memory pressure (retryable)",
 )
+append_seconds = metrics.counter(
+    "tempo_ingester_append_seconds_total",
+    "Seconds of the live-trace insert by phase: group (the push sorted by trace, "
+    "outside the tenant's lock), lock_wait (asking for the lock until holding it), "
+    "insert (holding it)",
+)
 
 
 class TraceTooLarge(Exception):
@@ -56,9 +62,56 @@ class MaxLiveTraces(Exception):
     """Reference: limiter.AssertMaxTracesPerUser."""
 
 
+class _Push:
+    """One push grouped by trace (rows in (trace_id, span_id) order),
+    shared by the live traces that hold row ranges of it. `live_rows`
+    counts the rows live traces still reference (read and written under
+    the tenant's lock); `seq` is the arrival number, so that readers put
+    pushes back in the order they came."""
+
+    __slots__ = ("batch", "seq", "live_rows")
+
+    def __init__(self, batch: SpanBatch, seq: int = 0):
+        self.batch = batch
+        self.seq = seq
+        self.live_rows = 0
+
+    def sparse(self) -> bool:
+        """Live traces reference at most half of the rows held."""
+        return 0 < self.live_rows * 2 <= self.batch.num_spans
+
+
+def _range_rows(lo: np.ndarray, hi: np.ndarray) -> np.ndarray:
+    """The row numbers of the ranges [lo, hi), one after the other."""
+    n = hi - lo
+    ends = np.cumsum(n)
+    return np.repeat(lo - (ends - n), n) + np.arange(int(ends[-1]))
+
+
+def _range_batches(segments: list) -> list[SpanBatch]:
+    """Row ranges [(push, lo, hi)] -> one batch a push, pushes in the
+    order they arrived: a push whose every row is wanted is passed as it
+    is, any other costs one select over its ranges (ascending, so the
+    batch keeps the (trace_id, span_id) order)."""
+    by_push: dict[int, tuple] = {}
+    for push, lo, hi in segments:
+        by_push.setdefault(id(push), (push, []))[1].append((lo, hi))
+    out = []
+    for push, ranges in sorted(by_push.values(), key=lambda e: e[0].seq):
+        if sum(hi - lo for lo, hi in ranges) == push.batch.num_spans:
+            out.append(push.batch)
+        else:
+            bounds = np.array(sorted(ranges), dtype=np.int64)
+            out.append(push.batch.select(_range_rows(bounds[:, 0], bounds[:, 1])))
+    return out
+
+
 @dataclass
 class LiveTrace:
-    segments: list = field(default_factory=list)  # list[SpanBatch]
+    # row ranges (push, lo, hi) of the pushes that brought the trace's
+    # spans: a range pins its push's grouped batch until the trace is
+    # cut or the push is repacked (TenantInstance._repack)
+    segments: list = field(default_factory=list)
     last_touch: float = 0.0
     first_touch: float = 0.0
     span_count: int = 0
@@ -100,6 +153,7 @@ class TenantInstance:
         self.flushed: list = []  # (meta, flushed_at) — cleared after timeout
         self.traces_created = 0
         self.spans_dropped_too_large = 0
+        self._pushes = 0  # arrival number of the newest push (_Push.seq)
 
     # -- push -----------------------------------------------------------
     def push_segment(self, data: bytes, now: float | None = None) -> None:
@@ -118,14 +172,30 @@ class TenantInstance:
     def _push_batch_traced(self, batch: SpanBatch, now: float | None = None) -> None:
         now = now or time.time()
         lim = self.overrides.for_tenant(self.tenant)
-        tid = batch.cols["trace_id"]
-        uniq, inverse = np.unique(tid, axis=0, return_inverse=True)
+        t_group = time.perf_counter()
+        # the push is grouped by trace once, outside the lock: one sort,
+        # then every trace's row range, 16-byte key and bytes (the
+        # integers select(rows).nbytes() gives: its rows' columns plus
+        # the push's dictionary) in a few vector expressions
+        push = _Push(batch.sorted_by_trace())
+        grouped = push.batch
+        firsts, _ = grouped.trace_boundaries()
+        bounds = np.append(firsts, grouped.num_spans)
+        attr_bounds = np.searchsorted(grouped.attrs["attr_span"], bounds)
+        trace_bytes = (np.diff(bounds) * SPAN_ROW_BYTES
+                       + np.diff(attr_bounds) * ATTR_ROW_BYTES
+                       + grouped.dictionary.nbytes())
+        keys = grouped.cols["trace_id"][firsts].astype(">u4").tobytes()
+        ranges = list(zip(bounds[:-1].tolist(), bounds[1:].tolist(), trace_bytes.tolist()))
         errors: list[Exception] = []
         appended_bytes = 0
+        t_ask = time.perf_counter()
         with self.lock:
-            for u in range(len(uniq)):
-                rows = np.flatnonzero(inverse == u)
-                key = uniq[u].astype(">u4").tobytes()
+            t_held = time.perf_counter()
+            self._pushes += 1
+            push.seq = self._pushes
+            for u, (lo, hi, nbytes) in enumerate(ranges):
+                key = keys[16 * u:16 * u + 16]
                 lt = self.live.get(key)
                 if lt is None:
                     if lim.max_traces_per_user and len(self.live) >= lim.max_traces_per_user:
@@ -138,22 +208,23 @@ class TenantInstance:
                     lt = LiveTrace(first_touch=now)
                     self.live[key] = lt
                     self.traces_created += 1
-                sub = batch.select(rows)
-                if lim.max_spans_per_trace and lt.span_count + sub.num_spans > lim.max_spans_per_trace:
-                    self.spans_dropped_too_large += sub.num_spans
+                spans = hi - lo
+                if lim.max_spans_per_trace and lt.span_count + spans > lim.max_spans_per_trace:
+                    self.spans_dropped_too_large += spans
                     errors.append(
                         TraceTooLarge(f"trace {key.hex()} exceeds {lim.max_spans_per_trace} spans")
                     )
                     continue
-                if lim.max_bytes_per_trace and lt.byte_count + sub.nbytes() > lim.max_bytes_per_trace:
-                    self.spans_dropped_too_large += sub.num_spans
+                if lim.max_bytes_per_trace and lt.byte_count + nbytes > lim.max_bytes_per_trace:
+                    self.spans_dropped_too_large += spans
                     errors.append(TraceTooLarge(f"trace {key.hex()} exceeds byte limit"))
                     continue
-                lt.segments.append(sub)
-                lt.span_count += sub.num_spans
-                lt.byte_count += sub.nbytes()
+                lt.segments.append((push, lo, hi))
+                lt.span_count += spans
+                lt.byte_count += nbytes
                 lt.last_touch = now
-                appended_bytes += sub.nbytes()
+                appended_bytes += nbytes
+                push.live_rows += spans
             live_traces_gauge.set(len(self.live), tenant=self.tenant)
             # charge the pool UNDER the instance lock: a concurrent cut
             # can only sub bytes it saw in self.live, and those are
@@ -162,8 +233,45 @@ class TenantInstance:
             # deficit that a late add would then leak forever
             if appended_bytes:
                 self.governor.pool("live_traces").add(appended_bytes)
+            repack = push.sparse()  # most of the push was refused
+        t_done = time.perf_counter()
+        append_seconds.inc(t_ask - t_group, phase="group")
+        append_seconds.inc(t_held - t_ask, phase="lock_wait")
+        append_seconds.inc(t_done - t_held, phase="insert")
+        if repack:
+            self._repack([push])
         if errors:
             raise errors[0]
+
+    def _repack(self, sparse: list) -> None:
+        """What a row range pins. A live trace keeps its pushes' grouped
+        batches reachable; once live traces reference at most half the
+        rows of one (`_Push.sparse`: the rest was cut or refused), the
+        surviving rows are re-selected into a batch of their own and the
+        ranges moved over, so a push held for live traces never holds
+        more than twice the rows they reference (checked after every cut
+        and every push that refused rows). The select runs outside the
+        lock; a trace cut meanwhile keeps its old ranges."""
+        refs: dict[int, list] = {id(p): [] for p in sparse}
+        with self.lock:
+            for key, lt in self.live.items():
+                for j, seg in enumerate(lt.segments):
+                    found = refs.get(id(seg[0]))
+                    if found is not None:
+                        found.append((key, lt, j, seg))
+        for old in sparse:
+            found = sorted(refs[id(old)], key=lambda r: r[3][1])
+            if not found:
+                continue
+            bounds = np.array([r[3][1:] for r in found], dtype=np.int64)
+            packed = _Push(old.batch.select(_range_rows(bounds[:, 0], bounds[:, 1])), seq=old.seq)
+            ends = np.cumsum(bounds[:, 1] - bounds[:, 0]).tolist()
+            with self.lock:
+                for (key, lt, j, seg), end in zip(found, ends):
+                    if self.live.get(key) is lt and lt.segments[j] is seg:
+                        spans = seg[2] - seg[1]
+                        lt.segments[j] = (packed, end - spans, end)
+                        packed.live_rows += spans
 
     # -- cuts -----------------------------------------------------------
     def cut_complete_traces(self, now: float | None = None, immediate: bool = False) -> int:
@@ -178,16 +286,28 @@ class TenantInstance:
     def _cut_complete_traces_traced(self, now: float | None, immediate: bool) -> int:
         now = now or time.time()
         cut = []
+        touched: dict[int, _Push] = {}
         with self.lock:
             for key, lt in list(self.live.items()):
                 if immediate or now - lt.last_touch > self.cfg.max_trace_idle_s:
                     cut.append((key, lt))
                     del self.live[key]
+                    for push, lo, hi in lt.segments:
+                        push.live_rows -= hi - lo
+                        touched[id(push)] = push
+            # pushes this cut took most of, but not all (see _repack)
+            sparse = [p for p in touched.values() if p.sparse()]
         live_traces_gauge.set(len(self.live), tenant=self.tenant)
         if not cut:
             return 0
+        if sparse:
+            self._repack(sparse)
         cut_bytes = sum(lt.byte_count for _, lt in cut)
-        batch = SpanBatch.concat([seg for _, lt in cut for seg in lt.segments]).sorted_by_trace()
+        # one batch a push (a whole push as it is), so concat remaps one
+        # dictionary a push, not one a trace
+        batch = SpanBatch.concat(
+            _range_batches([seg for _, lt in cut for seg in lt.segments])
+        ).sorted_by_trace()
         # append under the lock: cut_block_if_ready swaps self.head into
         # completing under it, and a completing block may already be mid
         # write_wal_block/clear() — an unlocked append can land on a block
@@ -388,7 +508,7 @@ class TenantInstance:
             lt = self.live.get(key)
             segments = list(lt.segments) if lt else []
         if segments:
-            parts.extend(batch_to_traces(SpanBatch.concat(segments)))
+            parts.extend(batch_to_traces(SpanBatch.concat(_range_batches(segments))))
         limbs = np.frombuffer(key, dtype=">u4").astype(np.uint32)
         with self.lock:
             wal_blocks = [self.head] + list(self.completing)
@@ -405,8 +525,9 @@ class TenantInstance:
         "<block_id>:<seg>" identity the cut path parked under) so the
         querier's live-tail scan can find the resident copy."""
         with self.lock:
-            segs = [seg for lt in self.live.values() for seg in lt.segments]
+            ranges = [seg for lt in self.live.values() for seg in lt.segments]
             wal_blocks = [self.head] + list(self.completing)
+        segs = _range_batches(ranges)
         from tempo_tpu.ops import ingest_tail
         for blk in wal_blocks:
             keyed = getattr(blk, "iter_batches_keyed", None)
@@ -424,7 +545,8 @@ class TenantInstance:
         read tail. Cut spans are already in the standing accumulator —
         including the WAL here would double-count every cut."""
         with self.lock:
-            return [seg for lt in self.live.values() for seg in lt.segments]
+            ranges = [seg for lt in self.live.values() for seg in lt.segments]
+        return _range_batches(ranges)
 
     def wal_segment_batches(self) -> list[tuple[str, SpanBatch]]:
         """(segment key, batch) for every WAL segment (head + completing)

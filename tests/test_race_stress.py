@@ -200,3 +200,57 @@ class TestBackgroundCacheStress:
         # post-conditions: inner LRU byte accounting consistent
         with inner._lock:
             assert inner._size == sum(len(v) for v in inner._data.values())
+
+
+class TestGeneratorStress:
+    def test_concurrent_pushes_pair_the_same_edges(self):
+        """Four pushers share one tenant's processors while `expire`
+        deletes behind them (a small wait_s): the service-graph pairing
+        stores are mutated and iterated by every push. No exception, and
+        the edges (every pair lies inside one push) are a serial run's."""
+        from tempo_tpu.model import synth
+        from tempo_tpu.modules.generator import (
+            TenantGeneratorInstance,
+            servicegraphs,
+            spanmetrics,
+        )
+        from tempo_tpu.modules.generator.servicegraphs import ServiceGraphsProcessor
+        from tempo_tpu.modules.overrides import Limits, Overrides
+
+        rounds = 20
+        batches = {seed: [synth.make_graph_batch(16, 6, seed=seed * 1000 + i)
+                          for i in range(rounds)] for seed in (1, 2, 3, 4)}
+
+        def instance():
+            inst = TenantGeneratorInstance("acme", Overrides(Limits()))
+            graphs = next(p for p in inst.processors if isinstance(p, ServiceGraphsProcessor))
+            graphs.wait_s = 1e-4  # halves left by earlier pushes expire in every push
+            return inst, graphs
+
+        serial, serial_graphs = instance()
+        for seed in batches:
+            for b in batches[seed]:
+                serial.push_batch(b)
+        assert serial_graphs.edges_emitted > 0 and serial_graphs.expired > 0
+
+        shared, graphs = instance()
+
+        def worker(seed):
+            for b in batches[seed]:
+                shared.push_batch(b)
+
+        run_threads(4, worker, seeds=[1, 2, 3, 4])
+        assert graphs.edges_emitted == serial_graphs.edges_emitted
+        # every half that found no partner left again, once
+        pending = len(graphs.pending_clients) + len(graphs.pending_servers)
+        serial_pending = len(serial_graphs.pending_clients) + len(serial_graphs.pending_servers)
+        assert graphs.expired + pending == serial_graphs.expired + serial_pending
+        # the registry both processors write to (it has a lock of its own)
+        # counted every call and every edge of every push
+
+        def counters(inst):
+            return sorted((s.name, s.labels, s.value) for s in inst.registry.collect(now_ms=1)
+                          if s.name in (spanmetrics.CALLS, servicegraphs.REQ_TOTAL,
+                                        servicegraphs.REQ_FAILED))
+
+        assert counters(shared) == counters(serial)
