@@ -28,8 +28,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from tempo_tpu.parallel import accounting
+from tempo_tpu.parallel.accounting import count_dispatch
 from tempo_tpu.parallel.mesh import RANGE_AXIS, WINDOW_AXIS
 from tempo_tpu.parallel.search import dispatch_lock as _dispatch_lock
+from tempo_tpu.util.devicetiming import timed_dispatch
 
 log = logging.getLogger(__name__)
 
@@ -84,6 +87,14 @@ class MeshMetricsEvaluator:
 
     def evaluate_blocks(self, blocks, plan, acc, on_block_error=None,
                         on_block_ok=None) -> None:
+        """One metrics job on the mesh: see _evaluate_blocks. The span
+        and the clock cover the job's host work (parallel/accounting)."""
+        with accounting.job("evaluate_blocks") as clock:
+            self._evaluate_blocks(blocks, plan, acc, on_block_error,
+                                  on_block_ok, clock)
+
+    def _evaluate_blocks(self, blocks, plan, acc, on_block_error, on_block_ok,
+                         clock) -> None:
         """blocks: iterable of lazily-opened VtpuBackendBlocks. Row
         groups are zone-map/time pruned with zero reads, surviving units
         evaluate host-side to slot ids, and slot batches dispatch in
@@ -117,15 +128,17 @@ class MeshMetricsEvaluator:
         def flush():
             if not pending:
                 return
-            pad = self.bucket_for(max(len(s) for s, _ in pending))
-            stacked = np.full((cap, pad), -1, np.int32)
-            wstack = np.zeros((cap, pad), np.int32)
-            for i, (s, w) in enumerate(pending):
-                stacked[i, : len(s)] = s
-                wstack[i, : len(s)] = w if w is not None else 1
-            from tempo_tpu.util.devicetiming import timed_dispatch
-
-            with _dispatch_lock:
+            clock.lap("plan", "mesh_bincount")
+            with clock.phase("stack"):
+                pad = self.bucket_for(max(len(s) for s, _ in pending))
+                stacked = np.full((cap, pad), -1, np.int32)
+                wstack = np.zeros((cap, pad), np.int32)
+                shard_rows = [0] * cap
+                for i, (s, w) in enumerate(pending):
+                    stacked[i, : len(s)] = s
+                    wstack[i, : len(s)] = w if w is not None else 1
+                    shard_rows[i] = len(s)
+            with clock.dispatching(_dispatch_lock):
                 # raw host arrays: the seam ships them (h2d bytes +
                 # transfer stage measured at the boundary)
                 out = timed_dispatch(
@@ -133,8 +146,11 @@ class MeshMetricsEvaluator:
                     stacked.reshape(self.w, self.r, pad),
                     wstack.reshape(self.w, self.r, pad),
                 )
-                counts = np.asarray(out).sum(axis=0, dtype=np.int64)
-            acc.counts += counts
+            # the psum reduces one (n_slots,) int32 vector a window
+            count_dispatch("mesh_bincount", len(pending), shard_rows, pad,
+                           4 * self.w * plan.n_slots)
+            with clock.phase("collect"):
+                acc.counts += np.asarray(out).sum(axis=0, dtype=np.int64)
             stats["dispatches"] += 1
             stats["units"] += len(pending)
             stats["h2d_bytes"] += stacked.nbytes + wstack.nbytes

@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from tempo_tpu.ops import bloom
+from tempo_tpu.parallel import accounting
+from tempo_tpu.parallel.accounting import count_dispatch
 from tempo_tpu.parallel.mesh import RANGE_AXIS, WINDOW_AXIS
 from tempo_tpu.util import metrics
 from tempo_tpu.util.devicetiming import timed_dispatch
@@ -316,6 +318,14 @@ class MeshSearcher:
     # -- search ----------------------------------------------------------
     def search_blocks(self, blocks, req, on_block_error=None,
                       on_block_ok=None) -> "object":
+        """One job's search on the mesh: see _search_blocks. The span
+        and the clock cover the job's host work (parallel/accounting)."""
+        with accounting.job("search_blocks") as clock:
+            return self._search_blocks(blocks, req, on_block_error,
+                                       on_block_ok, clock)
+
+    def _search_blocks(self, blocks, req, on_block_error, on_block_ok,
+                       clock) -> "object":
         """blocks: ITERABLE of lazily-opened VtpuBackendBlocks — a block
         is only opened (index + dictionary reads) when the scan actually
         reaches it, so limited queries over large tenants keep the old
@@ -440,148 +450,153 @@ class MeshSearcher:
                     break
                 unit_encs.append(row)
 
-            if unit_encs is not None:
-                from tempo_tpu.encoding.vtpu.colcache import shared_device_tier
+            kernel = "mesh_rle_scan" if unit_encs is not None else "mesh_scan"
+            clock.lap("plan", kernel)
+            with clock.phase("stack"):
+                if unit_encs is not None:
+                    from tempo_tpu.encoding.vtpu.colcache import shared_device_tier
 
-                tier = shared_device_tier()
-                pkeys = tuple(tuple(e.resident_key() for e in row)
-                              for row in unit_encs)
-                skey = ("mesh_stack", pkeys, n_cols, pad)
-                res = tier.get(skey) if tier is not None else None
-                if res is not None:
-                    # resident hot path: the stacked run payload is
-                    # already parked on device — skip run loading and
-                    # host stacking entirely; only the (tiny) per-query
-                    # codes + valid ship
-                    run_pad = int(res.meta["run_pad"])
-                    dev_values = res.arrays["values"]
-                    dev_lengths = res.arrays["lengths"]
-                    tier.record_avoided(res.host_bytes, kernel="mesh_rle_scan")
-                    for s, (blk, i, rg, preds) in enumerate(chunk):
-                        for c, (col_name, accept) in enumerate(preds["span_eq"]):
-                            k = min(len(accept), self.max_codes)
-                            codes[s, c, :k] = accept[:k]
-                        for c in range(len(preds["span_eq"]), n_cols):
-                            codes[s, c, 0] = 0
-                        valid[s, : rg.n_spans] = True
-                        live.append(s)
-                else:
-                    max_runs = 8
-                    unit_runs = []
-                    for s, (blk, i, rg, preds) in enumerate(chunk):
-                        try:
-                            runs = [with_retries(e.runs) for e in unit_encs[s]]
-                        except Exception as e:  # e.g. block deleted mid-query
-                            errors.append((blk, e))
-                            log.warning("mesh search: run load failed: %s", e)
-                            unit_runs.append(None)
-                            continue
-                        unit_runs.append(runs)
-                        for v, l in runs:
-                            max_runs = max(max_runs, len(l))
-                    run_pad = 1 << (max_runs - 1).bit_length()
-                    values = np.full((cap, n_cols, run_pad), NO_MATCH, np.uint32)
-                    lengths = np.zeros((cap, n_cols, run_pad), np.int32)
-                    for s, (blk, i, rg, preds) in enumerate(chunk):
-                        if unit_runs[s] is None:
-                            continue
-                        for c, ((col_name, accept), (v, l)) in enumerate(
-                                zip(preds["span_eq"], unit_runs[s])):
-                            values[s, c, : len(v)] = v.astype(np.uint32)
-                            lengths[s, c, : len(l)] = l
-                            k = min(len(accept), self.max_codes)
-                            codes[s, c, :k] = accept[:k]
-                        for c in range(len(preds["span_eq"]), n_cols):
-                            # fewer predicates than the widest: accept-all
-                            # (one all-covering run of value 0, code 0)
-                            values[s, c, 0] = 0
-                            lengths[s, c, 0] = rg.n_spans
-                            codes[s, c, 0] = 0
-                        valid[s, : rg.n_spans] = True
-                        live.append(s)
-                    dev_values = values.reshape(self.w, self.r, n_cols, run_pad)
-                    dev_lengths = lengths.reshape(self.w, self.r, n_cols, run_pad)
-                    if tier is not None and all(r is not None for r in unit_runs):
-                        # offer the WHOLE stack; admitted only when every
-                        # page in it sits inside the what-if knee. The
-                        # admitting dispatch serves from the fresh entry
-                        # too (one ship, counted as device_tier_admit)
-                        tier.offer(skey, "rle_stack",
-                                   {"values": dev_values,
-                                    "lengths": dev_lengths},
-                                   meta={"run_pad": run_pad},
-                                   host_bytes=values.nbytes + lengths.nbytes,
-                                   page_keys=[k for row in pkeys for k in row])
-                        got = tier.get(skey)
-                        if got is not None:
-                            dev_values = got.arrays["values"]
-                            dev_lengths = got.arrays["lengths"]
-                scan = make_sharded_rle_scan(self.mesh, n_cols, self.max_codes, pad)
-                with _dispatch_lock:
-                    # host arrays go in raw: the timed_dispatch seam
-                    # ships them itself, so h2d bytes + transfer time
-                    # are measured where they happen; resident (device)
-                    # payloads ship nothing and are counted as such
-                    masks, _totals = timed_dispatch(
-                        "mesh_rle_scan", scan,
+                    tier = shared_device_tier()
+                    pkeys = tuple(tuple(e.resident_key() for e in row)
+                                  for row in unit_encs)
+                    skey = ("mesh_stack", pkeys, n_cols, pad)
+                    res = tier.get(skey) if tier is not None else None
+                    if res is not None:
+                        # resident hot path: the stacked run payload is
+                        # already parked on device — skip run loading and
+                        # host stacking entirely; only the (tiny) per-query
+                        # codes + valid ship
+                        run_pad = int(res.meta["run_pad"])
+                        dev_values = res.arrays["values"]
+                        dev_lengths = res.arrays["lengths"]
+                        tier.record_avoided(res.host_bytes, kernel="mesh_rle_scan")
+                        for s, (blk, i, rg, preds) in enumerate(chunk):
+                            for c, (col_name, accept) in enumerate(preds["span_eq"]):
+                                k = min(len(accept), self.max_codes)
+                                codes[s, c, :k] = accept[:k]
+                            for c in range(len(preds["span_eq"]), n_cols):
+                                codes[s, c, 0] = 0
+                            valid[s, : rg.n_spans] = True
+                            live.append(s)
+                    else:
+                        max_runs = 8
+                        unit_runs = []
+                        for s, (blk, i, rg, preds) in enumerate(chunk):
+                            try:
+                                runs = [with_retries(e.runs) for e in unit_encs[s]]
+                            except Exception as e:  # e.g. block deleted mid-query
+                                errors.append((blk, e))
+                                log.warning("mesh search: run load failed: %s", e)
+                                unit_runs.append(None)
+                                continue
+                            unit_runs.append(runs)
+                            for v, l in runs:
+                                max_runs = max(max_runs, len(l))
+                        run_pad = 1 << (max_runs - 1).bit_length()
+                        values = np.full((cap, n_cols, run_pad), NO_MATCH, np.uint32)
+                        lengths = np.zeros((cap, n_cols, run_pad), np.int32)
+                        for s, (blk, i, rg, preds) in enumerate(chunk):
+                            if unit_runs[s] is None:
+                                continue
+                            for c, ((col_name, accept), (v, l)) in enumerate(
+                                    zip(preds["span_eq"], unit_runs[s])):
+                                values[s, c, : len(v)] = v.astype(np.uint32)
+                                lengths[s, c, : len(l)] = l
+                                k = min(len(accept), self.max_codes)
+                                codes[s, c, :k] = accept[:k]
+                            for c in range(len(preds["span_eq"]), n_cols):
+                                # fewer predicates than the widest: accept-all
+                                # (one all-covering run of value 0, code 0)
+                                values[s, c, 0] = 0
+                                lengths[s, c, 0] = rg.n_spans
+                                codes[s, c, 0] = 0
+                            valid[s, : rg.n_spans] = True
+                            live.append(s)
+                        dev_values = values.reshape(self.w, self.r, n_cols, run_pad)
+                        dev_lengths = lengths.reshape(self.w, self.r, n_cols, run_pad)
+                        if tier is not None and all(r is not None for r in unit_runs):
+                            # offer the WHOLE stack; admitted only when every
+                            # page in it sits inside the what-if knee. The
+                            # admitting dispatch serves from the fresh entry
+                            # too (one ship, counted as device_tier_admit)
+                            tier.offer(skey, "rle_stack",
+                                       {"values": dev_values,
+                                        "lengths": dev_lengths},
+                                       meta={"run_pad": run_pad},
+                                       host_bytes=values.nbytes + lengths.nbytes,
+                                       page_keys=[k for row in pkeys for k in row])
+                            got = tier.get(skey)
+                            if got is not None:
+                                dev_values = got.arrays["values"]
+                                dev_lengths = got.arrays["lengths"]
+                    scan = make_sharded_rle_scan(self.mesh, n_cols, self.max_codes, pad)
+                    args = (
                         dev_values,
                         dev_lengths,
                         codes.reshape(self.w, self.r, n_cols, self.max_codes),
                         valid.reshape(self.w, self.r, pad),
                     )
-                    masks_np = np.asarray(masks).reshape(cap, pad)
-                stats["units_runspace"] += len(live)
-                stats["h2d_bytes"] += codes.nbytes + valid.nbytes
-                if isinstance(dev_values, np.ndarray):
-                    stats["h2d_bytes"] += dev_values.nbytes + dev_lengths.nbytes
-            else:
-                scan = self._scan(n_cols)
-                cols = np.zeros((cap, n_cols, pad), np.uint32)
-                for s, (blk, i, rg, preds) in enumerate(chunk):
-                    try:
-                        for c, (col_name, accept) in enumerate(preds["span_eq"]):
-                            cols[s, c, : rg.n_spans] = with_retries(
-                                lambda b=blk, j=i, r=rg, n=col_name: self._col(b, j, r, n))
-                            k = min(len(accept), self.max_codes)
-                            codes[s, c, :k] = accept[:k]
-                    except Exception as e:  # e.g. block deleted mid-query
-                        errors.append((blk, e))
-                        log.warning("mesh search: column load failed: %s", e)
-                        continue
-                    for c in range(len(preds["span_eq"]), n_cols):
-                        # unit has fewer predicates than the widest: accept-all
-                        codes[s, c, 0] = 0
-                    valid[s, : rg.n_spans] = True
-                    live.append(s)
-                with _dispatch_lock:
-                    masks, _totals = timed_dispatch(
-                        "mesh_scan", scan,
+                    stats["units_runspace"] += len(live)
+                    stats["h2d_bytes"] += codes.nbytes + valid.nbytes
+                    if isinstance(dev_values, np.ndarray):
+                        stats["h2d_bytes"] += dev_values.nbytes + dev_lengths.nbytes
+                else:
+                    scan = self._scan(n_cols)
+                    cols = np.zeros((cap, n_cols, pad), np.uint32)
+                    for s, (blk, i, rg, preds) in enumerate(chunk):
+                        try:
+                            for c, (col_name, accept) in enumerate(preds["span_eq"]):
+                                cols[s, c, : rg.n_spans] = with_retries(
+                                    lambda b=blk, j=i, r=rg, n=col_name: self._col(b, j, r, n))
+                                k = min(len(accept), self.max_codes)
+                                codes[s, c, :k] = accept[:k]
+                        except Exception as e:  # e.g. block deleted mid-query
+                            errors.append((blk, e))
+                            log.warning("mesh search: column load failed: %s", e)
+                            continue
+                        for c in range(len(preds["span_eq"]), n_cols):
+                            # unit has fewer predicates than the widest: accept-all
+                            codes[s, c, 0] = 0
+                        valid[s, : rg.n_spans] = True
+                        live.append(s)
+                    args = (
                         cols.reshape(self.w, self.r, n_cols, pad),
                         codes.reshape(self.w, self.r, n_cols, self.max_codes),
                         valid.reshape(self.w, self.r, pad),
                     )
-                    masks_np = np.asarray(masks).reshape(cap, pad)
-                stats["h2d_bytes"] += cols.nbytes + codes.nbytes + valid.nbytes
-            stats["dispatches"] += 1
-            stats["units_scanned"] += len(live)
-            stats["collectives"] += 1  # psum of the per-window hit count
-            stats["d2h_bytes"] += masks_np.nbytes
-            stats["per_shard_rows"] += valid.sum(axis=1)
-            for s in live:
-                blk, i, rg, preds = chunk[s]
-                resp.inspected_traces += rg.n_traces
-                span_mask = masks_np[s, : rg.n_spans].copy()
-                if not span_mask.any():
-                    continue
-                try:
-                    # idempotent under retry: hit dedupe rides seen_ids
-                    with_retries(lambda b=blk, j=i, r=rg, p=preds, m=span_mask:
-                                 collect(b, j, r, p, m))
-                except Exception as e:
-                    errors.append((blk, e))
-                    log.warning("mesh search: hit collection failed: %s", e)
-                if done:
-                    return
+                    stats["h2d_bytes"] += cols.nbytes + codes.nbytes + valid.nbytes
+            with clock.dispatching(_dispatch_lock):
+                # host arrays go in raw: the timed_dispatch seam ships them
+                # itself, so h2d bytes + transfer time are measured where
+                # they happen; resident (device) payloads ship nothing and
+                # are counted as such
+                masks, _totals = timed_dispatch(kernel, scan, *args)
+            shard_rows = valid.sum(axis=1)
+            count_dispatch(kernel, len(live), shard_rows, pad, 4 * self.w)
+            with clock.phase("collect"):
+                # the sharded hit masks come home here, outside the lock: the
+                # dispatch has materialized, this is a copy and no collective
+                masks_np = np.asarray(masks).reshape(cap, pad)
+                stats["dispatches"] += 1
+                stats["units_scanned"] += len(live)
+                stats["collectives"] += 1  # psum of the per-window hit count
+                stats["d2h_bytes"] += masks_np.nbytes
+                stats["per_shard_rows"] += shard_rows
+                for s in live:
+                    blk, i, rg, preds = chunk[s]
+                    resp.inspected_traces += rg.n_traces
+                    span_mask = masks_np[s, : rg.n_spans].copy()
+                    if not span_mask.any():
+                        continue
+                    try:
+                        # idempotent under retry: hit dedupe rides seen_ids
+                        with_retries(lambda b=blk, j=i, r=rg, p=preds, m=span_mask:
+                                     collect(b, j, r, p, m))
+                    except Exception as e:
+                        errors.append((blk, e))
+                        log.warning("mesh search: hit collection failed: %s", e)
+                    if done:
+                        return
 
         for blk in blocks:
             if done:
@@ -654,6 +669,14 @@ class MeshSearcher:
     # -- batched multi-query search --------------------------------------
     def search_blocks_multi(self, blocks, reqs, on_block_error=None,
                             on_block_ok=None) -> list:
+        """N queries' search of one job on the mesh: see
+        _search_blocks_multi; span and clock as search_blocks."""
+        with accounting.job("search_blocks") as clock:
+            return self._search_blocks_multi(blocks, reqs, on_block_error,
+                                             on_block_ok, clock)
+
+    def _search_blocks_multi(self, blocks, reqs, on_block_error, on_block_ok,
+                             clock) -> list:
         """N concurrent queries over the SAME block list, coalesced: each
         (block, row-group) unit's rle run payload is stacked ONCE (or
         served straight from the device-resident hot tier) and every
@@ -775,65 +798,67 @@ class MeshSearcher:
                             host_unit(q, blk, i, rg, preds_q[q])
             if not units or all(done):
                 return
-            n_cols = max(1, max(len(u[6]) for u in units))
-            pad = self.bucket_for(max(u[2].n_spans for u in units))
-            pkeys = tuple(tuple(e.resident_key() for e in u[5]) for u in units)
-            skey = ("mesh_stack", pkeys, n_cols, pad)
-            res = tier.get(skey) if tier is not None else None
-            loaded = [True] * len(units)
-            if res is not None:
-                run_pad = int(res.meta["run_pad"])
-                dev_values = res.arrays["values"]
-                dev_lengths = res.arrays["lengths"]
-                tier.record_avoided(res.host_bytes, kernel="batched_rle_scan")
-            else:
-                max_runs = 8
-                unit_runs: list = []
+            clock.lap("plan", "batched_rle_scan")
+            with clock.phase("stack"):
+                n_cols = max(1, max(len(u[6]) for u in units))
+                pad = self.bucket_for(max(u[2].n_spans for u in units))
+                pkeys = tuple(tuple(e.resident_key() for e in u[5]) for u in units)
+                skey = ("mesh_stack", pkeys, n_cols, pad)
+                res = tier.get(skey) if tier is not None else None
+                loaded = [True] * len(units)
+                if res is not None:
+                    run_pad = int(res.meta["run_pad"])
+                    dev_values = res.arrays["values"]
+                    dev_lengths = res.arrays["lengths"]
+                    tier.record_avoided(res.host_bytes, kernel="batched_rle_scan")
+                else:
+                    max_runs = 8
+                    unit_runs: list = []
+                    for s, u in enumerate(units):
+                        blk, i, rg = u[0], u[1], u[2]
+                        try:
+                            runs = [with_retries(e.runs) for e in u[5]]
+                        except Exception as e:
+                            errors.append((blk, e))
+                            log.warning("mesh multi-search: run load failed: %s", e)
+                            unit_runs.append(None)
+                            loaded[s] = False
+                            continue
+                        unit_runs.append(runs)
+                        for v, l in runs:
+                            max_runs = max(max_runs, len(l))
+                    run_pad = 1 << (max_runs - 1).bit_length()
+                    values = np.full((cap, n_cols, run_pad), NO_MATCH, np.uint32)
+                    lengths = np.zeros((cap, n_cols, run_pad), np.int32)
+                    for s, u in enumerate(units):
+                        if unit_runs[s] is None:
+                            continue
+                        rg = u[2]
+                        for c, (v, l) in enumerate(unit_runs[s]):
+                            values[s, c, : len(v)] = v.astype(np.uint32)
+                            lengths[s, c, : len(l)] = l
+                        for c in range(len(u[6]), n_cols):
+                            values[s, c, 0] = 0
+                            lengths[s, c, 0] = rg.n_spans
+                    dev_values = values.reshape(self.w, self.r, n_cols, run_pad)
+                    dev_lengths = lengths.reshape(self.w, self.r, n_cols, run_pad)
+                    pkeys_flat = [k for row in pkeys for k in row]
+                    if tier is not None and all(loaded) and pkeys_flat:
+                        tier.offer(skey, "rle_stack",
+                                   {"values": dev_values, "lengths": dev_lengths},
+                                   meta={"run_pad": run_pad},
+                                   host_bytes=values.nbytes + lengths.nbytes,
+                                   page_keys=pkeys_flat)
+                        got = tier.get(skey)
+                        if got is not None:
+                            dev_values = got.arrays["values"]
+                            dev_lengths = got.arrays["lengths"]
+                valid = np.zeros((cap, pad), bool)
                 for s, u in enumerate(units):
-                    blk, i, rg = u[0], u[1], u[2]
-                    try:
-                        runs = [with_retries(e.runs) for e in u[5]]
-                    except Exception as e:
-                        errors.append((blk, e))
-                        log.warning("mesh multi-search: run load failed: %s", e)
-                        unit_runs.append(None)
-                        loaded[s] = False
-                        continue
-                    unit_runs.append(runs)
-                    for v, l in runs:
-                        max_runs = max(max_runs, len(l))
-                run_pad = 1 << (max_runs - 1).bit_length()
-                values = np.full((cap, n_cols, run_pad), NO_MATCH, np.uint32)
-                lengths = np.zeros((cap, n_cols, run_pad), np.int32)
-                for s, u in enumerate(units):
-                    if unit_runs[s] is None:
-                        continue
-                    rg = u[2]
-                    for c, (v, l) in enumerate(unit_runs[s]):
-                        values[s, c, : len(v)] = v.astype(np.uint32)
-                        lengths[s, c, : len(l)] = l
-                    for c in range(len(u[6]), n_cols):
-                        values[s, c, 0] = 0
-                        lengths[s, c, 0] = rg.n_spans
-                dev_values = values.reshape(self.w, self.r, n_cols, run_pad)
-                dev_lengths = lengths.reshape(self.w, self.r, n_cols, run_pad)
-                pkeys_flat = [k for row in pkeys for k in row]
-                if tier is not None and all(loaded) and pkeys_flat:
-                    tier.offer(skey, "rle_stack",
-                               {"values": dev_values, "lengths": dev_lengths},
-                               meta={"run_pad": run_pad},
-                               host_bytes=values.nbytes + lengths.nbytes,
-                               page_keys=pkeys_flat)
-                    got = tier.get(skey)
-                    if got is not None:
-                        dev_values = got.arrays["values"]
-                        dev_lengths = got.arrays["lengths"]
-            valid = np.zeros((cap, pad), bool)
-            for s, u in enumerate(units):
-                if loaded[s]:
-                    valid[s, : u[2].n_spans] = True
-            scan = make_sharded_batched_rle_scan(
-                self.mesh, n_cols, self.max_codes, batch, pad)
+                    if loaded[s]:
+                        valid[s, : u[2].n_spans] = True
+                scan = make_sharded_batched_rle_scan(
+                    self.mesh, n_cols, self.max_codes, batch, pad)
             shipped_payload = isinstance(dev_values, np.ndarray)
             first_dispatch = True
             for g0 in range(0, nq, batch):
@@ -841,22 +866,23 @@ class MeshSearcher:
                 if not any(not done[q] and any(u[4][q] for u in units)
                            for q in lanes):
                     continue  # every query in this group is done/absent
-                codes = np.full((cap, batch, n_cols, self.max_codes),
-                                NO_MATCH, np.uint32)
-                live = np.zeros((cap, batch, n_cols), bool)
-                for s, u in enumerate(units):
-                    if not loaded[s]:
-                        continue
-                    preds_q, want, cols = u[3], u[4], u[6]
-                    for j, q in enumerate(lanes):
-                        if not want[q] or done[q]:
+                with clock.phase("stack"):
+                    codes = np.full((cap, batch, n_cols, self.max_codes),
+                                    NO_MATCH, np.uint32)
+                    live = np.zeros((cap, batch, n_cols), bool)
+                    for s, u in enumerate(units):
+                        if not loaded[s]:
                             continue
-                        for name, accept in preds_q[q]["span_eq"]:
-                            c = cols.index(name)
-                            k = min(len(accept), self.max_codes)
-                            codes[s, j, c, :k] = accept[:k]
-                            live[s, j, c] = True
-                with _dispatch_lock:
+                        preds_q, want, cols = u[3], u[4], u[6]
+                        for j, q in enumerate(lanes):
+                            if not want[q] or done[q]:
+                                continue
+                            for name, accept in preds_q[q]["span_eq"]:
+                                c = cols.index(name)
+                                k = min(len(accept), self.max_codes)
+                                codes[s, j, c, :k] = accept[:k]
+                                live[s, j, c] = True
+                with clock.dispatching(_dispatch_lock):
                     masks, _totals = timed_dispatch(
                         "batched_rle_scan", scan,
                         dev_values,
@@ -866,45 +892,49 @@ class MeshSearcher:
                         live.reshape(self.w, self.r, batch, n_cols),
                         valid.reshape(self.w, self.r, pad),
                     )
+                shard_rows = valid.sum(axis=1)
+                count_dispatch("batched_rle_scan", sum(loaded), shard_rows, pad,
+                               4 * self.w * batch)
+                with clock.phase("collect"):
                     masks_np = np.asarray(masks).reshape(cap, batch, pad)
-                stats["dispatches"] += 1
-                stats["collectives"] += 1
-                active_lanes = sum(
-                    1 for q in lanes if not done[q]
-                    and any(u[4][q] for u in units))
-                stats["query_lanes"] += active_lanes
-                batched_lanes_total.inc(active_lanes)
-                stats["d2h_bytes"] += masks_np.nbytes
-                stats["h2d_bytes"] += codes.nbytes + live.nbytes
-                if first_dispatch:
-                    stats["h2d_bytes"] += valid.nbytes
-                    if shipped_payload:
-                        stats["h2d_bytes"] += (dev_values.nbytes
-                                               + dev_lengths.nbytes)
-                    stats["units_scanned"] += sum(loaded)
-                    stats["units_runspace"] += sum(loaded)
-                    stats["per_shard_rows"] += valid.sum(axis=1)
-                first_dispatch = False
-                for s, u in enumerate(units):
-                    if not loaded[s]:
-                        continue
-                    blk, i, rg, preds_q, want = u[0], u[1], u[2], u[3], u[4]
-                    for j, q in enumerate(lanes):
-                        if not want[q] or done[q]:
+                    stats["dispatches"] += 1
+                    stats["collectives"] += 1
+                    active_lanes = sum(
+                        1 for q in lanes if not done[q]
+                        and any(u[4][q] for u in units))
+                    stats["query_lanes"] += active_lanes
+                    batched_lanes_total.inc(active_lanes)
+                    stats["d2h_bytes"] += masks_np.nbytes
+                    stats["h2d_bytes"] += codes.nbytes + live.nbytes
+                    if first_dispatch:
+                        stats["h2d_bytes"] += valid.nbytes
+                        if shipped_payload:
+                            stats["h2d_bytes"] += (dev_values.nbytes
+                                                   + dev_lengths.nbytes)
+                        stats["units_scanned"] += sum(loaded)
+                        stats["units_runspace"] += sum(loaded)
+                        stats["per_shard_rows"] += shard_rows
+                    first_dispatch = False
+                    for s, u in enumerate(units):
+                        if not loaded[s]:
                             continue
-                        resps[q].inspected_traces += rg.n_traces
-                        span_mask = masks_np[s, j, : rg.n_spans].copy()
-                        if not span_mask.any():
-                            continue
-                        try:
-                            with_retries(
-                                lambda qq=q, b=blk, jj=i, r=rg,
-                                p=preds_q[q], m=span_mask:
-                                collect(qq, b, jj, r, p, m))
-                        except Exception as e:
-                            errors.append((blk, e))
-                            log.warning(
-                                "mesh multi-search: hit collection failed: %s", e)
+                        blk, i, rg, preds_q, want = u[0], u[1], u[2], u[3], u[4]
+                        for j, q in enumerate(lanes):
+                            if not want[q] or done[q]:
+                                continue
+                            resps[q].inspected_traces += rg.n_traces
+                            span_mask = masks_np[s, j, : rg.n_spans].copy()
+                            if not span_mask.any():
+                                continue
+                            try:
+                                with_retries(
+                                    lambda qq=q, b=blk, jj=i, r=rg,
+                                    p=preds_q[q], m=span_mask:
+                                    collect(qq, b, jj, r, p, m))
+                            except Exception as e:
+                                errors.append((blk, e))
+                                log.warning(
+                                    "mesh multi-search: hit collection failed: %s", e)
                 if all(done):
                     return
 

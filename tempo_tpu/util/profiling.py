@@ -253,20 +253,26 @@ def reduce_capture(window_ns: float, annotations: list, programs: list | None,
                  whatever lies outside it is clipped away
     annotations  [(thread, name, start_ns, duration_ns)] of the host plane:
                  the intervals annotation() wrote
-    programs     [(name, start_ns, duration_ns)], one per program run (the
-                 device planes' `XLA Modules` lines); None without a device plane
-    ops          [(start_ns, duration_ns)], one per operation (`XLA Ops`)
+    programs     [(device, name, start_ns, duration_ns)], one per program run
+                 (the device planes' `XLA Modules` lines; `device` is the
+                 plane's name); None without a device plane
+    ops          [(device, start_ns, duration_ns)], one per operation (`XLA Ops`)
 
-    device    busy seconds (the union of `ops`, over every device plane) and
-              idle seconds of the window, and per program (trailing hash
-              cut) its runs and device seconds, split by whether the run
+    device    busy seconds (the union of `ops`, over every device plane: some
+              device ran) and idle seconds of the window; `per_device`, the
+              busy seconds of each plane by itself; and per program (trailing
+              hash cut) its runs and device seconds, split by whether the run
               overlaps a `dispatch/*` annotation. None without a device
               plane, and then so are `idle` and `alignment`.
     dispatch  per kernel: count, wall seconds (`dispatch/<kernel>`), transfer
               seconds (`transfer/<kernel>`), and device seconds: each run is
               given to the one dispatch it overlaps most and counted only
-              where it lies inside that dispatch, so device seconds never pass
-              the wall.
+              where it lies inside that dispatch, and runs of several devices
+              that overlap count once, so device seconds never pass the wall.
+              `skew_s`, only where a dispatch ran on several devices (a mesh
+              program): the latest minus the earliest, over its devices, of
+              the end of each device's last run inside it: how long the
+              first shard to finish was done before the last.
     idle      the complement of busy inside the window, partitioned; the
               labels sum to the idle seconds exactly. At every idle instant
               the time is split equally among the threads whose innermost
@@ -315,10 +321,10 @@ def reduce_capture(window_ns: float, annotations: list, programs: list | None,
     dispatches.sort()
     starts = [a for a, _, _ in dispatches]
     longest = max((b - a for a, b, _ in dispatches), default=0.0)
-    given: dict = {}  # dispatch index -> [(start, end)] of its runs, clipped
+    given: dict = {}  # dispatch index -> [(start, end, device)] of its runs, clipped
     per_program: dict = {}
     counted = aligned = early = 0
-    for name, a, d in programs:
+    for device, name, a, d in programs:
         a, b = max(0.0, a), min(window_ns, a + d)
         if b <= a:
             continue
@@ -341,16 +347,29 @@ def reduce_capture(window_ns: float, annotations: list, programs: list | None,
         row["device_s"] += (b - a) / 1e9
         if best is not None:
             da, db, _ = dispatches[best]
-            given.setdefault(best, []).append((max(a, da), min(b, db)))
+            given.setdefault(best, []).append((max(a, da), min(b, db), device))
     for i, runs in given.items():
-        table[dispatches[i][2]]["device_s"] += sum(b - a for a, b in _union(runs)) / 1e9
+        row = table[dispatches[i][2]]
+        row["device_s"] += sum(b - a for a, b in _union([r[:2] for r in runs])) / 1e9
+        last: dict = {}  # device -> the end of its last run inside this dispatch
+        for _, b, device in runs:
+            last[device] = max(b, last.get(device, b))
+        if len(last) > 1:
+            row["skew_s"] = (row.get("skew_s", 0.0)
+                             + (max(last.values()) - min(last.values())) / 1e9)
 
-    busy = _union([(max(0.0, a), min(window_ns, a + d)) for a, d in ops or []])
+    by_device: dict = {}
+    for device, a, d in ops or []:
+        by_device.setdefault(device, []).append((max(0.0, a), min(window_ns, a + d)))
+    busy = _union([iv for ivs in by_device.values() for iv in ivs])
     busy_ns = sum(b - a for a, b in busy)
     idle = _partition_idle(window_ns, busy, by_thread)
     summary["device"] = {
         "busy_s": busy_ns / 1e9,
         "idle_s": (window_ns - busy_ns) / 1e9,
+        "per_device": [{"device": device,
+                        "busy_s": sum(b - a for a, b in _union(ivs)) / 1e9}
+                       for device, ivs in sorted(by_device.items())],
         "programs": [{"program": p, "inside": i, **row}
                      for (p, i), row in sorted(per_program.items())],
     }
@@ -389,17 +408,19 @@ def read_capture(out_dir: str) -> tuple:
             programs, ops = programs or [], ops or []
             for line in plane.lines:
                 if line.name == "XLA Modules":
-                    programs += [(e.name, float(e.start_ns), float(e.duration_ns))
+                    programs += [(plane.name, e.name, float(e.start_ns), float(e.duration_ns))
                                  for e in line.events]
                 elif line.name == "XLA Ops":
-                    ops += [(float(e.start_ns), float(e.duration_ns)) for e in line.events]
+                    ops += [(plane.name, float(e.start_ns), float(e.duration_ns))
+                            for e in line.events]
     if armed is None:
         raise ValueError(f"{path} holds no {ARMED}")
     t0, window_ns = armed
     return (window_ns,
             [(thread, name, a - t0, d) for thread, name, a, d in annotations],
-            None if programs is None else [(name, a - t0, d) for name, a, d in programs],
-            None if ops is None else [(a - t0, d) for a, d in ops])
+            None if programs is None else [(dev, name, a - t0, d)
+                                           for dev, name, a, d in programs],
+            None if ops is None else [(dev, a - t0, d) for dev, a, d in ops])
 
 
 # the capture's counters: every tempo_tpu_profile_* family grows only when a
@@ -423,6 +444,12 @@ dispatches_total = metrics.counter(
     "tempo_tpu_profile_dispatches_total",
     "dispatch/* annotations inside captures, by kernel",
 )
+mesh_skew_seconds_total = metrics.counter(
+    "tempo_tpu_profile_mesh_skew_seconds_total",
+    "Shard skew of the dispatches inside captures that ran on several "
+    "devices, by kernel: latest minus earliest end, over the devices, of each "
+    "device's last program run inside the dispatch",
+)
 
 
 def _count(summary: dict) -> None:
@@ -434,6 +461,8 @@ def _count(summary: dict) -> None:
         dispatch_wall_seconds_total.inc(row["wall_s"], kernel=kernel)
         dispatch_device_seconds_total.inc(row["device_s"], kernel=kernel)
         dispatches_total.inc(row["count"], kernel=kernel)
+        if "skew_s" in row:  # only a dispatch that ran on several devices has one
+            mesh_skew_seconds_total.inc(row["skew_s"], kernel=kernel)
 
 
 # ---------------------------------------------------------------------------

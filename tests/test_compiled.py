@@ -308,6 +308,30 @@ class TestExecutableReuse:
         for p, w in zip(plans, wires):
             assert w["series"] == _interp_wire(db, metas, p)["series"]
 
+    @pytest.mark.parametrize("cap,launches", [(2, 3), (4, 2), (16, 1)])
+    def test_a_job_of_more_units_than_a_launch_takes_is_cut(
+            self, fresh_cache, monkeypatch, cap, launches):
+        """The program is traced for its unit count and compiles the
+        longer the more units it holds (executor.MAX_UNITS): a codec
+        group goes in launches of at most that many, and integer adds
+        make the counts the interpreter's whatever the cut."""
+        from tempo_tpu.compiled import executor
+
+        db = TempoDB(DBConfig(backend="mock"), raw_backend=MockBackend())
+        for i in range(5):  # five one-row-group blocks of one codec group
+            b = synth.make_batch(64, 8, seed=300 + i)
+            b.cols["service"] = np.sort(b.cols["service"].copy())
+            db.write_batch("t", b.sorted_by_trace())
+        metas = list(db.blocklist.metas("t"))
+        plan = _plan("{ resource.service.name = `cart` && duration > 100us } | rate()")
+        monkeypatch.setattr(executor, "MAX_UNITS", cap)
+        d0 = devicetiming.dispatch_total.total(kernel="compiled_metrics")
+        wire = compiled.try_query_range(db, "t", plan, metas)
+        d1 = devicetiming.dispatch_total.total(kernel="compiled_metrics")
+        assert wire is not None and wire["series"]
+        assert wire["series"] == _interp_wire(db, metas, plan)["series"]
+        assert d1 - d0 == launches
+
     def test_batched_multi_matches_sequential(self, corpus, fresh_cache):
         db, metas = corpus
         qr = Querier(db)

@@ -458,15 +458,18 @@ class TestSearchWaterfall:
             assert "queue_wait" in resp.stage_seconds
             assert "admission" in resp.stage_seconds
             assert "fetch" in resp.stage_seconds  # block IO attributed
-            total = sum(resp.stage_seconds.values())
-            # stage times account for wall clock without double counting
-            # (exclusive nesting). On an idle host the sum lands within
-            # ~10% of wall (verified by the e2e drive); here the lower
-            # bound is loose because a saturated CI host deschedules
-            # threads in gaps no stage owns, and a flaking timing bound
-            # teaches people to ignore the gate
-            assert total <= wall * 1.25
-            assert total >= wall * 0.25
+            assert set(resp.stage_seconds) <= set(stagetimings.STAGES)
+            assert all(v > 0 for v in resp.stage_seconds.values())
+            # the sums' own identity, whatever the host's scheduling
+            # (no margin on a sandbox's clock: ROADMAP C11): stages nest
+            # exclusively, the one worker runs the jobs one after the
+            # other, and admission and merge come before and after them,
+            # so every stage but the queue wait is an interval of its
+            # own inside the request and together they cannot pass its
+            # wall. (A saturated host deschedules threads in gaps no
+            # stage owns, so there is no lower bound to hold.)
+            work = sum(v for k, v in resp.stage_seconds.items() if k != "queue_wait")
+            assert work <= wall
         finally:
             app.shutdown()
 
@@ -669,6 +672,7 @@ class TestHTTPPropagation:
 # ---------------------------------------------------------------------------
 
 MS = 1e6  # the reducer's inputs are nanoseconds
+D0 = "/device:TPU:0"  # the one device plane of the one-chip cases
 
 
 class TestReduceCapture:
@@ -685,11 +689,11 @@ class TestReduceCapture:
         (1, "transfer/pallas_in_set", 46 * MS, 4 * MS),
     ]
     PROGRAMS = [
-        ("jit__scan(123456789)", 52 * MS, 6 * MS),      # inside the dispatch
-        ("jit__scan(987654321)", 64 * MS, 3 * MS),      # 1 ms inside, 2 ms past its end
-        ("jit_block_sketch_build(42)", 95 * MS, 2 * MS),  # no dispatch near it
+        (D0, "jit__scan(123456789)", 52 * MS, 6 * MS),      # inside the dispatch
+        (D0, "jit__scan(987654321)", 64 * MS, 3 * MS),      # 1 ms inside, 2 ms past its end
+        (D0, "jit_block_sketch_build(42)", 95 * MS, 2 * MS),  # no dispatch near it
     ]
-    OPS = [(52 * MS, 6 * MS), (64 * MS, 3 * MS), (95 * MS, 2 * MS)]
+    OPS = [(D0, 52 * MS, 6 * MS), (D0, 64 * MS, 3 * MS), (D0, 95 * MS, 2 * MS)]
 
     def reduce(self, **kw):
         from tempo_tpu.util.profiling import reduce_capture
@@ -746,7 +750,8 @@ class TestReduceCapture:
         # 6 ms of the first run + the 1 ms of the second that lies inside
         assert row["device_s"] == pytest.approx(0.007)
         # a program longer than its dispatch is clipped to it
-        s = self.reduce(programs=[("jit__scan(1)", 40 * MS, 50 * MS)], ops=[(40 * MS, 50 * MS)])
+        s = self.reduce(programs=[(D0, "jit__scan(1)", 40 * MS, 50 * MS)],
+                        ops=[(D0, 40 * MS, 50 * MS)])
         row = s["dispatch"]["pallas_in_set"]
         assert row["device_s"] == pytest.approx(row["wall_s"])
 
@@ -760,19 +765,19 @@ class TestReduceCapture:
         a = self.reduce()["alignment"]
         assert (a["runs"], a["aligned"], a["early"]) == (3, 2, 0)
         # a run that starts within 1 ms after its dispatch closed still counts
-        a = self.reduce(programs=[("jit__scan(1)", 65.5 * MS, 1 * MS)])["alignment"]
+        a = self.reduce(programs=[(D0, "jit__scan(1)", 65.5 * MS, 1 * MS)])["alignment"]
         assert a["share"] == 1.0
-        a = self.reduce(programs=[("jit__scan(1)", 66.5 * MS, 1 * MS)])["alignment"]
+        a = self.reduce(programs=[(D0, "jit__scan(1)", 66.5 * MS, 1 * MS)])["alignment"]
         assert (a["share"], a["early"]) == (0.0, 0)
         # one the device stamped just before its dispatch opened does not:
         # it is counted apart, as `early` (the device's stamps lead)
-        s = self.reduce(programs=[("jit__scan(1)", 44.5 * MS, 0.25 * MS)])
+        s = self.reduce(programs=[(D0, "jit__scan(1)", 44.5 * MS, 0.25 * MS)])
         assert (s["alignment"]["share"], s["alignment"]["early"]) == (0.0, 1)
         assert s["device"]["programs"][0]["inside"] == "none"
         # early, but it reaches into the dispatch: aligned, and not early
-        a = self.reduce(programs=[("jit__scan(1)", 44.5 * MS, 1 * MS)])["alignment"]
+        a = self.reduce(programs=[(D0, "jit__scan(1)", 44.5 * MS, 1 * MS)])["alignment"]
         assert (a["share"], a["early"]) == (1.0, 0)
-        a = self.reduce(programs=[("jit__scan(1)", 42 * MS, 1 * MS)])["alignment"]
+        a = self.reduce(programs=[(D0, "jit__scan(1)", 42 * MS, 1 * MS)])["alignment"]
         assert (a["share"], a["early"]) == (0.0, 0)
 
     def test_what_lies_outside_the_window_is_clipped_away(self):
@@ -782,9 +787,9 @@ class TestReduceCapture:
         s = self.reduce(
             annotations=[(0, "stage/fetch", -10 * MS, 30 * MS),
                          (0, "stage/merge", 120 * MS, 5 * MS)],
-            programs=[("jit__scan(1)", -5 * MS, 8 * MS), ("jit__scan(2)", 98 * MS, 6 * MS),
-                      ("jit__scan(3)", 101 * MS, 1 * MS)],
-            ops=[(-5 * MS, 8 * MS), (98 * MS, 6 * MS), (101 * MS, 1 * MS)])
+            programs=[(D0, "jit__scan(1)", -5 * MS, 8 * MS), (D0, "jit__scan(2)", 98 * MS, 6 * MS),
+                      (D0, "jit__scan(3)", 101 * MS, 1 * MS)],
+            ops=[(D0, -5 * MS, 8 * MS), (D0, 98 * MS, 6 * MS), (D0, 101 * MS, 1 * MS)])
         assert s["device"]["busy_s"] == pytest.approx(0.005)  # 0-3 and 98-100 ms
         assert s["idle"]["stage/fetch"] == pytest.approx(0.017)  # 0-20 ms less 3 busy
         assert "stage/merge" not in s["idle"]
@@ -797,6 +802,71 @@ class TestReduceCapture:
         s = self.reduce(programs=None, ops=None)
         assert s["device"] is None and s["idle"] is None and s["alignment"] is None
         assert s["dispatch"]["pallas_in_set"]["count"] == 1
+
+    def test_one_device_plane_reads_as_before_and_has_no_skew(self):
+        s = self.reduce()
+        assert s["device"]["per_device"] == [
+            {"device": D0, "busy_s": pytest.approx(s["device"]["busy_s"])}]
+        assert all("skew_s" not in row for row in s["dispatch"].values())
+
+    # four device planes under one mesh dispatch of 20 ms (40-60 ms): every
+    # shard starts at 42 ms, shard k ends 2 ms after shard k-1; then a second
+    # dispatch (70-80 ms) whose runs end together, and one run on shard 3 alone
+    MESH = [f"/device:TPU:{k}" for k in range(4)]
+    MESH_ANNOTATIONS = [
+        (0, "mesh/search_blocks", 30 * MS, 60 * MS),
+        (0, "mesh/stack", 32 * MS, 6 * MS),
+        (0, "dispatch/mesh_rle_scan", 40 * MS, 20 * MS),
+        (0, "mesh/collect", 61 * MS, 5 * MS),
+        (0, "dispatch/mesh_rle_scan", 70 * MS, 10 * MS),
+        (1, "mesh/wait", 45 * MS, 15 * MS),
+    ]
+    MESH_PROGRAMS = (
+        [(d, "jit_step(7)", 42 * MS, (4 + 2 * k) * MS) for k, d in enumerate(MESH)]
+        + [(d, "jit_step(7)", 72 * MS, 3 * MS) for d in MESH]
+        + [(MESH[3], "jit_convert(9)", 50 * MS, 1 * MS)])  # a second run, before its last
+    MESH_OPS = [(d, a, n) for d, _, a, n in MESH_PROGRAMS]
+
+    def reduce_mesh(self):
+        return self.reduce(annotations=self.MESH_ANNOTATIONS, programs=self.MESH_PROGRAMS,
+                           ops=self.MESH_OPS)
+
+    def test_four_planes_each_have_their_busy_seconds(self):
+        dev = self.reduce_mesh()["device"]
+        assert [d["device"] for d in dev["per_device"]] == self.MESH
+        # 4, 6, 8, 10 ms of the first dispatch + 3 ms of the second; shard 3's
+        # extra run lies inside its long one
+        assert [d["busy_s"] for d in dev["per_device"]] == [
+            pytest.approx(x) for x in (0.007, 0.009, 0.011, 0.013)]
+        # some device ran: 42-52 and 72-75 ms
+        assert dev["busy_s"] == pytest.approx(0.013)
+        assert dev["busy_s"] <= sum(d["busy_s"] for d in dev["per_device"])
+
+    def test_a_mesh_dispatchs_device_seconds_never_pass_its_wall(self):
+        row = self.reduce_mesh()["dispatch"]["mesh_rle_scan"]
+        assert (row["count"], row["wall_s"]) == (2, pytest.approx(0.030))
+        # runs of four devices that overlap count once: 10 ms + 3 ms
+        assert row["device_s"] == pytest.approx(0.013)
+        assert row["device_s"] <= row["wall_s"]
+
+    def test_skew_is_latest_end_less_earliest_end_over_the_devices(self):
+        row = self.reduce_mesh()["dispatch"]["mesh_rle_scan"]
+        # first dispatch: shards end at 46, 48, 50, 52 ms (shard 3's extra run
+        # ends at 51, before its last); the second's end together
+        assert row["skew_s"] == pytest.approx(0.006)
+
+    def test_with_four_planes_the_idle_labels_still_sum_to_the_idle_seconds(self):
+        s = self.reduce_mesh()
+        assert s["device"]["idle_s"] == pytest.approx(0.087)
+        assert sum(s["idle"].values()) == pytest.approx(s["device"]["idle_s"], rel=1e-12)
+        # the mesh path's host work is named: stack 32-38 ms, collect 61-66 ms,
+        # the job's own self time 30-32, 38-40, 60-61, 66-70 and 80-90 ms
+        assert s["idle"]["mesh/stack"] == pytest.approx(0.006)
+        assert s["idle"]["mesh/collect"] == pytest.approx(0.005)
+        assert s["idle"]["mesh/search_blocks"] == pytest.approx(0.019)
+        # a thread waiting for the dispatch lock is blamed only when no other
+        # works: thread 0 is inside an annotation for all of 45-60 ms
+        assert s["idle"]["mesh/wait"] == 0.0
 
 
 class TestSeamsWithoutACapture:
