@@ -295,7 +295,10 @@ class Child:
     def get_json(self, path: str, params: dict | None = None):
         if params:
             path += "?" + urllib.parse.urlencode(params)
-        status, body = self.request("GET", path)
+        try:
+            status, body = self.request("GET", path)
+        except urllib.error.HTTPError as e:  # urlopen raises on 4xx/5xx
+            raise SmokeFailure(f"GET {path} -> {e.code}") from e
         check(status == 200, f"GET {path} -> {status}")
         return json.loads(body)
 

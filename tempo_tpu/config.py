@@ -273,6 +273,12 @@ def check_config(cfg: Config) -> list[str]:
             "ingester.complete_block_timeout_s < storage.trace.blocklist_poll_s: "
             "queriers may miss traces between ingester handoff and blocklist poll"
         )
+    if app.db.compaction.compacted_retention_s < 2 * app.db.blocklist_poll_s:
+        warnings.append(
+            "storage.trace.compaction.compacted_retention_s < 2 x blocklist_poll_s: "
+            "trace-by-ID reads a compacted block for two polls after its compaction, "
+            "until every querier has polled the output; retention may clear it first"
+        )
     if app.remote_write is not None and app.remote_write.endpoint and not app.generator_enabled:
         warnings.append("metrics_generator.remote_write set but the generator is disabled")
     if app.resource.hard_watermark <= app.resource.soft_watermark:

@@ -44,6 +44,12 @@ orphans_swept = metrics.counter(
     "Meta-less partial blocks (crash between data and meta.json) deleted "
     "by the startup/maintenance orphan sweep",
 )
+find_block_probes = metrics.counter(
+    "tempodb_find_block_probes_total",
+    "Blocks a trace-by-ID lookup opened: those, live or compacted within "
+    "two polls, that passed the trace-ID range, time and block-ID shard "
+    "tests of TempoDB.find",
+)
 
 
 @dataclass
@@ -294,8 +300,10 @@ class TempoDB:
              block_start: str = "0" * 32, block_end: str = "f" * 32,
              time_start: int = 0, time_end: int = 0) -> Trace | None:
         """Trace-by-ID across blocks (reference: tempodb.Find:272 with
-        includeBlock shard-range + time filtering :494-517; self-traced
-        like the reference's tempodb.go:276 span). Partial traces from
+        includeBlock :494-517: the trace ID inside the block's
+        [min_id, max_id], the block's own ID inside the shard
+        [block_start, block_end], plus time filtering; self-traced like
+        the reference's tempodb.go:276 span). Partial traces from
         multiple blocks are combined."""
         with tracing.span("tempodb/find", tenant=tenant):
             return self._find_traced(tenant, trace_id, block_start, block_end,
@@ -304,12 +312,29 @@ class TempoDB:
     def _find_traced(self, tenant, trace_id, block_start, block_end,
                      time_start, time_end) -> Trace | None:
         hex_id = trace_id.hex().rjust(32, "0")
-        metas = [
-            m for m in self.blocklist.metas(tenant)
-            if m.min_id <= hex_id <= m.max_id
-            and _overlaps(m, time_start, time_end)
-            and _in_shard(m, block_start, block_end)
+
+        def include(m):
+            return (m.min_id <= hex_id <= m.max_id
+                    and _overlaps(m, time_start, time_end)
+                    and _in_shard(m, block_start, block_end))
+
+        metas = [m for m in self.blocklist.metas(tenant) if include(m)]
+        # A find is the union of its shard jobs, each with its own view
+        # of the list (another moment, maybe another querier), and a
+        # compaction swaps its inputs for an output whose ID lies in
+        # another slice. So the inputs stay candidates for two polls
+        # after the swap, the time in which every querier comes to see
+        # the output (reference: includeCompactedBlock tempodb.go:519).
+        # Live list first: a swap between the two reads then shows an
+        # input twice, never not at all.
+        lookback = time.time() - 2 * self.cfg.blocklist_poll_s
+        live = {m.block_id for m in metas}
+        metas += [
+            c.meta for c in self.blocklist.compacted_metas(tenant)
+            if c.compacted_time >= lookback and c.meta.block_id not in live
+            and include(c.meta)
         ]
+        find_block_probes.inc(len(metas))
 
         def job(meta):
             with tracing.span("tempodb/find_block", block=str(meta.block_id)):
@@ -324,8 +349,9 @@ class TempoDB:
         if fatal:
             # a failed block read could hide spans of this trace; surface it
             # rather than return a silently incomplete trace (NotFound is
-            # the benign deleted-by-compaction race: that data lives in
-            # the compaction output, which is also in the list)
+            # the benign cleared-after-compaction race: that data lives
+            # in the compaction output, which the shard job of the
+            # output's own slice reads)
             raise fatal[0]
         return combine_traces([r for r in results if r is not None])
 
@@ -757,6 +783,10 @@ def _overlaps(meta, start: int, end: int) -> bool:
 
 
 def _in_shard(meta, block_start: str, block_end: str) -> bool:
-    """Block's [min,max] ID range intersects the queried blockID shard
-    (frontend trace-by-ID sharding, reference: tracebyidsharding.go:228)."""
-    return meta.max_id >= block_start and meta.min_id <= block_end
+    """The block's own ID, as 32 hex digits, lies in the queried block-ID
+    shard (frontend trace-by-ID sharding, reference: the blockID half of
+    includeBlock tempodb.go:494-517). A shard is half-open, and closed
+    where it ends at the top of the ID space, so the slices of
+    create_block_boundaries select every block exactly once."""
+    block_id = meta.block_id.replace("-", "")
+    return block_start <= block_id and (block_id < block_end or block_end == "f" * 32)

@@ -115,6 +115,11 @@ class TestCheckConfig:
         assert any("quorum" in w for w in warnings)
         assert any("object-store round trip" in w for w in warnings)
 
+    def test_warns_when_retention_clears_a_compacted_block_a_find_still_reads(self):
+        cfg = Config()
+        cfg.app.db.compaction.compacted_retention_s = 2 * cfg.app.db.blocklist_poll_s - 1
+        assert any("compacted_retention_s" in w for w in check_config(cfg))
+
     def test_clean_config_has_no_warnings(self):
         assert check_config(Config()) == []
 

@@ -7,12 +7,14 @@ import time
 import numpy as np
 import pytest
 
-from tempo_tpu.db import DBConfig, TempoDB
+from tempo_tpu.backend.base import CompactedBlockMeta
+from tempo_tpu.db import DBConfig, TempoDB, find_block_probes
 from tempo_tpu.db.compaction import CompactionConfig, TimeWindowBlockSelector
 from tempo_tpu.db.pool import JobPool
 from tempo_tpu.encoding.common import BlockConfig, SearchRequest
 from tempo_tpu.model import synth
 from tempo_tpu.model import trace as tr
+from tempo_tpu.modules.frontend import create_block_boundaries
 
 
 def make_db(tmp_path, **kw):
@@ -68,14 +70,20 @@ class TestWriteFind:
     def test_shard_range_pruning(self, tmp_path):
         db = make_db(tmp_path)
         traces = synth.make_traces(10, seed=6)
-        write_traces(db, "tenant", traces)
+        meta = write_traces(db, "tenant", traces)
         tid = traces[0].trace_id
-        hex_id = tid.hex()
-        # a shard range that excludes the trace must not find it
-        lo = "0" * 32
-        hi = format(int(hex_id, 16) - 1, "032x")
-        assert db.find("tenant", tid, block_start=lo, block_end=hi) is None
-        assert db.find("tenant", tid, block_start=hex_id, block_end="f" * 32) is not None
+        block_hex = meta.block_id.replace("-", "")
+        # a shard is a slice of the BLOCK-ID space, half-open: one that
+        # ends at the block's own ID must not open the block, wherever
+        # the trace ID lies, and the slice that starts there must
+        lo, hi = "0" * 32, "f" * 32
+        assert db.find("tenant", tid, block_start=lo, block_end=block_hex) is None
+        assert db.find("tenant", tid, block_start=block_hex, block_end=hi) is not None
+        # a cut at the trace ID (the old reading of the bounds) prunes nothing
+        cut = format(int(tid.hex(), 16) - 1, "032x")
+        below = db.find("tenant", tid, block_start=lo, block_end=cut)
+        above = db.find("tenant", tid, block_start=cut, block_end=hi)
+        assert (below is None) != (above is None)
 
 
 class TestSearchEngine:
@@ -225,6 +233,128 @@ class TestCompactionEngine:
         assert len(group) == 1 or sum(m.total_objects for m in group) <= 15
 
 
+class TestFindAcrossCompaction:
+    """A find is the union of its shard jobs, each with its own snapshot
+    of the blocklist, and the shards partition the blocks by block ID.
+    A compaction swaps inputs {A, B} for an output C of another slice,
+    so the inputs stay candidates for two polls after the swap
+    (reference: includeCompactedBlock tempodb.go:519)."""
+
+    TENANT = "tenant"
+    N_SHARDS = 4
+    # A in the first slice, B in the last: C's slice differs from one of them
+    BLOCK_IDS = ("10000000-0000-4000-8000-00000000000a",
+                 "f0000000-0000-4000-8000-00000000000b")
+
+    def _compacted_store(self, tmp_path):
+        """-> (whole trace, a querier's db whose list still holds {A, B},
+        the compactor's db that swapped them for C on the same backend)"""
+        t = synth.make_trace(seed=3, n_spans=10)
+        spans = list(t.all_spans())
+        resource = t.batches[0][0]
+        halves = [tr.Trace(trace_id=t.trace_id, batches=[(resource, spans[:6])]),
+                  tr.Trace(trace_id=t.trace_id, batches=[(resource, spans[4:])])]
+        querier = make_db(tmp_path)
+        for j, (half, block_id) in enumerate(zip(halves, self.BLOCK_IDS)):
+            batch = tr.traces_to_batch([half] + synth.make_traces(4, seed=70 + j))
+            querier.write_batch(self.TENANT, batch.sorted_by_trace(), block_id=block_id)
+        compactor = make_db(tmp_path)
+        compactor.poll_now()
+        assert compactor.compact_once(self.TENANT) == 1
+        assert [m.block_id for m in querier.blocklist.metas(self.TENANT)] == list(self.BLOCK_IDS)
+        (out,) = compactor.blocklist.metas(self.TENANT)
+        assert out.block_id not in self.BLOCK_IDS
+        return t, querier, compactor
+
+    def _swap(self, how, querier, compactor):
+        if how == "poll":
+            querier.poll_now()
+        else:  # what compaction.py does to the compactor's own list
+            querier.blocklist.update(
+                self.TENANT,
+                adds=compactor.blocklist.metas(self.TENANT),
+                removes=querier.blocklist.metas(self.TENANT),
+                compacted_adds=compactor.blocklist.compacted_metas(self.TENANT))
+
+    def _shards(self):
+        bounds = create_block_boundaries(self.N_SHARDS)
+        return list(zip(bounds, bounds[1:]))
+
+    @staticmethod
+    def _span_ids(traces):
+        got = tr.combine_traces([t for t in traces if t is not None])
+        return None if got is None else sorted(s.span_id for s in got.all_spans())
+
+    @pytest.mark.parametrize("how", ["update", "poll"])
+    @pytest.mark.parametrize("order", ["ascending", "descending"])
+    def test_blocklist_swap_between_the_shard_jobs_of_one_find(self, tmp_path, how, order):
+        t, querier, compactor = self._compacted_store(tmp_path)
+        shards = self._shards()
+        if order == "descending":
+            shards.reverse()
+        want = sorted(s.span_id for s in t.all_spans())
+        for swap_before in range(self.N_SHARDS + 1):
+            # every round starts from the list of before the compaction
+            querier.blocklist.apply_poll_results(
+                {self.TENANT: [c.meta for c in compactor.blocklist.compacted_metas(self.TENANT)]}, {})
+            parts = []
+            for i, (lo, hi) in enumerate(shards):
+                if i == swap_before:
+                    self._swap(how, querier, compactor)
+                parts.append(querier.find(self.TENANT, t.trace_id, block_start=lo, block_end=hi))
+            assert self._span_ids(parts) == want, (how, order, swap_before)
+
+    @pytest.mark.parametrize("how", ["update", "poll"])
+    def test_shard_jobs_spread_over_a_stale_and_a_fresh_querier(self, tmp_path, how):
+        t, stale, compactor = self._compacted_store(tmp_path)
+        if how == "update":
+            fresh = compactor  # the all-in-one that ran the compaction
+        else:
+            fresh = make_db(tmp_path)  # a querier that polled after it
+            fresh.poll_now()
+        assert len(fresh.blocklist.metas(self.TENANT)) == 1
+        want = sorted(s.span_id for s in t.all_spans())
+        shards = self._shards()
+        for assignment in range(1 << self.N_SHARDS):
+            parts = [
+                (fresh if assignment >> i & 1 else stale).find(
+                    self.TENANT, t.trace_id, block_start=lo, block_end=hi)
+                for i, (lo, hi) in enumerate(shards)
+            ]
+            assert self._span_ids(parts) == want, (how, bin(assignment))
+
+    @pytest.mark.parametrize("how", ["update", "poll"])
+    def test_blocklist_swap_between_the_two_reads_of_one_shard_job(self, tmp_path, monkeypatch, how):
+        """The live list is read first: a swap before the compacted list
+        is read shows an input in both, and it is opened once."""
+        t, querier, compactor = self._compacted_store(tmp_path)
+        live = querier.blocklist.metas(self.TENANT)
+        monkeypatch.setattr(querier.blocklist, "metas", lambda tenant: live)
+        self._swap(how, querier, compactor)
+        probes = find_block_probes.value()
+        got = querier.find(self.TENANT, t.trace_id)
+        assert self._span_ids([got]) == sorted(s.span_id for s in t.all_spans())
+        assert find_block_probes.value() - probes == 2
+
+    def test_compacted_inputs_leave_the_candidates_after_two_polls(self, tmp_path):
+        t, _, db = self._compacted_store(tmp_path)
+        want = sorted(s.span_id for s in t.all_spans())
+        probes = find_block_probes.value()
+        assert self._span_ids([db.find(self.TENANT, t.trace_id)]) == want
+        assert find_block_probes.value() - probes == 3  # C and, for now, A and B
+        # the same list two polls later: every querier has seen C by then
+        aged = [
+            CompactedBlockMeta(meta=c.meta,
+                               compacted_time=c.compacted_time - 2 * db.cfg.blocklist_poll_s - 1)
+            for c in db.blocklist.compacted_metas(self.TENANT)
+        ]
+        db.blocklist.apply_poll_results(
+            {self.TENANT: db.blocklist.metas(self.TENANT)}, {self.TENANT: aged})
+        probes = find_block_probes.value()
+        assert self._span_ids([db.find(self.TENANT, t.trace_id)]) == want
+        assert find_block_probes.value() - probes == 1
+
+
 class TestRetentionEngine:
     def test_two_phase_retention(self, tmp_path):
         db = make_db(tmp_path)
@@ -267,7 +397,6 @@ class TestWalManager:
 class TestPollErrorHandling:
     def test_transient_error_aborts_poll(self, tmp_path):
         from tempo_tpu.backend import MockBackend
-        from tempo_tpu.db import DBConfig, TempoDB
 
         raw = MockBackend()
         db = TempoDB(DBConfig(backend="mock"), raw_backend=raw)

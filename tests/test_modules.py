@@ -4,22 +4,26 @@ all-in-one, the reference's TestAllInOne shape), frontend sharding,
 fair queue, generator processors."""
 
 import json
+import random
 import time
+import uuid
 
 import numpy as np
 import pytest
 
 from tempo_tpu.app import App, AppConfig
-from tempo_tpu.db import DBConfig
+from tempo_tpu.backend.base import BlockMeta
+from tempo_tpu.db import DBConfig, _in_shard, find_block_probes
 from tempo_tpu.encoding.common import SearchRequest
 from tempo_tpu.model import synth
 from tempo_tpu.model import trace as tr
 from tempo_tpu.modules.distributor import RateLimited
-from tempo_tpu.modules.frontend import create_block_boundaries
+from tempo_tpu.modules.frontend import FrontendConfig, create_block_boundaries
 from tempo_tpu.modules.ingester import MaxLiveTraces, TraceTooLarge
 from tempo_tpu.modules.overrides import Limits, Overrides
 from tempo_tpu.modules.queue import RequestQueue, TooManyRequests
 from tempo_tpu.modules.ring import FileKV, MemoryKV, Ring
+from tempo_tpu.util import stagetimings
 
 
 def make_app(tmp_path, **kw):
@@ -317,6 +321,99 @@ class TestFrontend:
         assert b[0] == "0" * 32 and b[-1] == "f" * 32
         assert len(b) == 5
         assert b == sorted(b)
+
+    @pytest.mark.parametrize("n_shards", [1, 2, 4, 50])
+    def test_block_id_in_exactly_one_shard(self, n_shards):
+        bounds = create_block_boundaries(n_shards)
+        assert len(bounds) == n_shards + 1
+        rng = random.Random(n_shards)
+        ids = [rng.getrandbits(128) for _ in range(300)]
+        for b in bounds:  # the boundary values themselves and their neighbours
+            ids += [v for v in (int(b, 16) - 1, int(b, 16), int(b, 16) + 1)
+                    if 0 <= v < 1 << 128]
+        for v in ids:
+            meta = BlockMeta(block_id=str(uuid.UUID(int=v)))
+            hits = [i for i in range(n_shards)
+                    if _in_shard(meta, bounds[i], bounds[i + 1])]
+            assert len(hits) == 1, (meta.block_id, hits)
+            # the default bounds (vulture, cli, serverless, mode="all")
+            assert _in_shard(meta, "0" * 32, "f" * 32)
+
+    def _sharded_app(self, tmp_path, query_shards):
+        return make_app(
+            tmp_path,
+            frontend=FrontendConfig(query_shards=query_shards, hedge_after_s=0),
+            generator_enabled=False,
+        )
+
+    @pytest.mark.parametrize("query_shards", [1, 4, 7])
+    def test_find_probes_each_candidate_block_once(self, tmp_path, query_shards):
+        app = self._sharded_app(tmp_path, query_shards)
+        try:
+            blocks = [synth.make_traces(12, seed=60 + j) for j in range(4)]
+            metas = [
+                app.db.write_batch("single-tenant",
+                                   tr.traces_to_batch(ts).sorted_by_trace())
+                for ts in blocks
+            ]
+            want = blocks[2][5]
+            # absent from every block, inside every block's ID range
+            absent = bytes.fromhex(format(int(want.trace_id.hex(), 16) ^ 1, "032x"))
+            for tid, found in ((want.trace_id, True), (absent, False)):
+                hex_id = tid.hex()
+                candidates = sum(m.min_id <= hex_id <= m.max_id for m in metas)
+                assert candidates >= 2
+                probes = find_block_probes.value()
+                finds = stagetimings.stage_seconds_hist.count(
+                    stage="queue_wait", kind="find")
+                got = app.find_trace(tid)
+                if found:
+                    assert got.span_count() == want.span_count()
+                else:
+                    assert got is None
+                assert find_block_probes.value() - probes == candidates
+                # the per-layer metric's denominator: one observation a find
+                assert stagetimings.stage_seconds_hist.count(
+                    stage="queue_wait", kind="find") - finds == 1
+        finally:
+            app.shutdown()
+
+    @pytest.mark.parametrize("block_ids, slices", [
+        (("10000000-0000-4000-8000-000000000001", "90000000-0000-4000-8000-000000000002"), [0, 2]),
+        # either side of a boundary, and the boundary itself in the upper slice
+        (("3fffffff-ffff-ffff-ffff-ffffffffffff", "40000000-0000-0000-0000-000000000000"), [0, 1]),
+        (("00000000-0000-0000-0000-000000000000", "ffffffff-ffff-ffff-ffff-ffffffffffff"), [0, 3]),
+    ])
+    def test_find_combines_a_trace_split_over_shards(self, tmp_path, block_ids, slices):
+        t = synth.make_trace(seed=3, n_spans=10)
+        spans = list(t.all_spans())
+        resource = t.batches[0][0]
+        halves = [tr.Trace(trace_id=t.trace_id, batches=[(resource, spans[:6])]),
+                  tr.Trace(trace_id=t.trace_id, batches=[(resource, spans[4:])])]
+        answers = {}
+        for query_shards in (1, 4):
+            app = self._sharded_app(tmp_path, query_shards)
+            try:
+                if query_shards == 1:  # write once, re-read at both shard counts
+                    for half, block_id in zip(halves, block_ids):
+                        app.db.write_batch(
+                            "single-tenant",
+                            tr.traces_to_batch([half]).sorted_by_trace(),
+                            block_id=block_id)
+                app.db.poll_now()
+                bounds = create_block_boundaries(4)
+                by_id = {m.block_id: m for m in app.db.blocklist.metas("single-tenant")}
+                assert [
+                    [i for i in range(4) if _in_shard(by_id[b], bounds[i], bounds[i + 1])]
+                    for b in block_ids
+                ] == [[s] for s in slices]
+                got = app.find_trace(t.trace_id)
+                assert got.span_count() == 10
+                answers[query_shards] = sorted(
+                    s.span_id for s in got.all_spans())
+            finally:
+                app.shutdown()
+        assert answers[1] == answers[4] == sorted(s.span_id for s in spans)
 
     def test_queue_fairness(self):
         q = RequestQueue(max_per_tenant=100)
