@@ -31,7 +31,8 @@ def make_raw_backend(kind: str, options: dict | None = None) -> RawBackend:
 
     TEMPO_TPU_FAULTS (e.g. "read=0.01,corrupt=0.001,seed=7") wraps the
     result in a FaultInjectingBackend — the operator chaos knob; see
-    backend/faults.py. bench.py refuses to run with it armed."""
+    backend/faults.py. A run that measures leaves it unset: injected
+    errors and latency are not the path users pay for."""
     return _maybe_inject_faults(_make_raw_backend(kind, options))
 
 

@@ -30,8 +30,8 @@ Fault classes (all off by default):
 
 `TEMPO_TPU_FAULTS` ("read=0.01,corrupt=0.001,seed=7") arms a process-
 wide plan that make_raw_backend applies to every backend it builds —
-the operator chaos knob. bench.py refuses to run with it set (the
-faults-off guard): perf numbers must measure the real path.
+the operator chaos knob. A run that measures leaves it unset: perf
+numbers must measure the real path, not injected errors and latency.
 
 Retryable-vs-terminal taxonomy lives here too (`retryable_error`):
 connection-ish errors retry, NotFound / CorruptPage / DeadlineExceeded /

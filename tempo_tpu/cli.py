@@ -401,7 +401,7 @@ def cmd_analyse_device(args) -> int:
         print("\ndevice-resident hot tier:")
         if not rt.get("enabled"):
             print("  disabled at snapshot time "
-                  "(device_tier.budget_mb=0 / TEMPO_TPU_DEVICE_TIER_MB unset)")
+                  "(device_tier.budget_mb=0)")
             return 0
         st = rt.get("stats", {})
         print(f"  resident: {st.get('entries', 0)} entries, "

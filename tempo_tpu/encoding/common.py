@@ -79,7 +79,7 @@ class CompactionOptions:
     # pages verbatim (byte copy + page-index offset rewrite) instead of
     # decode->gather->re-encode; dictionary-coded columns re-encode only
     # under a non-identity dictionary remap (lazy column gather). False
-    # forces the full re-encode path everywhere (the bench's slow arm).
+    # forces the full re-encode path everywhere (the tests' reference arm).
     zero_decode: bool = True
 
 

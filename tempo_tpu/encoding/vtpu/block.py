@@ -91,18 +91,18 @@ usage.register_tenant_family(decoded_bytes_total)
 
 def runspace_enabled() -> bool:
     """Run-space evaluation kill switch (TEMPO_TPU_RUNSPACE=0): the
-    bench's row-space A/B arm and the operator escape hatch. Off means
-    every predicate/gather expands full columns, exactly the pre-tier
-    read path; results are bit-identical either way."""
+    row-space reference arm of the tests and the operator escape hatch.
+    Off means every predicate/gather expands full columns, exactly the
+    pre-tier read path; results are bit-identical either way."""
     return os.environ.get("TEMPO_TPU_RUNSPACE", "1").strip().lower() not in (
         "0", "false", "no",
     )
 
 
 def zone_maps_enabled() -> bool:
-    """Zone-map pruning kill switch (TEMPO_TPU_ZONEMAPS=0): the bench's
-    A/B arm and the operator escape hatch if a block's stats are ever
-    suspect."""
+    """Zone-map pruning kill switch (TEMPO_TPU_ZONEMAPS=0, every row
+    group is read): the reference arm tests compare pruned answers with,
+    and the operator escape hatch if a block's stats are ever suspect."""
     return os.environ.get("TEMPO_TPU_ZONEMAPS", "1").strip().lower() not in (
         "0", "false", "no",
     )

@@ -65,9 +65,6 @@ def set_threads(n: int) -> None:
 def _threads() -> int:
     if _pool_threads:
         return _pool_threads
-    env = os.environ.get("TEMPO_TPU_CODEC_THREADS")
-    if env:
-        return max(1, int(env))
     return min(8, os.cpu_count() or 1)
 
 

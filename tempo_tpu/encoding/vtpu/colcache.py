@@ -22,8 +22,8 @@ instance serves every block of the process — queriers, the API server
 and the mesh searcher all hit the same working set, like the
 reference's shared backend cache.
 
-The DEVICE tier (`DeviceTier`, sized by TEMPO_TPU_DEVICE_TIER_MB or the
-`device_tier` config section; 0 = off) closes the transfer-ledger loop:
+The DEVICE tier (`DeviceTier`, sized by the `device_tier` config
+section; 0 = off) closes the transfer-ledger loop:
 the hottest (block, column) pages — in their ENCODED run/dict/packed
 form, 10-50x smaller than decoded rows — are admitted as device arrays
 at the knee of the ghost-LRU what-if curve (util/pageheat.admission_*),
@@ -196,10 +196,9 @@ def is_tail_key(key) -> bool:
 
 @dataclasses.dataclass
 class DeviceTierConfig:
-    """Config section `device_tier` (env analog TEMPO_TPU_DEVICE_TIER_MB
-    for the budget). budget_mb=0 disables the tier entirely — the
-    default, so single-shot workloads never pay device memory for pages
-    they will not re-scan."""
+    """Config section `device_tier`. budget_mb=0 disables the tier
+    entirely — the default, so single-shot workloads never pay device
+    memory for pages they will not re-scan."""
 
     budget_mb: int = 0
     # sub-budget (carved out of budget_mb, never additive) for the
@@ -565,34 +564,15 @@ def configure_device_tier(cfg: "DeviceTierConfig | None") -> DeviceTier | None:
 
 
 def shared_device_tier() -> DeviceTier | None:
-    """The process-wide device tier, or None when disabled (the default:
-    no config and TEMPO_TPU_DEVICE_TIER_MB unset/0)."""
-    global _shared_device
-    if _shared_device is None:
-        with _device_lock:
-            if _shared_device is None:
-                mb = int(os.environ.get("TEMPO_TPU_DEVICE_TIER_MB", "0"))
-                if mb <= 0:
-                    return None
-                tail_mb = int(os.environ.get("TEMPO_TPU_INGEST_TAIL_MB", "0"))
-                tier = DeviceTier(mb << 20,
-                                  ingest_tail_budget_bytes=tail_mb << 20)
-                _arm_device_metrics()
-                _shared_device = tier
+    """The process-wide device tier `configure_device_tier` installed,
+    or None when disabled (the default: `device_tier.budget_mb` 0)."""
     return _shared_device
 
 
 def hbm_headroom_bytes() -> int:
     """Detected accelerator memory limit for the default device, or 0
-    when unknown (CPU backends report no limit). TEMPO_TPU_HBM_BYTES
-    overrides for fleets whose runtime under-reports. check_config
-    compares the configured tier budget against this."""
-    env = os.environ.get("TEMPO_TPU_HBM_BYTES", "")
-    if env:
-        try:
-            return int(env)
-        except ValueError:
-            return 0
+    when unknown (CPU backends report no limit). check_config compares
+    the configured tier budget against this."""
     try:
         import jax
 

@@ -774,8 +774,8 @@ class _ShardedTileMerger:
         arrays) happens here on host.
 
         hll_regs/cm_counts ride along for callers beyond write_block
-        (hot-trace detection feeding max_spans_per_trace, bench recall
-        accounting): cm holds psum-merged span counts per trace key.
+        (hot-trace detection feeding max_spans_per_trace): cm holds
+        psum-merged span counts per trace key.
         """
         import jax
 

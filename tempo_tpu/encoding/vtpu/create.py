@@ -196,8 +196,8 @@ class BlockWriter:
     path can interleave the two append kinds in global trace-ID order;
     write_block() below remains the one-shot wrapper every other caller
     uses. Counters (pages_copied_verbatim / pages_reencoded and their
-    byte twins) make the copy-vs-encode split observable in bench
-    artifacts and compaction metrics.
+    byte twins) make the copy-vs-encode split observable in compaction
+    metrics.
     """
 
     def __init__(self, tenant: str, backend: TypedBackend, cfg: BlockConfig,

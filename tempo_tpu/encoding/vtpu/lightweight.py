@@ -89,7 +89,8 @@ DBP_MAX_WIDTH = 32
 def lightweight_enabled() -> bool:
     """Writer kill switch (TEMPO_TPU_LIGHTWEIGHT=0): readers always
     understand the encodings; this only stops NEW pages from using them
-    (the bench's legacy-codec arm and the operator escape hatch)."""
+    (the legacy-codec arm tests compare with, and the operator escape
+    hatch)."""
     return os.environ.get("TEMPO_TPU_LIGHTWEIGHT", "1").strip().lower() not in (
         "0", "false", "no",
     )

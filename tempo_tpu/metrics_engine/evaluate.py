@@ -302,9 +302,9 @@ class DeviceAccumulator(HostAccumulator):
 def make_accumulator(plan: MetricsPlan, device: bool | None = None) -> HostAccumulator:
     """Pick the reduction path: the Pallas device bincount when a real
     accelerator backend is attached (or TEMPO_TPU_METRICS_DEVICE=1
-    forces it — the bench's device arm on CPU hosts), host numpy
-    otherwise (interpret-mode pallas on CPU costs more than np.add.at —
-    the same economics as the search read path, PERF.md). device=False
+    forces it, which is how a CPU host exercises the device arm), host
+    numpy otherwise (interpret-mode pallas on CPU costs more than
+    np.add.at). device=False
     forces host (the mesh path brings its own reduction and only needs
     the bookkeeping half)."""
     import os

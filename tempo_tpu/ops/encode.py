@@ -7,8 +7,8 @@ the same arithmetic in reverse so the WRITE path's cut/flush encode is a
 batched device kernel instead of a per-column host loop. Pages are
 bit-identical to the host encoders — same header, same body CRC, same
 np.packbits(bitorder="little") stream layout — so readers (host decode,
-device-resident decode, gather) cannot tell which arm produced a block,
-and the bench's paired-arm parity assert holds byte for byte.
+device-resident decode, gather) cannot tell which arm produced a block
+(tests/test_device_encode.py holds the two arms to byte equality).
 
 Division of labor per codec (one timed_dispatch per page, so the flush
 waterfall shows encode as `transfer` (column ship) + `kernel` stages):

@@ -9,16 +9,16 @@ so a process that resolved to the CPU turns every arm off together and
 `describe()` reports exactly what the gates saw. App.__init__ logs it
 and /status/device serves it under `backend`.
 
-Measurement entry points (chip_smoke.py, bench.py, tools/bench_suite.py)
-have no CPU fallback: `check_measurable` refuses any platform but "tpu"
-unless the caller asked for a CPU dry run (JAX_PLATFORMS=cpu for the
-benches, `--cpu-dry-run` for the smoke).
+A run that measures has no CPU fallback: `check_measurable` refuses any
+platform but "tpu" unless the caller asked for a CPU dry run by name
+(`--cpu-dry-run`), because a time taken on the CPU backend says nothing
+about the device and must never be read as one. An environment that
+merely pins JAX_PLATFORMS=cpu is not that opt-in.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 
 
 class NoAccelerator(RuntimeError):
@@ -40,11 +40,6 @@ def on_accelerator() -> bool:
     return platform() == "tpu"
 
 
-def cpu_requested() -> bool:
-    """The caller pinned the CPU themselves: JAX_PLATFORMS=cpu."""
-    return os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
-
-
 def check_measurable(found: str, cpu_ok: bool) -> None:
     """Raise NoAccelerator unless `found` (a resolved platform name) is
     "tpu", or is "cpu" and the entry point's caller opted in to a CPU
@@ -56,19 +51,6 @@ def check_measurable(found: str, cpu_ok: bool) -> None:
         "(there is no CPU fallback; a CPU dry run must be asked for "
         "explicitly, is labelled cpu and carries no device metric)"
     )
-
-
-def require_measurable() -> dict:
-    """Gate for in-process measurement entry points (bench.py,
-    tools/bench_suite.py): the device tags for their JSON lines, or
-    NoAccelerator unless this is a TPU or the caller set
-    JAX_PLATFORMS=cpu."""
-    import jax
-
-    check_measurable(platform(), cpu_ok=cpu_requested())
-    d = jax.devices()
-    return {"platform": d[0].platform, "device_kind": d[0].device_kind,
-            "device_count": len(d)}
 
 
 def describe() -> dict:

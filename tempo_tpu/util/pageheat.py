@@ -574,11 +574,9 @@ class PageHeatExporter:
     _KEEP = 5
     _EXPORT_STREAM_CAP = 16384  # newest accesses carried in the snapshot
 
-    def __init__(self, interval_s: float | None = None,
+    def __init__(self, interval_s: float = 300.0,
                  export_dir: str | None = None):
-        env_s = os.environ.get("TEMPO_TPU_PAGEHEAT_EXPORT_S", "")
-        self.interval_s = interval_s if interval_s is not None else (
-            float(env_s) if env_s else 300.0)
+        self.interval_s = interval_s
         self.export_dir = export_dir or os.environ.get(
             "TEMPO_TPU_PAGEHEAT_EXPORT_DIR") or None
         self.last: dict | None = None

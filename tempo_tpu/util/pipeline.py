@@ -26,8 +26,7 @@ def overlap_enabled() -> bool:
 
     On a single-core host the GIL-released C calls still cannot run
     concurrently with Python (one core), so background threads only add
-    context switches; measured on the bench workload they cost ~2x.
-    TEMPO_TPU_OVERLAP=0/1 overrides the auto-detect."""
+    context switches. TEMPO_TPU_OVERLAP=0/1 overrides the auto-detect."""
     env = os.environ.get("TEMPO_TPU_OVERLAP")
     if env is not None:
         return env.strip().lower() not in ("0", "false", "no")

@@ -289,9 +289,10 @@ def span(name: str, **attrs):
 
 @dataclass
 class SelfTracingConfig:
-    """`self_tracing:` config section. Off by default — the bench guard
-    (bench.py) refuses to measure with it armed, and production turns it
-    on explicitly like the reference turns on its Jaeger exporter."""
+    """`self_tracing:` config section. Off by default: an armed exporter
+    puts the engine's own spans on the ingest path it reports on, so no
+    benchmark configuration arms it, and production turns it on
+    explicitly like the reference turns on its Jaeger exporter."""
 
     enabled: bool = False
     tenant: str = SELF_TENANT

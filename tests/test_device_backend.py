@@ -29,14 +29,6 @@ class TestBackend:
         with pytest.raises(backend.NoAccelerator, match="'gpu'"):
             backend.check_measurable("gpu", cpu_ok=True)
 
-    def test_require_measurable_needs_the_callers_opt_in(self, monkeypatch):
-        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-        assert backend.require_measurable() == {
-            "platform": "cpu", "device_kind": "cpu", "device_count": 8}
-        monkeypatch.delenv("JAX_PLATFORMS")
-        with pytest.raises(backend.NoAccelerator):
-            backend.require_measurable()
-
 
 class TestCompileCache:
     @pytest.fixture()

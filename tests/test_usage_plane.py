@@ -298,7 +298,7 @@ class TestCompactionDebt:
             c["pages"] for c in a["columns"].values())
         assert a["zonemap"]["coverageRatio"] == 1.0
         # lightweight codecs are in play (the PageMeta mix the analyser
-        # reports is what /status/storage and BENCH_r06+ consume)
+        # reports is what /status/storage serves)
         assert set(a["codecPages"]) & {"rle", "dct", "dbp"}
 
 

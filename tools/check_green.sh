@@ -1,17 +1,21 @@
 #!/usr/bin/env bash
-# Tier-1 green gate: run ROADMAP.md's verify command and fail on ANY
-# test failure or error. Snapshots must run this before committing —
-# round 5 shipped two committed-broken tests because nothing gated the
-# tree on its own suite.
+# A pre-commit gate on a CPU: ROADMAP.md's tier-1 command, then three
+# tools/loadtest.py correctness smokes of the default-off tiers (device
+# tier + compiled shapes + ingest tail; result cache; auto-RCA under
+# injected faults): the only end-to-end gate on them, since no benchmark
+# cell turns them on.
+# It measures nothing: a CPU run says whether answers are right and what
+# the program counts. Times come from benchmark/run.py on the chip.
 #
 # Exit code: pytest's own (nonzero on any F/E, including collection
-# errors). The DOTS_PASSED line mirrors the driver's pass-count metric.
+# errors), else the first smoke's that failed. The DOTS_PASSED line
+# mirrors the driver's pass count.
 #
-# Deeper (non-tier-1) gates when touching the ingest/query/SLO planes:
+# Deeper, when touching the ingest/query/SLO planes:
 #   python tools/loadtest.py --duration 120 --rate 10 --vulture
-# runs the mixed 10-100x workload WITH the continuous-verification
-# prober beside it and additionally gates on vulture correctness at
-# drain (zero notfound/incorrect probes) and the freshness SLO.
+# runs the mixed workload with the continuous-verification prober beside
+# it and gates on vulture correctness at drain (zero notfound/incorrect
+# probes) and the freshness SLO.
 set -uo pipefail
 cd "$(dirname "$0")/.."
 

@@ -1643,9 +1643,9 @@ def ingest_heavy_probe(write_url: str, query_url: str, ing_urls: list,
 
 
 def storage_summary(query_url: str) -> dict:
-    """Fleet storage health from the frontend's /status/storage — the
-    same compression/debt/zone-map numbers bench_suite emits, so CI
-    tracks storage health alongside perf."""
+    """Fleet storage health from the frontend's /status/storage
+    (compression, compaction debt, zone-map coverage), so the rig's
+    output carries storage health beside its gates."""
     try:
         doc = _get_json(query_url + "/status/storage?refresh=1", timeout=60)
     except Exception as e:  # noqa: BLE001 — summary is best-effort
