@@ -432,9 +432,9 @@ def new_wire() -> dict:
 
 def merge_wire(merged: dict, wire: dict, plan: MetricsPlan, bin_offset: int = 0) -> None:
     """Fold one job partial (HostAccumulator.to_wire form) into the
-    merged state, shifting the job's local bins by bin_offset steps
-    (frontend time-range sharding). Addition only, so merge order never
-    changes results."""
+    merged state, shifting the job's local bins by bin_offset steps (a
+    frontend block job's window starts that far into the query's grid).
+    Addition only, so merge order never changes results."""
     nb = plan.n_buckets
     for s in wire.get("series", []):
         key = s.get("key")

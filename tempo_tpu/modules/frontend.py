@@ -40,6 +40,13 @@ query_cost_hist = metrics.histogram(
 )
 
 
+metrics_job_blocks_total = metrics.counter(
+    "tempo_tpu_frontend_metrics_job_blocks_total",
+    "Block IDs the query_range sharder placed in metrics_blocks jobs: a "
+    "query's candidate blocks when each is in exactly one job",
+)
+
+
 def create_block_boundaries(n_shards: int) -> list[str]:
     """n_shards+1 uniform 128-bit hex boundaries (reference:
     tracebyidsharding.go:228 createBlockBoundaries)."""
@@ -51,11 +58,44 @@ def create_block_boundaries(n_shards: int) -> list[str]:
     return bounds
 
 
+def _chunk_by_bytes(metas: list, target_bytes: int):
+    """Consecutive groups of block metas, each closed once it holds
+    target_bytes of block data (reference: searchsharding.go:266
+    backendRequests): every meta is in exactly one group."""
+    group, size = [], 0
+    for m in metas:
+        group.append(m)
+        size += max(m.size_bytes, 1)
+        if size >= target_bytes:
+            yield group
+            group, size = [], 0
+    if group:
+        yield group
+
+
+def _metrics_blocks_job(plan, group: list, common: dict) -> dict:
+    """One metrics_blocks job over `group` (block metas). Its window is
+    the step-aligned hull of the blocks' [start_time, end_time], clipped
+    to the plan's grid and never empty: a block on the window's own last
+    second still gets the last bin."""
+    last = plan.n_bins - 1
+    k0 = (min(m.start_time for m in group) - plan.start_s) // plan.step_s
+    k1 = (max(m.end_time for m in group) - plan.start_s) // plan.step_s
+    k0 = min(max(k0, 0), last)
+    k1 = min(max(k1, k0), last) + 1
+    return {"kind": "metrics_blocks", "block_ids": [m.block_id for m in group],
+            "start": plan.start_s + k0 * plan.step_s,
+            "end": min(plan.end_s, plan.start_s + k1 * plan.step_s), **common}
+
+
 @dataclass
 class FrontendConfig:
+    # find: the block-ID space is cut into this many `blocks` jobs (also
+    # the resident ceiling admission charges, x target_bytes_per_job)
     query_shards: int = 4
     max_retries: int = 2
-    # search: one backend job per this many bytes of block data
+    # search, query_range, graph: one backend job per this many bytes of
+    # block data; every block is in exactly one job of a query
     target_bytes_per_job: int = 100 * 1024 * 1024
     query_ingesters_until_s: int = 3600  # recent window served by ingesters
     max_duration_s: int = 0  # per-tenant via overrides wins
@@ -495,17 +535,10 @@ class Frontend:
             if (not req.start_seconds or m.end_time >= req.start_seconds)
             and (not req.end_seconds or m.start_time <= req.end_seconds)
         ]
-        est_bytes = 0
-        group, size = [], 0
-        for m in metas:
-            group.append(m.block_id)
-            size += max(m.size_bytes, 1)
-            est_bytes += max(m.size_bytes, 1)
-            if size >= self.cfg.target_bytes_per_job:
-                descs.append({"kind": "search_blocks", "block_ids": group, "search": req.to_dict()})
-                group, size = [], 0
-        if group:
-            descs.append({"kind": "search_blocks", "block_ids": group, "search": req.to_dict()})
+        est_bytes = sum(max(m.size_bytes, 1) for m in metas)
+        for group in _chunk_by_bytes(metas, self.cfg.target_bytes_per_job):
+            descs.append({"kind": "search_blocks", "search": req.to_dict(),
+                          "block_ids": [m.block_id for m in group]})
 
         if any(d["kind"] == "search_blocks" for d in descs):
             # search executes through the PR 16 fused batched scans
@@ -535,18 +568,22 @@ class Frontend:
     def query_range(self, tenant: str, query: str, start_s: int, end_s: int,
                     step_s: int, max_series: int = 64, exemplars: int = 0) -> dict:
         """TraceQL metrics over [start, end) at step resolution
-        (reference: the frontend's query_range sharder — time-range
-        shards over backend blocks + a recent-window job served from
-        ingester live data, modules/frontend metrics middleware).
+        (reference: the frontend's query_range sharder — jobs over
+        backend blocks + a recent-window job served from ingester live
+        data, modules/frontend metrics middleware).
 
         The full range is compiled once up front (client errors fail
-        before any job is sharded), then split into step-ALIGNED
-        sub-windows — each worker evaluates a sub-plan whose bins map
-        back into the parent grid by a pure offset, so partials merge by
-        integer addition and shard boundaries can never change results.
-        The recent job covers the whole window from ingester live/WAL
-        segments (the not-yet-flushed tail); block jobs cover flushed
-        data, the same disjointness contract the search path uses.
+        before any job is sharded). Every backend block whose time range
+        touches the window goes into exactly one job: blocks in
+        start_time order, chunked by target_bytes_per_job as search
+        chunks them (query_shards plays no part here). A job's window is
+        the step-ALIGNED hull of its blocks' time ranges, so each worker
+        evaluates a sub-plan whose bins map back into the parent grid by
+        a pure offset: partials merge by integer addition and where the
+        jobs are cut can never change results. The recent job covers the
+        whole window from ingester live/WAL segments (the not-yet-flushed
+        tail); block jobs cover flushed data, the same disjointness
+        contract the search path uses.
         """
         with stagetimings.request() as st, usage.attribute(tenant, "query_range"), \
                 insights.LOG.observe(tenant, "query_range",
@@ -587,31 +624,21 @@ class Frontend:
             descs.append({"kind": "metrics_recent", "start": plan.start_s,
                           "end": plan.end_s, **common})
 
-        # step-aligned time-range shards, blocks chunked per shard by the
-        # same byte budget the search sharder uses
-        n_shards = max(1, min(self.cfg.query_shards, plan.n_bins))
-        bins_per = -(-plan.n_bins // n_shards)  # ceil
-        metas = self.db.blocklist.metas(tenant)
-        est_bytes = 0
-        b = 0
-        while b < plan.n_bins:
-            w0 = plan.start_s + b * plan.step_s
-            w1 = min(plan.end_s, plan.start_s + (b + bins_per) * plan.step_s)
-            b += bins_per
-            group, size = [], 0
-            for m in metas:
-                if m.end_time < w0 or m.start_time > w1:
-                    continue
-                group.append(m.block_id)
-                size += max(m.size_bytes, 1)
-                est_bytes += max(m.size_bytes, 1)
-                if size >= self.cfg.target_bytes_per_job:
-                    descs.append({"kind": "metrics_blocks", "block_ids": group,
-                                  "start": w0, "end": w1, **common})
-                    group, size = [], 0
-            if group:
-                descs.append({"kind": "metrics_blocks", "block_ids": group,
-                              "start": w0, "end": w1, **common})
+        # every candidate block goes into exactly ONE job: a block is
+        # sorted by trace ID, so each of its row groups spans the block's
+        # whole time range and a job cannot read "its part" of a block.
+        # Blocks in start_time order are chunked by the byte budget the
+        # search sharder uses; a job's window is the step-aligned hull
+        # of its blocks' time ranges, clipped to the plan's grid, so the
+        # job stays a sub-plan whose bins map back by a pure offset
+        metas = sorted(
+            (m for m in self.db.blocklist.metas(tenant)
+             if m.end_time >= plan.start_s and m.start_time <= plan.end_s),
+            key=lambda m: m.start_time)
+        est_bytes = sum(max(m.size_bytes, 1) for m in metas)
+        for group in _chunk_by_bytes(metas, self.cfg.target_bytes_per_job):
+            descs.append(_metrics_blocks_job(plan, group, common))
+        metrics_job_blocks_total.inc(len(metas))
 
         # protected = the whole range sits in the recent window (same
         # rule as search: touching `now` alone doesn't protect a scan)
@@ -752,17 +779,10 @@ class Frontend:
             if (not start_s or m.end_time >= start_s)
             and (not end_s or m.start_time <= end_s)
         ]
-        est_bytes = 0
-        group, size = [], 0
-        for m in metas:
-            group.append(m.block_id)
-            size += max(m.size_bytes, 1)
-            est_bytes += max(m.size_bytes, 1)
-            if size >= self.cfg.target_bytes_per_job:
-                descs.append({"kind": "graph_blocks", "block_ids": group, **common})
-                group, size = [], 0
-        if group:
-            descs.append({"kind": "graph_blocks", "block_ids": group, **common})
+        est_bytes = sum(max(m.size_bytes, 1) for m in metas)
+        for group in _chunk_by_bytes(metas, self.cfg.target_bytes_per_job):
+            descs.append({"kind": "graph_blocks", "block_ids": [m.block_id for m in group],
+                          **common})
 
         # protected only when confined to the recent window (the search
         # rule: touching `now` alone doesn't protect a scan)

@@ -18,7 +18,13 @@ from tempo_tpu.encoding.common import SearchRequest
 from tempo_tpu.model import synth
 from tempo_tpu.model import trace as tr
 from tempo_tpu.modules.distributor import RateLimited
-from tempo_tpu.modules.frontend import FrontendConfig, create_block_boundaries
+from tempo_tpu.metrics_engine import compile_metrics_plan
+from tempo_tpu.modules.frontend import (
+    FrontendConfig,
+    _metrics_blocks_job,
+    create_block_boundaries,
+    metrics_job_blocks_total,
+)
 from tempo_tpu.modules.ingester import MaxLiveTraces, TraceTooLarge
 from tempo_tpu.modules.overrides import Limits, Overrides
 from tempo_tpu.modules.queue import RequestQueue, TooManyRequests
@@ -377,6 +383,67 @@ class TestFrontend:
                     stage="queue_wait", kind="find") - finds == 1
         finally:
             app.shutdown()
+
+    @pytest.mark.parametrize("query_shards", [1, 4, 7])
+    def test_query_range_places_each_candidate_block_once(
+            self, tmp_path, monkeypatch, query_shards):
+        app = self._sharded_app(tmp_path, query_shards)
+        try:
+            base = 1_700_000_000
+            # three blocks inside a one-hour window, one a day later
+            metas = [
+                app.db.write_batch("single-tenant", synth.make_batch(
+                    40, 4, seed=70 + j, base_time_ns=(base + off) * 10**9))
+                for j, off in enumerate((30, 1790, 3500, 86_400))
+            ]
+            descs = []
+            run = app.frontend._run_jobs
+            monkeypatch.setattr(
+                app.frontend, "_run_jobs",
+                lambda tenant, ds: (descs.extend(ds), run(tenant, ds))[1])
+            placed = metrics_job_blocks_total.value()
+            asked = stagetimings.stage_seconds_hist.count(
+                stage="queue_wait", kind="query_range")
+            doc = app.query_range("{} | rate()", base, base + 3600, 60)
+            assert sum(float(v) * 60 for s in doc["result"] for _, v in s["values"]) \
+                == pytest.approx(3 * 160)
+            jobs = [d for d in descs if d["kind"] == "metrics_blocks"]
+            in_jobs = [b for d in jobs for b in d["block_ids"]]
+            assert sorted(in_jobs) == sorted(m.block_id for m in metas[:3])
+            # the counter reads the descriptors: one count a block ID placed
+            assert metrics_job_blocks_total.value() - placed == len(in_jobs) == 3
+            # the per-layer metric's denominator: one observation a query_range
+            assert stagetimings.stage_seconds_hist.count(
+                stage="queue_wait", kind="query_range") - asked == 1
+        finally:
+            app.shutdown()
+
+    @pytest.mark.parametrize("blocks, start, end, step, window", [
+        # data on a step boundary, a window one step either side: one bin
+        ([(1060, 1063)], 1000, 1180, 60, (1060, 1120)),
+        # the group's earliest start to its latest end, step-aligned outward
+        ([(1130, 1190), (1070, 1075), (1200, 1241)], 1000, 1600, 60, (1060, 1300)),
+        # a block that began before the window or ends after it is clipped to the grid
+        ([(400, 1010)], 1000, 1600, 60, (1000, 1060)),
+        ([(1500, 9000)], 1000, 1600, 60, (1480, 1600)),
+        ([(0, 9000)], 1000, 1600, 60, (1000, 1600)),
+        # a window that is no whole number of steps: the last bin ends with it
+        ([(1590, 1592)], 1000, 1600, 45, (1585, 1600)),
+        # a block ON the window's own last second passes the inclusive
+        # candidate test and still gets a bin, the last one
+        ([(1600, 1603)], 1000, 1600, 60, (1540, 1600)),
+        ([(990, 1000)], 1000, 1600, 60, (1000, 1060)),
+    ])
+    def test_metrics_job_window_is_the_hull_on_the_plans_grid(
+            self, blocks, start, end, step, window):
+        plan = compile_metrics_plan("{} | rate()", start, end, step)
+        group = [BlockMeta(start_time=a, end_time=b) for a, b in blocks]
+        d = _metrics_blocks_job(plan, group, {"q": "{} | rate()", "step": step})
+        assert (d["start"], d["end"]) == window
+        assert d["block_ids"] == [m.block_id for m in group]
+        assert (d["start"] - start) % step == 0
+        assert start <= d["start"] < d["end"] <= end
+        assert (d["kind"], d["q"], d["step"]) == ("metrics_blocks", "{} | rate()", step)
 
     @pytest.mark.parametrize("block_ids, slices", [
         (("10000000-0000-4000-8000-000000000001", "90000000-0000-4000-8000-000000000002"), [0, 2]),

@@ -413,6 +413,74 @@ def _get_json(url):
         return r.status, json.loads(r.read())
 
 
+@pytest.fixture()
+def frontend_app(tmp_path):
+    """App factory over one store: each call re-opens it with its own
+    FrontendConfig (hedging off, so a job runs exactly once)."""
+    from tempo_tpu.app import App, AppConfig
+    from tempo_tpu.db import DBConfig
+    from tempo_tpu.modules.frontend import FrontendConfig
+
+    apps = []
+
+    def make(**frontend):
+        app = App(AppConfig(
+            db=DBConfig(backend="local", backend_path=str(tmp_path / "blocks"),
+                        wal_path=str(tmp_path / "wal")),
+            frontend=FrontendConfig(hedge_after_s=0, **frontend),
+            generator_enabled=False))
+        apps.append(app)
+        return app
+
+    yield make
+    for app in apps:
+        app.shutdown()
+
+
+def _write_block(app, seed, start_s, span_s=1, n_traces=120):
+    """One flushed block whose span starts spread over
+    [start_s, start_s + span_s)."""
+    b = synth.make_batch(n_traces, 4, seed=seed, base_time_ns=start_s * 10**9)
+    rng = np.random.default_rng(1000 + seed)
+    n = len(b.cols["start_unix_nano"])
+    starts = start_s * 10**9 + rng.integers(0, span_s * 10**9, size=n)
+    starts[:2] = (start_s * 10**9, (start_s + span_s) * 10**9 - 1)  # the range's own ends
+    b.cols["start_unix_nano"] = starts.astype(np.uint64)
+    return app.db.write_batch("single-tenant", b)
+
+
+def _block_jobs(app, monkeypatch):
+    """The metrics_blocks descriptors of every query_range from here on."""
+    seen = []
+    run = app.frontend._run_jobs
+
+    def spy(tenant, descs):
+        seen.extend(dict(d) for d in descs if d["kind"] == "metrics_blocks")
+        return run(tenant, descs)
+
+    monkeypatch.setattr(app.frontend, "_run_jobs", spy)
+    return seen
+
+
+def _one_pass(app, q, start, end, step, **kw):
+    """The matrix of ONE evaluator over the whole window and every block."""
+    plan = _plan(q, start=start, end=end, step=step, **kw)
+    enc = app.db.default_encoding()
+    acc = HostAccumulator(plan)
+    for m in app.db.blocklist.metas("single-tenant"):
+        evaluate_block(plan, enc.open_block(m, app.db.backend, app.db.cfg.block), acc)
+    return _matrix(plan, acc)
+
+
+def _hull(metas, start, end, step):
+    """Step-aligned hull of the metas' time ranges on the grid of
+    [start, end) — what a job over them has to carry as its window."""
+    last = -(-(end - start) // step) - 1
+    k0 = min(max((min(m.start_time for m in metas) - start) // step, 0), last)
+    k1 = min(max((max(m.end_time for m in metas) - start) // step, k0), last) + 1
+    return start + k0 * step, min(end, start + k1 * step)
+
+
 class TestEndToEnd:
     def test_http_query_range_matrix(self, served_app):
         import urllib.parse
@@ -511,15 +579,79 @@ class TestEndToEnd:
         ref = _matrix(plan, acc)
         assert doc["result"] == ref["result"]
 
-    def test_sharded_series_cap_fails_loud(self, served_app):
-        """Each time shard caps series in its own first-seen order, so a
-        cross-shard overflow could leave silent zero-bin holes — the
-        frontend must fail the query instead of merging them."""
-        app, _ = served_app
-        for seed in range(4):  # one block per time shard, 8 services each
+    @pytest.mark.parametrize("q", [QUERIES[0], QUERIES[2]])
+    def test_blocks_on_a_step_boundary_are_read_once(self, q, frontend_app, monkeypatch):
+        """The benchmark's layout: two blocks whose data starts exactly
+        on a step boundary, a window one step either side of it. Each
+        block is in exactly one job, the job carries the one bin that
+        holds the data, and the matrix equals one evaluator's pass."""
+        app = frontend_app()
+        metas = [_write_block(app, seed, BASE_S + 60, span_s=2) for seed in (0, 1)]
+        assert all(m.start_time == BASE_S + 60 for m in metas)
+        jobs = _block_jobs(app, monkeypatch)
+        doc = app.query_range(q, BASE_S, BASE_S + 180, 60)
+        assert [(sorted(d["block_ids"]), d["start"], d["end"]) for d in jobs] == [
+            (sorted(m.block_id for m in metas), BASE_S + 60, BASE_S + 120)]
+        assert doc["result"] == _one_pass(app, q, BASE_S, BASE_S + 180, 60)["result"]
+        assert doc["result"]
+
+    @pytest.mark.parametrize("query_shards", [1, 2, 4])
+    def test_block_straddling_shard_edges_is_in_one_job(
+            self, query_shards, frontend_app, monkeypatch):
+        """A one-hour window at step 60 used to be cut at 15, 30 and 45
+        minutes when query_shards was 4: one block here crosses all three
+        edges, one crosses two, one none. Whatever query_shards says, the
+        jobs are the same, no block ID is in two of them, and the matrix
+        is one evaluator's."""
+        app = frontend_app(query_shards=query_shards)
+        metas = [
+            _write_block(app, 0, BASE_S + 600, span_s=2400),   # 10' .. 50'
+            _write_block(app, 1, BASE_S + 1200, span_s=1200),  # 20' .. 40'
+            _write_block(app, 2, BASE_S + 3000, span_s=30),    # 50' .. 50'30"
+        ]
+        q = "{} | rate() by (resource.service.name)"
+        jobs = _block_jobs(app, monkeypatch)
+        doc = app.query_range(q, BASE_S, BASE_S + 3600, 60)
+        placed = [b for d in jobs for b in d["block_ids"]]
+        assert sorted(placed) == sorted(m.block_id for m in metas)
+        assert [(d["block_ids"], d["start"], d["end"]) for d in jobs] == [
+            ([m.block_id for m in metas], *_hull(metas, BASE_S, BASE_S + 3600, 60))]
+        assert (jobs[0]["start"], jobs[0]["end"]) == (BASE_S + 600, BASE_S + 3060)
+        assert doc["result"] == _one_pass(app, q, BASE_S, BASE_S + 3600, 60)["result"]
+
+    @pytest.mark.parametrize("q", [QUERIES[0], QUERIES[2]])
+    def test_far_apart_blocks_get_their_own_hull(self, q, frontend_app, monkeypatch):
+        """A byte budget of one byte a job: every block is its own job,
+        in start_time order, each with the hull of its own time range
+        (so its own bin_offset at the merge) and not the whole window."""
+        app = frontend_app(target_bytes_per_job=1)
+        # written out of time order, and one block outside the window
+        starts = {0: BASE_S + 1830, 1: BASE_S + 45, 2: BASE_S + 3590, 3: BASE_S + 700,
+                  4: BASE_S + 7200}
+        metas = {seed: _write_block(app, seed, t0, span_s=90) for seed, t0 in starts.items()}
+        start, end, step = BASE_S, BASE_S + 3600, 60
+        jobs = _block_jobs(app, monkeypatch)
+        doc = app.query_range(q, start, end, step)
+        inside = [metas[seed] for seed in (1, 3, 0, 2)]
+        assert [(d["block_ids"], d["start"], d["end"]) for d in jobs] == [
+            ([m.block_id], *_hull([m], start, end, step)) for m in inside]
+        for d in jobs:
+            assert (d["start"] - start) % step == 0 and start <= d["start"] < d["end"] <= end
+            assert d["end"] - d["start"] <= 3 * step  # 90 s of data, never the hour
+        assert len({(d["start"] - start) // step for d in jobs}) == 4  # four bin_offsets
+        assert doc["result"] == _one_pass(app, q, start, end, step)["result"]
+
+    def test_sharded_series_cap_fails_loud(self, frontend_app, monkeypatch):
+        """Each job caps series in its own first-seen order, so an
+        overflow in one of several jobs could leave silent zero-bin holes
+        — the frontend must fail the query instead of merging them. The
+        several jobs come from the byte budget: one block a job."""
+        app = frontend_app(target_bytes_per_job=1)
+        for seed in range(4):  # 8 services each
             app.db.write_batch("single-tenant", synth.make_batch(
                 200, 4, seed=seed, base_time_ns=(BASE_S + seed * 180) * 10**9))
-        app.db.poll_now()
+        jobs = _block_jobs(app, monkeypatch)
         with pytest.raises(ValueError, match="max_series"):
             app.query_range("{} | rate() by (resource.service.name)",
                             BASE_S, BASE_S + 600, 60, max_series=2)
+        assert len(jobs) == 4
