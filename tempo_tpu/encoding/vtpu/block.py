@@ -55,7 +55,7 @@ from tempo_tpu.encoding.vtpu import format as fmt
 from tempo_tpu.model.columnar import ATTR_COLUMNS, SPAN_COLUMNS, VT_STR, SpanBatch
 from tempo_tpu.model.trace import Trace, batch_to_traces
 from tempo_tpu.ops import bloom
-from tempo_tpu.util import metrics, stagetimings, usage
+from tempo_tpu.util import metrics, stagetimings, tracing, usage
 
 # columns needed to build TraceSearchMetadata for matching traces
 _META_COLS = ["trace_id", "parent_span_id", "start_unix_nano", "duration_nano", "name", "service"]
@@ -251,8 +251,18 @@ class EncodedColumn:
         placement: (codec, arrays, meta, host_bytes), or None when the
         shape cannot scan on device (vector columns, >32-bit rle/dct
         values, multi-subcolumn dbp). host_bytes is what one host-path
-        serve moves — the per-hit avoided-transfer increment."""
+        serve moves — the per-hit avoided-transfer increment.
+
+        Every array whose length follows the page's DATA (run count,
+        dictionary size, packed length) is padded to a power of two, so
+        the resident programs (ops/scan) compile once a bucket, not once
+        a page: rle with zero-length runs (they expand to no row), dct
+        with a repeated real entry (no index points at it; the rule of
+        scan.pad_codes_u32), dbp with zero words past the guard word
+        (the decode reads none of them). Masks stay bit-identical; the
+        pad is in the arrays' nbytes, so the tier's budget stays true."""
         from tempo_tpu.encoding.vtpu import lightweight as lw
+        from tempo_tpu.ops.scan import pad_codes_u32, pad_pow2
 
         pm = self.pm
         if pm.shape and len(pm.shape) > 1:
@@ -266,8 +276,8 @@ class EncodedColumn:
                     or values.dtype.itemsize > 4):
                 return None
             return ("rle",
-                    {"values": values.astype(np.uint32),
-                     "lengths": lengths.astype(np.int32)},
+                    {"values": pad_pow2(values.astype(np.uint32), 0),
+                     "lengths": pad_pow2(lengths.astype(np.int32), 0)},
                     {"n": self.n},
                     values.nbytes + lengths.nbytes)
         if self.codec == "dct":
@@ -277,7 +287,7 @@ class EncodedColumn:
                 return None
             w = max(values.shape[0] - 1, 0).bit_length()
             return ("dct",
-                    {"values": values.astype(np.uint32),
+                    {"values": pad_codes_u32(values),
                      "idx": idx.astype(np.int32)},
                     {"n": self.n},
                     values.nbytes + (self.n * w + 7) // 8)
@@ -289,7 +299,7 @@ class EncodedColumn:
             raw = bytes(streams[0])
             pad = (-len(raw)) % 4 + 4  # round to words + one guard word
             words = np.frombuffer(raw + b"\x00" * pad, "<u4")
-            return ("dbp", {"words": words},
+            return ("dbp", {"words": pad_pow2(words, 0)},
                     {"n": n, "first": int(first[0]), "width": int(widths[0])},
                     n * np.dtype(pm.dtype).itemsize)
         return None
@@ -308,21 +318,24 @@ class EncodedColumn:
             return res
         if not tier.should_admit([key]):
             return None
-        payload = self.resident_payload()
-        if payload is None:
-            return None
-        codec, arrays, meta, host_bytes = payload
-        if tier.offer(key, codec, arrays, meta, host_bytes=host_bytes):
-            return tier.get(key)
-        return None
+        with tracing.span("devicetier/admit"):
+            payload = self.resident_payload()
+            if payload is None:
+                return None
+            codec, arrays, meta, host_bytes = payload
+            admitted = tier.offer(key, codec, arrays, meta,
+                                  host_bytes=host_bytes)
+        return tier.get(key) if admitted else None
 
     # -- predicate evaluation in encoded space -------------------------
-    def in_set_mask(self, codes: np.ndarray, invert: bool = False):
+    def in_set_mask(self, codes: np.ndarray, invert: bool = False,
+                    resident: bool = True):
         """Row mask for `column in codes` (1-D columns), or None when
-        this codec cannot answer without full decode (dbp)."""
+        this codec cannot answer without full decode (dbp). resident
+        False: the device tier was asked for this page already."""
         from tempo_tpu.ops import scan
 
-        res = self.resident()
+        res = self.resident() if resident else None
         if res is not None:
             m = scan.resident_in_set_mask(res, codes, invert=invert)
             if m is not None:
@@ -341,13 +354,13 @@ class EncodedColumn:
             return hit[idx] if self.n else np.zeros(0, bool)
         return None
 
-    def range_mask(self, lo, hi):
+    def range_mask(self, lo, hi, resident: bool = True):
         """Row mask for lo <= column <= hi, or None (dbp/entropy —
         though a RESIDENT dbp page answers: its device delta-decode is
         fused into the limb compare)."""
         from tempo_tpu.ops import scan
 
-        res = self.resident()
+        res = self.resident() if resident else None
         if res is not None:
             m = scan.resident_range_mask(res, lo, hi)
             if m is not None:
@@ -723,13 +736,15 @@ class VtpuBackendBlock:
                 return out
 
             ra = ReadAhead(load_stage1, len(live)) if stage1 and live else None
+            resident = self._resident_masks(live, req, preds)
             try:
                 for i, rg in enumerate(live):
                     resp.inspected_traces += rg.n_traces
                     have = ra.get(i) if ra is not None else {}
                     remaining = (req.limit - len(resp.traces)) if req.limit else 0
                     resp.traces.extend(self._search_row_group(
-                        rg, req, preds, limit=remaining, have_cols=have))
+                        rg, req, preds, limit=remaining, have_cols=have,
+                        have_masks=resident[i]))
                     if req.limit and len(resp.traces) >= req.limit:
                         break
             finally:
@@ -740,9 +755,60 @@ class VtpuBackendBlock:
         resp.coalesced_reads = self.coalesced_reads - coalesced_before
         return resp
 
+    def _resident_masks(self, live: list, req, preds) -> list[dict]:
+        """The span predicates' masks over the row groups whose pages the
+        device tier holds, {column: mask} for each of `live`: every
+        predicate is asked of all those row groups in ONE dispatch a
+        shape bucket (ops/scan.resident_*_masks), not one a page, in the
+        order _search_row_group asks them and, as there, only of the
+        row groups in which a span survived the predicates before. A row
+        group whose page is not resident drops out (its column is filed
+        as None: asked, not held); its own loop goes on from there."""
+        from tempo_tpu.encoding.vtpu.colcache import shared_device_tier
+        from tempo_tpu.ops import scan
+
+        have: list[dict] = [{} for _ in live]
+        tier = shared_device_tier() if self._colcache is not None else None
+        if tier is None:
+            return have
+        asks = [(col, codes, None) for col, codes in preds["span_eq"]]
+        if req.min_duration_ns or req.max_duration_ns:
+            asks.append(("duration_nano", None, (
+                np.uint64(req.min_duration_ns or 0),
+                np.uint64(req.max_duration_ns or ((1 << 64) - 1)))))
+        alive = {i: None for i, rg in enumerate(live) if rg.n_spans}
+        for col, codes, bounds in asks:
+            entries = {}
+            for i in alive:
+                enc = self.encoded_column(live[i], col)
+                if enc is not None:
+                    have[i][col] = None  # asked: the page's one lookup
+                    res = enc.resident()
+                    if res is not None:
+                        entries[i] = res
+            if not entries:
+                break
+            masks = (scan.resident_in_set_masks(list(entries.values()), codes)
+                     if bounds is None else
+                     scan.resident_range_masks(list(entries.values()), *bounds))
+            survivors = {}
+            for (i, res), m in zip(entries.items(), masks):
+                if m is None:
+                    continue
+                tier.record_avoided(res.host_bytes,
+                                    kernel=f"resident_{res.codec}_scan")
+                have[i][col] = m
+                m = m if alive[i] is None else alive[i] & m
+                if m.any():
+                    survivors[i] = m
+            alive = survivors
+        return have
+
     def _search_row_group(self, rg, req, preds, limit: int,
-                          have_cols: dict | None = None) -> list[TraceSearchMetadata]:
-        """limit: max hits to return; 0 means unbounded.
+                          have_cols: dict | None = None,
+                          have_masks: dict | None = None) -> list[TraceSearchMetadata]:
+        """limit: max hits to return; 0 means unbounded. have_masks:
+        predicate masks already evaluated (_resident_masks), by column.
 
         Lazy projection in three stages: the most selective predicate's
         column alone (usually prefetched), then — only if spans survive —
@@ -754,6 +820,7 @@ class VtpuBackendBlock:
         if n == 0:
             return []
         cols = dict(have_cols or {})
+        masks = have_masks or {}
         span_mask = np.ones(n, bool)
         dur_pred = bool(req.min_duration_ns or req.max_duration_ns)
 
@@ -763,11 +830,11 @@ class VtpuBackendBlock:
             return self.encoded_column(rg, name) is not None
 
         for k, (col, codes) in enumerate(preds["span_eq"]):
-            m = None
-            if col not in cols:
+            m = masks.get(col)
+            if m is None and col not in cols:
                 enc = self.encoded_column(rg, col)
                 if enc is not None:
-                    m = enc.in_set_mask(codes)
+                    m = enc.in_set_mask(codes, resident=col not in masks)
             if m is None:
                 if col not in cols:
                     if k == 0:
@@ -789,11 +856,12 @@ class VtpuBackendBlock:
         if dur_pred:
             lo = req.min_duration_ns or 0
             hi = req.max_duration_ns or ((1 << 64) - 1)
-            m = None
-            if "duration_nano" not in cols:
+            m = masks.get("duration_nano")
+            if m is None and "duration_nano" not in cols:
                 enc = self.encoded_column(rg, "duration_nano")
                 if enc is not None:
-                    m = enc.range_mask(np.uint64(lo), np.uint64(hi))
+                    m = enc.range_mask(np.uint64(lo), np.uint64(hi),
+                                       resident="duration_nano" not in masks)
             if m is None:
                 if "duration_nano" not in cols:
                     cols.update(self.read_columns(rg, ["duration_nano"]))
@@ -802,6 +870,16 @@ class VtpuBackendBlock:
             span_mask &= m
             if not span_mask.any():
                 return []
+            if "duration_nano" in masks and self._colcache is not None:
+                # the device tier answered and left no decoded column
+                # behind: where the host cache holds one (another query
+                # decoded it), the hits' gathers index it, as they do
+                # the column the host path's compare reads; else they
+                # unpack the page's miniblocks row by row
+                dec = self._colcache.get((self.meta.block_id, "duration_nano",
+                                          rg.pages["duration_nano"].offset))
+                if dec is not None:
+                    cols["duration_nano"] = dec
 
         # attr predicates: evaluate over the attr table then AND per-span
         if preds["attr"]:

@@ -163,9 +163,12 @@ def _register_metrics(cache) -> None:
             f"Column cache {name} by tier (host=decoded arrays, "
             "device=resident compressed pages; colcache.stats)",
         )
-        # the tail_* trio only ever appears on the device tier (the
-        # ingest_tail keyspace); host stats simply never set them
+        # `admissions` and the tail_* trio only ever appear on the device
+        # tier; host stats simply never set them. (`avoided_bytes` stays
+        # in stats(): tempo_tpu_device_transfer_bytes_avoided_total
+        # already counts the same bytes at the same statement)
         for name in ("hits", "misses", "evictions", "bytes", "entries",
+                     "admissions",
                      "tail_bytes", "tail_entries", "tail_max_bytes")
     }
 

@@ -164,56 +164,87 @@ def runs_firsts_seg(run_lengths: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
 # run-space host helpers above EXACTLY — code-set padding repeats a real
 # code instead of a sentinel, so device membership is np.isin
 # bit-for-bit even against pathological column values.
+#
+# Each program takes a TUPLE of pages of one shape bucket and answers
+# them in one dispatch, (k, n) bool: a dispatch costs milliseconds of
+# waiting for the interpreter around microseconds of device, so a scan
+# over a block's row groups pays it once a bucket, not once a page.
 
 
 @functools.partial(jax.jit, static_argnames=("n", "invert"))
 def _rle_in_set_resident_jit(values, lengths, codes, n: int, invert: bool):
-    """values/lengths (R,) resident; codes (K,) shipped -> (n,) bool."""
-    run_hit = jnp.any(values[:, None] == codes[None, :].astype(values.dtype),
-                      axis=1)
-    if invert:
-        run_hit = ~run_hit
-    return jnp.repeat(run_hit, lengths, total_repeat_length=n)
+    """values/lengths k x (R,) resident; codes (K,) shipped -> (k, n) bool."""
+    def page(v, l):
+        run_hit = jnp.any(v[:, None] == codes[None, :].astype(v.dtype), axis=1)
+        if invert:
+            run_hit = ~run_hit
+        return jnp.repeat(run_hit, l, total_repeat_length=n)
+
+    return jax.vmap(page)(jnp.stack(values), jnp.stack(lengths))
 
 
 @functools.partial(jax.jit, static_argnames=("n",))
 def _rle_between_resident_jit(values, lengths, lo, hi, n: int):
-    run_hit = (values >= lo.astype(values.dtype)) \
-        & (values <= hi.astype(values.dtype))
-    return jnp.repeat(run_hit, lengths, total_repeat_length=n)
+    def page(v, l):
+        run_hit = (v >= lo.astype(v.dtype)) & (v <= hi.astype(v.dtype))
+        return jnp.repeat(run_hit, l, total_repeat_length=n)
+
+    return jax.vmap(page)(jnp.stack(values), jnp.stack(lengths))
 
 
 @functools.partial(jax.jit, static_argnames=("invert",))
 def _dct_in_set_resident_jit(dvals, idx, codes, invert: bool):
-    """dvals (V,) page dictionary + idx (n,) resident -> (n,) bool: the
-    verdict is computed once per dictionary ENTRY and gathered by the
-    resident index — the dct analog of the per-run verdict."""
-    hit = jnp.any(dvals[:, None] == codes[None, :].astype(dvals.dtype),
-                  axis=1)
-    if invert:
-        hit = ~hit
-    return hit[idx]
+    """dvals k x (V,) page dictionaries + idx k x (n,) resident -> (k, n)
+    bool: the verdict is computed once per dictionary ENTRY and gathered
+    by the resident index — the dct analog of the per-run verdict."""
+    def page(dv, ix):
+        hit = jnp.any(dv[:, None] == codes[None, :].astype(dv.dtype), axis=1)
+        if invert:
+            hit = ~hit
+        return hit[ix]
+
+    return jax.vmap(page)(jnp.stack(dvals), jnp.stack(idx))
 
 
 @jax.jit
 def _dct_between_resident_jit(dvals, idx, lo, hi):
-    hit = (dvals >= lo.astype(dvals.dtype)) & (dvals <= hi.astype(dvals.dtype))
-    return hit[idx]
+    def page(dv, ix):
+        hit = (dv >= lo.astype(dv.dtype)) & (dv <= hi.astype(dv.dtype))
+        return hit[ix]
+
+    return jax.vmap(page)(jnp.stack(dvals), jnp.stack(idx))
 
 
 @functools.partial(jax.jit, static_argnames=("n",))
-def _dbp_between_resident_jit(words, first_hi, first_lo, width, bounds,
-                              n: int):
-    """Resident packed-delta words -> range verdict, decode fused in:
-    the same _dbp_decode_jit the shipped path uses (bit-identical limbs)
-    followed by the two-limb u64 compare. bounds (4,) uint32 =
-    [lo_hi, lo_lo, hi_hi, hi_lo]."""
+def _dbp_between_resident_jit(words, heads, bounds, n: int):
+    """Resident packed-delta words k x (W,) -> (k, n) range verdict,
+    decode fused in: the same _dbp_decode_jit the shipped path uses
+    (bit-identical limbs) followed by the two-limb u64 compare. heads
+    (k, 3) uint32: each page's own [first_hi, first_lo, width]; bounds
+    (4,) uint32 = [lo_hi, lo_lo, hi_hi, hi_lo]. The pages run one after
+    another (lax.map): the decode's scan vmapped over 8 or 16 pages
+    takes the TPU's compiler 10 and 35 s, mapped 1-3 s whatever k."""
     from tempo_tpu.ops.pallas_kernels import _dbp_decode_jit
 
-    h, l = _dbp_decode_jit(words, first_hi, first_lo, width, n)
-    ge = (h > bounds[0]) | ((h == bounds[0]) & (l >= bounds[1]))
-    le = (h < bounds[2]) | ((h == bounds[2]) & (l <= bounds[3]))
-    return ge & le
+    def page(args):
+        w, head = args
+        h, l = _dbp_decode_jit(w, head[0], head[1], head[2].astype(jnp.int32), n)
+        ge = (h > bounds[0]) | ((h == bounds[0]) & (l >= bounds[1]))
+        le = (h < bounds[2]) | ((h == bounds[2]) & (l <= bounds[3]))
+        return ge & le
+
+    return jax.lax.map(page, (jnp.stack(words), heads))
+
+
+def pad_pow2(a: np.ndarray, fill) -> np.ndarray:
+    """1-D `a` extended with `fill` to the next power of two (an empty
+    array to one element): the shape rule of code sets and of resident
+    payloads, so that a jitted scan compiles once a bucket and not once
+    a length."""
+    k = 1 << max(0, a.size - 1).bit_length()
+    if k == a.size:
+        return a
+    return np.concatenate([a, np.full(k - a.size, fill, a.dtype)])
 
 
 def pad_codes_u32(codes: np.ndarray) -> np.ndarray:
@@ -226,83 +257,113 @@ def pad_codes_u32(codes: np.ndarray) -> np.ndarray:
     codes = np.asarray(codes).astype(np.uint32, copy=False).reshape(-1)
     if codes.size == 0:
         codes = np.array([NO_MATCH_CODE], np.uint32)
-    k = 1
-    while k < codes.size:
-        k <<= 1
-    if k == codes.size:
-        return codes
-    return np.concatenate([codes, np.full(k - codes.size, codes[0], np.uint32)])
+    return pad_pow2(codes, codes[0])
 
 
 _pad_codes_u32 = pad_codes_u32  # compat alias for older call sites
 
 
-def resident_in_set_mask(res, codes: np.ndarray,
-                         invert: bool = False) -> np.ndarray | None:
-    """Row mask for `column in codes` served from one resident entry
-    (colcache._Resident duck type: .codec/.arrays/.meta), or None when
-    the resident form cannot answer (dbp). Dispatches under the timing
-    seam: the resident arrays count as `resident`, never h2d — only the
-    code set ships."""
+# pages answered by one resident dispatch at most: bounds the (k, n)
+# intermediates of the decode, and the programs of a bucket to five sizes
+_RESIDENT_GROUP = 16
+
+
+def _resident_scan(out: list, entries, codec: str, names, dispatch) -> None:
+    """Answer every `codec` page of `entries` into `out`, one dispatch a
+    group: pages grouped by what a resident program is compiled on (row
+    count and the shapes of the arrays `names`), at most _RESIDENT_GROUP
+    a group, each group extended to a power of two by repeating its last
+    page, so that a bucket compiles once a size class and not once a
+    count. dispatch(members, *stacks) -> (k, n) device verdict, one
+    tuple of k arrays for each of `names`."""
+    groups: dict = {}
+    for pos, res in enumerate(entries):
+        if res.codec == codec and int(res.meta["n"]) > 0:
+            key = (int(res.meta["n"]),
+                   tuple(res.arrays[nm].shape for nm in names))
+            groups.setdefault(key, []).append(pos)
+    for positions in groups.values():
+        for at in range(0, len(positions), _RESIDENT_GROUP):
+            chunk = positions[at:at + _RESIDENT_GROUP]
+            members = [entries[pos] for pos in chunk]
+            members += members[-1:] * ((1 << (len(chunk) - 1).bit_length())
+                                       - len(chunk))
+            mask = np.asarray(dispatch(
+                members, *(tuple(m.arrays[nm] for m in members) for nm in names)))
+            for row, pos in enumerate(chunk):
+                out[pos] = mask[row]
+
+
+def resident_in_set_masks(entries, codes: np.ndarray,
+                          invert: bool = False) -> list:
+    """Row masks for `column in codes`, one for each resident entry
+    (colcache._Resident duck type: .codec/.arrays/.meta), None where the
+    resident form cannot answer (dbp). One dispatch a shape bucket under
+    the timing seam: the resident arrays count as `resident`, never h2d
+    — only the code set ships."""
     from tempo_tpu.util.devicetiming import timed_dispatch
 
     codes = _pad_codes_u32(codes)
-    n = int(res.meta["n"])
-    if res.codec == "rle":
-        if n == 0:
-            return np.zeros(0, bool)
-        mask = timed_dispatch(
-            "resident_rle_scan", _rle_in_set_resident_jit,
-            res.arrays["values"], res.arrays["lengths"], codes, n,
-            bool(invert))
-        return np.asarray(mask)
-    if res.codec == "dct":
-        if n == 0:
-            return np.zeros(0, bool)
-        mask = timed_dispatch(
-            "resident_dct_scan", _dct_in_set_resident_jit,
-            res.arrays["values"], res.arrays["idx"], codes, bool(invert))
-        return np.asarray(mask)
-    return None
+    out: list = [np.zeros(0, bool) if res.codec in ("rle", "dct")
+                 and int(res.meta["n"]) == 0 else None for res in entries]
+    _resident_scan(
+        out, entries, "rle", ("values", "lengths"),
+        lambda ms, values, lengths: timed_dispatch(
+            "resident_rle_scan", _rle_in_set_resident_jit, values, lengths,
+            codes, int(ms[0].meta["n"]), bool(invert)))
+    _resident_scan(
+        out, entries, "dct", ("values", "idx"),
+        lambda ms, dvals, idx: timed_dispatch(
+            "resident_dct_scan", _dct_in_set_resident_jit, dvals, idx,
+            codes, bool(invert)))
+    return out
+
+
+def resident_range_masks(entries, lo, hi) -> list:
+    """Row masks for lo <= column <= hi, one for each resident entry;
+    dbp pages answer by fusing the device delta-decode into the compare."""
+    from tempo_tpu.util.devicetiming import timed_dispatch
+
+    out: list = [np.zeros(0, bool) if int(res.meta["n"]) == 0 else None
+                 for res in entries]
+    if any(res.codec != "dbp" for res in entries):
+        lo32, hi32 = np.uint32(lo), np.uint32(hi)
+        _resident_scan(
+            out, entries, "rle", ("values", "lengths"),
+            lambda ms, values, lengths: timed_dispatch(
+                "resident_rle_scan", _rle_between_resident_jit, values,
+                lengths, lo32, hi32, int(ms[0].meta["n"])))
+        _resident_scan(
+            out, entries, "dct", ("values", "idx"),
+            lambda ms, dvals, idx: timed_dispatch(
+                "resident_dct_scan", _dct_between_resident_jit, dvals, idx,
+                lo32, hi32))
+    lo64, hi64 = int(lo), int(hi)
+    bounds = np.array(
+        [lo64 >> 32, lo64 & 0xFFFFFFFF, hi64 >> 32, hi64 & 0xFFFFFFFF],
+        np.uint32)
+
+    def dbp(ms, words):
+        heads = np.array(
+            [[int(m.meta["first"]) >> 32, int(m.meta["first"]) & 0xFFFFFFFF,
+              int(m.meta["width"])] for m in ms], np.uint32)
+        return timed_dispatch(
+            "resident_dbp_scan", _dbp_between_resident_jit, words, heads,
+            bounds, int(ms[0].meta["n"]))
+
+    _resident_scan(out, entries, "dbp", ("words",), dbp)
+    return out
+
+
+def resident_in_set_mask(res, codes: np.ndarray,
+                         invert: bool = False) -> np.ndarray | None:
+    """resident_in_set_masks of one page."""
+    return resident_in_set_masks([res], codes, invert=invert)[0]
 
 
 def resident_range_mask(res, lo, hi) -> np.ndarray | None:
-    """Row mask for lo <= column <= hi from one resident entry; dbp
-    pages answer by fusing the device delta-decode into the compare."""
-    from tempo_tpu.util.devicetiming import timed_dispatch
-
-    n = int(res.meta["n"])
-    if res.codec == "rle":
-        if n == 0:
-            return np.zeros(0, bool)
-        mask = timed_dispatch(
-            "resident_rle_scan", _rle_between_resident_jit,
-            res.arrays["values"], res.arrays["lengths"],
-            np.uint32(lo), np.uint32(hi), n)
-        return np.asarray(mask)
-    if res.codec == "dct":
-        if n == 0:
-            return np.zeros(0, bool)
-        mask = timed_dispatch(
-            "resident_dct_scan", _dct_between_resident_jit,
-            res.arrays["values"], res.arrays["idx"],
-            np.uint32(lo), np.uint32(hi))
-        return np.asarray(mask)
-    if res.codec == "dbp":
-        if n == 0:
-            return np.zeros(0, bool)
-        lo64, hi64 = int(lo), int(hi)
-        bounds = np.array(
-            [lo64 >> 32, lo64 & 0xFFFFFFFF, hi64 >> 32, hi64 & 0xFFFFFFFF],
-            np.uint32)
-        first = int(res.meta["first"])
-        mask = timed_dispatch(
-            "resident_dbp_scan", _dbp_between_resident_jit,
-            res.arrays["words"],
-            np.uint32(first >> 32), np.uint32(first & 0xFFFFFFFF),
-            np.int32(res.meta["width"]), bounds, n)
-        return np.asarray(mask)
-    return None
+    """resident_range_masks of one page."""
+    return resident_range_masks([res], lo, hi)[0]
 
 
 # ---------------------------------------------------------------------------

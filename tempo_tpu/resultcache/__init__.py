@@ -61,7 +61,7 @@ import zlib
 
 from tempo_tpu.backend import faults as faults_mod
 from tempo_tpu.cache.client import LRUCache
-from tempo_tpu.util import metrics, usage
+from tempo_tpu.util import metrics, tracing, usage
 from tempo_tpu.util.queryshape import KEYSPACE_VERSION
 
 # entry-layout version: bump when the framed document schema changes
@@ -226,8 +226,9 @@ class ResultCache:
         happens here: the untagged kind-labelled counters and the active
         per-tenant cost vector move at the same statement."""
         k = self.key(tenant, block_id, kind, fp, subrange)
-        raw = self._fetch_raw(k)
-        doc = decode_entry(raw)
+        with tracing.span("resultcache/lookup"):
+            raw = self._fetch_raw(k)
+            doc = decode_entry(raw)
         if doc is None:
             if raw is not None:
                 rc_corrupt.inc(kind=kind)
@@ -253,10 +254,11 @@ class ResultCache:
         return doc
 
     def _store(self, k: str, doc: dict) -> None:
-        raw = encode_entry(doc)
-        self._local.store([k], [raw])
-        if self._remote is not None:
-            self._remote.store([k], [raw])
+        with tracing.span("resultcache/store"):
+            raw = encode_entry(doc)
+            self._local.store([k], [raw])
+            if self._remote is not None:
+                self._remote.store([k], [raw])
 
     def put(self, tenant: str, block_id: str, kind: str, fp: str,
             wire, bytes_saved: int = 0, subrange: str = "all") -> None:

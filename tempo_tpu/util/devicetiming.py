@@ -243,3 +243,5 @@ def timed_dispatch(kernel: str, fn, *args, ship: bool = True, **kwargs):
         usage.charge("device_seconds", dt)
         usage.charge("device_dispatches")
         count_transfer(kernel, h2d=h2d, d2h=d2h, resident=resident)
+        if ann is not None:
+            profiling.dispatch_bytes_total.inc(h2d + d2h + resident, kernel=kernel)

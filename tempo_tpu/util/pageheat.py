@@ -526,11 +526,25 @@ def admission_report(budget_bytes: int | None = None,
     """One admission decision, explained: the what-if knee, the
     effective budget (knee capped by the configured tier budget when
     given), and the candidate set at that budget — what `cli analyse
-    device --resident` and the tier's refresh both read."""
+    device --resident` and the tier's refresh both read.
+
+    The knee rations a budget the working set does not fit in. Where
+    every page the ledger holds fits the configured budget at once,
+    nothing competes for it and the whole budget is effective: else the
+    knee (a sixteenth to the whole of the working set, moving with the
+    access stream) is packed hottest-first with pages no scan ever asks
+    the tier for, and the pages that scans do ask for trickle in over
+    dozens of refreshes. A ledger that has seen no reuse still admits
+    nothing (knee 0)."""
     ledger = ledger or LEDGER
     report = what_if_report(ledger=ledger)
     knee = knee_budget(report["curve"])
-    effective = knee if budget_bytes is None else min(knee, int(budget_bytes))
+    if budget_bytes is None:
+        effective = knee
+    elif knee and report["uniqueEncodedBytes"] <= int(budget_bytes):
+        effective = int(budget_bytes)
+    else:
+        effective = min(knee, int(budget_bytes))
     cands = admission_candidates(effective, ledger=ledger, min_ships=min_ships)
     return {
         "kneeBudgetBytes": knee,

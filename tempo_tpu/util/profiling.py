@@ -424,7 +424,9 @@ def read_capture(out_dir: str) -> tuple:
 
 
 # the capture's counters: every tempo_tpu_profile_* family grows only when a
-# capture ends, and only where the trace holds a device plane
+# capture ends, and only where the trace holds a device plane (but
+# dispatch_bytes_total, which timed_dispatch adds to as an annotated
+# dispatch returns: the trace does not carry a dispatch's bytes)
 device_idle_seconds_total = metrics.counter(
     "tempo_tpu_profile_device_idle_seconds_total",
     "Seconds inside captures in which no device operation ran, by what the "
@@ -443,6 +445,13 @@ dispatch_device_seconds_total = metrics.counter(
 dispatches_total = metrics.counter(
     "tempo_tpu_profile_dispatches_total",
     "dispatch/* annotations inside captures, by kernel",
+)
+dispatch_bytes_total = metrics.counter(
+    "tempo_tpu_profile_dispatch_bytes_total",
+    "Bytes the dispatches that wrote a dispatch/* annotation took as "
+    "arguments (shipped or resident) and gave as results, by kernel: "
+    "tempo_tpu_device_transfer_bytes_total's count, held to the dispatches "
+    "a capture saw, so that it divides by their device seconds",
 )
 mesh_skew_seconds_total = metrics.counter(
     "tempo_tpu_profile_mesh_skew_seconds_total",

@@ -224,6 +224,24 @@ class TestAdmissionPolicy:
         flat = [{"budgetBytes": b, "savedBytes": 0} for b in (10, 20, 30)]
         assert pageheat.knee_budget(flat) == 0
 
+    def test_a_working_set_that_fits_the_budget_is_not_rationed_by_the_knee(self):
+        """The knee rations a budget the working set exceeds. With room
+        for every page the ledger holds, every re-shipped page is a
+        candidate at the first refresh; a cold ledger still admits none."""
+        led = self._ledger()
+        unique = pageheat.what_if_report(ledger=led)["uniqueEncodedBytes"]
+        tight = pageheat.admission_report(budget_bytes=unique - 1, ledger=led)
+        assert tight["effectiveBudgetBytes"] == tight["kneeBudgetBytes"] < unique
+        roomy = pageheat.admission_report(budget_bytes=unique, ledger=led)
+        assert roomy["effectiveBudgetBytes"] == unique > roomy["kneeBudgetBytes"]
+        assert {(c["block"], c["column"]) for c in roomy["candidates"]} == {
+            ("blk-hot", "service"), ("blk-hot", "name")}
+        cold = pageheat.PageHeatLedger()
+        for i in range(10):
+            cold.touch(f"b{i}", "service", 0, moved_bytes=150_000, encoded_bytes=9_000)
+        rep = pageheat.admission_report(budget_bytes=1 << 30, ledger=cold)
+        assert rep["effectiveBudgetBytes"] == 0 and not rep["candidates"]
+
     def test_tier_admits_only_inside_admission_set(self):
         tier = colcache.DeviceTier(32 << 20, refresh_s=3600.0)
         tier._admit_keys = frozenset({("blk-hot", "service", 0)})
@@ -455,3 +473,316 @@ class TestTierObservability:
     def test_report_disabled_without_tier(self):
         assert colcache._shared_device is None
         assert colcache.device_tier_report() == {"enabled": False}
+
+
+# ---------------------------------------------------------------------------
+# 6. resident payloads in bucketed shapes: one program a bucket, masks
+#    bit-identical to the host path at the padding's edges
+# ---------------------------------------------------------------------------
+
+
+def _page(codec: str, rows: np.ndarray):
+    """EncodedColumn over one encoded page held in memory: the real
+    resident_payload / in_set_mask / range_mask, no block behind it
+    (`_colcache` None: the host path, never a tier)."""
+    import types
+
+    from tempo_tpu.encoding.vtpu.block import EncodedColumn
+
+    page = {"rle": lw.rle_encode, "dct": lw.dct_encode, "dbp": lw.dbp_encode}[codec](rows)
+    col = object.__new__(EncodedColumn)
+    col.blk = types.SimpleNamespace(_colcache=None)
+    col.name, col.codec, col.n = "c", codec, rows.shape[0]
+    col.pm = types.SimpleNamespace(shape=rows.shape, dtype=rows.dtype.str,
+                                   offset=0, codec=codec)
+    col._page = lambda: page
+    col.runs = lambda: lw.rle_decode_runs(page, rows.dtype.str, rows.shape)
+    col._dct_indices = lambda: lw.dct_indices(page, rows.dtype.str, rows.shape)
+    return col
+
+
+def _resident_of(col):
+    codec, arrays, meta, host_bytes = col.resident_payload()
+    return colcache._Resident(
+        codec, {k: jnp.asarray(v) for k, v in arrays.items()}, meta, host_bytes)
+
+
+def _rle_rows(rng, n: int, runs: int, values) -> np.ndarray:
+    """n rows in exactly `runs` runs, neighbours distinct, from `values`."""
+    cuts = np.sort(rng.choice(np.arange(1, n), runs - 1, replace=False))
+    lengths = np.diff(np.concatenate([[0], cuts, [n]]))
+    vals = [int(rng.choice(values))]
+    while len(vals) < runs:
+        v = int(rng.choice(values))
+        if v != vals[-1]:
+            vals.append(v)
+    return np.repeat(np.asarray(vals, np.uint32), lengths)
+
+
+def _dct_rows(rng, n: int, dict_size: int, lo: int = 0) -> np.ndarray:
+    """n rows over a page dictionary of exactly `dict_size` entries."""
+    entries = (lo + 3 * np.arange(dict_size)).astype(np.uint32)
+    rows = np.concatenate([entries, rng.choice(entries, n - dict_size)])
+    return rng.permutation(rows).astype(np.uint32)
+
+
+def _dbp_rows(rng, n: int, max_delta: int) -> np.ndarray:
+    return (np.cumsum(rng.integers(0, max_delta, n))
+            + 17_000_000_000_000).astype(np.uint64)
+
+
+def _is_pow2(k: int) -> bool:
+    return k >= 1 and k & (k - 1) == 0
+
+
+class TestBucketedResidentShapes:
+    N = 512
+
+    @pytest.mark.parametrize("codec,jits,pages", [
+        # five run counts in (32, 64], then one in (64, 128]
+        ("rle", ("_rle_in_set_resident_jit", "_rle_between_resident_jit"),
+         [(33,), (40,), (51,), (57,), (64,), (65,)]),
+        # five dictionary sizes in (16, 32], then one in (32, 64]
+        ("dct", ("_dct_in_set_resident_jit", "_dct_between_resident_jit"),
+         [(17,), (20,), (25,), (31,), (32,), (33,)]),
+        # delta widths 4, 5, 6, 7, 7 bits: 65, 81, 97, 113, 113 words, all
+        # in (64, 128]; then 9 bits, 145 words, in (128, 256]
+        ("dbp", ("_dbp_between_resident_jit",),
+         [(8,), (16,), (32,), (64,), (60,), (250,)]),
+    ])
+    def test_one_resident_program_a_bucket(self, codec, jits, pages):
+        """Pages whose data-dependent length differs compile at most one
+        resident program a power-of-two bucket; each mask equals the
+        host path's (numpy's for dbp, which the host path cannot answer)."""
+        rng = np.random.default_rng(7)
+        fns = [getattr(scan_mod, j) for j in jits]
+        buckets = set()
+        size0 = [f._cache_size() for f in fns]
+        for (k,) in pages:
+            if codec == "rle":
+                rows = _rle_rows(rng, self.N, k, np.arange(1, 9))
+            elif codec == "dct":
+                rows = _dct_rows(rng, self.N, k, lo=5)
+            else:
+                rows = _dbp_rows(rng, self.N, k)
+            col = _page(codec, rows)
+            res = _resident_of(col)
+            for name, a in res.arrays.items():
+                if name != "idx":  # one a row: the page's own row count
+                    assert _is_pow2(a.shape[0]), (name, a.shape)
+            buckets.add(tuple(sorted((n, a.shape[0]) for n, a in res.arrays.items())))
+            lo, hi = int(rows[40]), int(rows[460])
+            lo, hi = min(lo, hi), max(lo, hi)
+            want = (rows >= lo) & (rows <= hi)
+            np.testing.assert_array_equal(scan_mod.resident_range_mask(res, lo, hi), want)
+            if codec != "dbp":
+                np.testing.assert_array_equal(col.range_mask(lo, hi), want)
+                codes = np.unique(rows[:3]).astype(np.uint32)[:2]
+                np.testing.assert_array_equal(
+                    scan_mod.resident_in_set_mask(res, codes), col.in_set_mask(codes))
+        assert len(buckets) == 2, buckets
+        for f, s0 in zip(fns, size0):
+            assert f._cache_size() - s0 <= len(buckets), (f, s0, f._cache_size())
+
+    @pytest.mark.parametrize("case", [
+        "rle_zero_length_pad_runs", "rle_column_holds_zero", "rle_invert",
+        "rle_short_page", "dct_repeated_entry_pad", "dct_column_holds_zero",
+        "dct_invert", "dct_short_page", "dbp_zero_words_pad", "dbp_short_page",
+    ])
+    def test_masks_equal_the_host_path_at_the_paddings_edges(self, case):
+        rng = np.random.default_rng(11)
+        codec, _, what = case.partition("_")
+        n = 37 if what == "short_page" else self.N
+        if codec == "rle":
+            # 5 runs pad to 8: three zero-length runs whose value is 0
+            values = np.arange(0, 4) if what == "column_holds_zero" else np.arange(1, 9)
+            rows = _rle_rows(rng, n, 5, values)
+        elif codec == "dct":
+            # 5 entries pad to 8 by repeating the first, which the column holds
+            rows = _dct_rows(rng, n, 5, lo=0 if what == "column_holds_zero" else 9)
+        else:
+            rows = _dbp_rows(rng, n, 40)
+        col = _page(codec, rows)
+        res = _resident_of(col)
+        payload = col.resident_payload()[1]
+        if codec == "rle":
+            assert payload["lengths"].shape[0] == 8
+            assert int(payload["lengths"].sum()) == n and (payload["lengths"][5:] == 0).all()
+        elif codec == "dct":
+            assert payload["values"].shape[0] == 8
+            assert (payload["values"][5:] == payload["values"][0]).all()
+            assert int(payload["idx"].max()) < 5
+        else:
+            unpadded = lw.dbp_parts(col._page(), rows.dtype.str, rows.shape)[3][0]
+            real = (len(bytes(unpadded)) + 3) // 4 + 1  # words + the guard word
+            assert _is_pow2(payload["words"].shape[0]) and payload["words"].shape[0] >= real
+            assert not payload["words"][real:].any()
+        # the pad is part of what the tier charges its budget with
+        assert res.nbytes == sum(a.nbytes for a in payload.values())
+        lo, hi = sorted((int(rows[3]), int(rows[n - 4])))
+        for a, b in ((lo, hi), (0, 0), (int(rows.min()), int(rows.max())), (hi + 1, hi + 2)):
+            want = (rows >= a) & (rows <= b)
+            np.testing.assert_array_equal(scan_mod.resident_range_mask(res, a, b), want)
+            if codec != "dbp":
+                np.testing.assert_array_equal(col.range_mask(a, b), want)
+        if codec == "dbp":
+            return
+        first = int(payload["values"][0])
+        for codes in ([0], [first], [first, int(rows[1])], [], [0xFFFFFFFF]):
+            codes = np.asarray(codes, np.uint32)
+            for invert in (False, True):
+                if what == "invert" and not invert:
+                    continue
+                got = scan_mod.resident_in_set_mask(res, codes, invert=invert)
+                np.testing.assert_array_equal(got, np.isin(rows, codes, invert=invert))
+                np.testing.assert_array_equal(got, col.in_set_mask(codes, invert=invert))
+
+
+# ---------------------------------------------------------------------------
+# 7. one resident dispatch a shape bucket, not one a page
+# ---------------------------------------------------------------------------
+
+
+def _resident_dispatches() -> float:
+    return sum(devicetiming.dispatch_total.total(kernel=f"resident_{c}_scan")
+               for c in ("rle", "dct", "dbp"))
+
+
+class TestOneDispatchABucket:
+    N = 256
+
+    def _pages(self, codec, sizes, n=None):
+        rng = np.random.default_rng(23)
+        n = n or self.N
+        make = {"rle": lambda k: _rle_rows(rng, n, k, np.arange(0, 8)),
+                "dct": lambda k: _dct_rows(rng, n, k, lo=0),
+                "dbp": lambda k: _dbp_rows(rng, n, k)}[codec]
+        cols = [_page(codec, make(k)) for k in sizes]
+        return cols, [_resident_of(c) for c in cols]
+
+    @pytest.mark.parametrize("codec,sizes,dispatches", [
+        # run counts: five in (16, 32] and two in (32, 64]: two programs' shapes
+        ("rle", (17, 20, 25, 31, 32, 33, 40), 2),
+        ("dct", (17, 20, 25, 31, 32, 33, 40), 2),
+        # 4..7 bit deltas share a word bucket at 256 rows, 9 bits is the next
+        ("dbp", (8, 16, 32, 64, 60, 250), 2),
+        # 17 pages of one shape: the group is cut at 16
+        ("rle", (20,) * 17, 2),
+        # 5 pages of one shape: one dispatch, extended to 8 by repeating the last
+        ("dct", (20,) * 5, 1),
+    ])
+    def test_many_pages_one_dispatch_a_shape_group(self, codec, sizes, dispatches):
+        cols, entries = self._pages(codec, sizes)
+        rows = [lw.rle_decode(c._page(), c.pm.dtype, c.pm.shape) if codec == "rle"
+                else lw.dct_decode(c._page(), c.pm.dtype, c.pm.shape) if codec == "dct"
+                else lw.dbp_decode(c._page(), c.pm.dtype, c.pm.shape) for c in cols]
+        lo, hi = sorted((int(rows[0][9]), int(rows[-1][200])))
+        d0 = _resident_dispatches()
+        got = scan_mod.resident_range_masks(entries, lo, hi)
+        assert _resident_dispatches() - d0 == dispatches
+        for r, m in zip(rows, got):
+            np.testing.assert_array_equal(m, (r >= lo) & (r <= hi))
+        if codec == "dbp":
+            assert scan_mod.resident_in_set_masks(entries, np.array([1], np.uint32)) \
+                == [None] * len(entries)
+            return
+        codes = np.array([0, int(rows[0][1])], np.uint32)
+        for invert in (False, True):
+            d0 = _resident_dispatches()
+            got = scan_mod.resident_in_set_masks(entries, codes, invert=invert)
+            assert _resident_dispatches() - d0 == dispatches
+            for c, r, m in zip(cols, rows, got):
+                np.testing.assert_array_equal(m, np.isin(r, codes, invert=invert))
+                np.testing.assert_array_equal(m, c.in_set_mask(codes, invert=invert))
+
+    def test_codecs_and_row_counts_mixed_in_one_call(self):
+        """A short last row group and pages of every codec in one list:
+        each comes back in its own place, an empty page without a
+        dispatch."""
+        rle_c, rle_e = self._pages("rle", (20, 21))
+        short_c, short_e = self._pages("rle", (20,), n=37)
+        dct_c, dct_e = self._pages("dct", (9,))
+        dbp_c, dbp_e = self._pages("dbp", (16,))
+        empty = colcache._Resident("rle", {"values": jnp.zeros(1, jnp.uint32),
+                                           "lengths": jnp.zeros(1, jnp.int32)},
+                                   {"n": 0}, 0)
+        entries = [dbp_e[0], rle_e[0], short_e[0], empty, dct_e[0], rle_e[1]]
+        d0 = _resident_dispatches()
+        got = scan_mod.resident_range_masks(entries, 2, 5)
+        assert _resident_dispatches() - d0 == 4   # dbp, rle x 2 rows counts, dct
+        assert got[3].shape == (0,)
+        for c, m in zip((rle_c[0], short_c[0], dct_c[0], rle_c[1]),
+                        (got[1], got[2], got[4], got[5])):
+            np.testing.assert_array_equal(m, c.range_mask(2, 5))
+        dbp_rows = lw.dbp_decode(dbp_c[0]._page(), dbp_c[0].pm.dtype, dbp_c[0].pm.shape)
+        np.testing.assert_array_equal(got[0], (dbp_rows >= 2) & (dbp_rows <= 5))
+
+    @pytest.mark.parametrize("tags,min_duration_ns", [
+        (True, 0), (False, 1), (True, 1)])
+    def test_block_search_asks_each_predicate_of_all_row_groups_at_once(
+            self, device_tier, tags, min_duration_ns):
+        """A tag search over a block of many row groups, its pages
+        resident: dispatches count the predicates' shape groups, not the
+        pages; every page is looked up once a predicate; the hits equal
+        the tier-off search's."""
+        from tempo_tpu.encoding import from_version
+        from tempo_tpu.encoding.common import BlockConfig
+
+        # 256 spans a row group: the size at which durations go dbp
+        db = TempoDB(DBConfig(backend="mock", block=BlockConfig(row_group_spans=256)),
+                     raw_backend=MockBackend())
+        traces = synth.make_traces(600, seed=41, spans_per_trace=4)
+        db.write_batch("t", tr.traces_to_batch(traces).sorted_by_trace())
+        meta = next(iter(db.blocklist.metas("t")))
+        blk = from_version("vtpu1").open_block(meta, db.backend, db.cfg.block)
+        n_rgs = len(blk.index().row_groups)
+        assert n_rgs >= 8
+        req = SearchRequest(tags={"service.name": _svc(traces)} if tags else {},
+                            min_duration_ns=min_duration_ns, limit=0)
+        warm = blk.search(req)                     # admits every page it asks for
+        d0, l0 = _resident_dispatches(), device_tier.hits + device_tier.misses
+        hot = blk.search(req)
+        dispatched = _resident_dispatches() - d0
+        lookups = device_tier.hits + device_tier.misses - l0
+        predicates = int(tags) + int(bool(min_duration_ns))
+        assert 1 <= lookups <= predicates * n_rgs
+        assert 1 <= dispatched < lookups           # a group holds several pages
+        colcache._shared_device = None
+        cold = from_version("vtpu1").open_block(meta, db.backend, db.cfg.block).search(req)
+        assert _ids(warm) == _ids(hot) == _ids(cold) and _ids(cold)
+
+    def test_a_tier_answered_duration_leaves_the_hits_the_cached_column(
+            self, device_tier, monkeypatch):
+        """The host path's duration compare reads the decoded column and
+        hands it to the hit collection; a mask the tier answered leaves
+        none behind. Where the host cache holds the decoded column the
+        hits index it: as many page gathers as with the tier off, and
+        the same hits."""
+        from tempo_tpu.encoding import from_version
+        from tempo_tpu.encoding.common import BlockConfig
+
+        db = TempoDB(DBConfig(backend="mock", block=BlockConfig(row_group_spans=256)),
+                     raw_backend=MockBackend())
+        traces = synth.make_traces(600, seed=43, spans_per_trace=4)
+        db.write_batch("t", tr.traces_to_batch(traces).sorted_by_trace())
+        meta = next(iter(db.blocklist.metas("t")))
+        blk = from_version("vtpu1").open_block(meta, db.backend, db.cfg.block)
+        req = SearchRequest(tags={"service.name": _svc(traces)}, min_duration_ns=1,
+                            start_seconds=1, end_seconds=1 << 33, limit=0)
+        gathers = []
+        real = lw.dbp_gather
+        monkeypatch.setattr(lw, "dbp_gather",
+                            lambda *a, **k: gathers.append(1) or real(*a, **k))
+        colcache._shared_device = None
+        cold = blk.search(req)          # decodes duration_nano into the host cache
+        del gathers[:]
+        cold = blk.search(req)
+        off = len(gathers)
+        colcache._shared_device = device_tier
+        blk.search(req)                 # admits
+        del gathers[:]
+        d0 = _resident_dispatches()
+        hot = blk.search(req)
+        assert _resident_dispatches() > d0
+        assert len(gathers) == off
+        assert _ids(hot) == _ids(cold) and _ids(cold)
