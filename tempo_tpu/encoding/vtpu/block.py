@@ -82,6 +82,18 @@ inspected_bytes_total = metrics.counter(
     "Bytes read from backend storage by block readers (index, "
     "dictionary, bloom, coalesced page ranges), by tenant",
 )
+gathers_total = metrics.counter(
+    "tempodb_gathers_total",
+    "Selective reads of a lightweight page (EncodedColumn.gather), by "
+    "codec and by source: cached (the page's parsed form or runs came "
+    "from the column cache) or parsed (the page was parsed and "
+    "checksummed for this call)",
+)
+# every series exists from the start, so a window in which the cache
+# served nothing reads a cached share of 0, not a missing value
+for _codec in ("rle", "dct", "dbp"):
+    for _source in ("cached", "parsed"):
+        gathers_total.inc(0, codec=_codec, source=_source)
 # tenant series of the read counters evict with the usage accountant's
 # idle-tenant GC (the readers touch() the accountant on every account),
 # so a tenant-ID fuzzing querier can't grow /metrics forever
@@ -155,7 +167,9 @@ class EncodedColumn:
     eq/in_set/between evaluate per RUN (rle) or per page-DICTIONARY
     entry (dct) and the verdict expands as one bool per row: the values
     of unselected runs are never materialized. gather() reads only the
-    requested rows (rle: run lookup; dct: bit windows; dbp: miniblocks).
+    requested rows (rle: run lookup; dct: indices; dbp: miniblocks),
+    from the page's parsed form in the column cache where the block has
+    one.
     Every operation reports what it materialized to the owning block's
     decoded_bytes counter, so decodedBytes tracks the selectivity, not
     the row count.
@@ -183,54 +197,53 @@ class EncodedColumn:
             cache.put(key, np.frombuffer(page, np.uint8))
         return page
 
-    def runs(self):
-        """(values, lengths) of an rle page — the run-space read."""
-        from tempo_tpu.encoding.vtpu import lightweight as lw
-
+    def _parsed(self, kinds: tuple, parse):
+        """(this page's parsed form, whether the column cache held it).
+        The form is the tuple of arrays parse(page, dtype, shape) makes,
+        kept in the cache one part a kind under the page's key. Every
+        part present or a re-parse (eviction takes parts one by one);
+        a parse verifies all of the page, so a page that fails raises
+        here and leaves nothing cached."""
         blk, pm = self.blk, self.pm
         cache = blk._colcache
-        kv = (blk.meta.block_id, self.name, pm.offset, "runv")
-        kl = (blk.meta.block_id, self.name, pm.offset, "runl")
+        keys = [(blk.meta.block_id, self.name, pm.offset, kind) for kind in kinds]
         if cache is not None:
-            values, lengths = cache.get(kv), cache.get(kl)
-            if values is not None and lengths is not None:
-                # a warm hit is STILL a re-ship: the host cache elides
-                # IO+decode, not the h2d trip — exactly the signal the
-                # page-heat ledger exists to surface
-                blk._touch_pageheat(self.name, pm,
-                                    values.nbytes + lengths.nbytes)
-                return values, lengths
-        values, lengths = lw.rle_decode_runs(self._page(), pm.dtype, pm.shape)
-        blk._account_decoded(values.nbytes + lengths.nbytes)
-        blk._touch_pageheat(self.name, pm, values.nbytes + lengths.nbytes)
+            parts = tuple(cache.get(key) for key in keys)
+            if all(part is not None for part in parts):
+                return parts, True
+        parts = parse(self._page(), pm.dtype, pm.shape)
         if cache is not None:
-            cache.put(kv, values)
-            cache.put(kl, lengths)
-        return values, lengths
+            for key, part in zip(keys, parts):
+                cache.put(key, part)
+        return parts, False
+
+    def runs(self):
+        """(values, lengths) of an rle page — the run-space read."""
+        return self._runs()[0]
+
+    def _runs(self):
+        from tempo_tpu.encoding.vtpu import lightweight as lw
+
+        (values, lengths), cached = self._parsed(("runv", "runl"), lw.rle_decode_runs)
+        if not cached:
+            self.blk._account_decoded(values.nbytes + lengths.nbytes)
+        # a warm hit is STILL a re-ship: the host cache elides IO+decode,
+        # not the h2d trip — exactly the signal the page-heat ledger
+        # exists to surface
+        self.blk._touch_pageheat(self.name, self.pm, values.nbytes + lengths.nbytes)
+        return (values, lengths), cached
 
     def _dct_indices(self):
         from tempo_tpu.encoding.vtpu import lightweight as lw
 
-        blk, pm = self.blk, self.pm
-        cache = blk._colcache
-        kv = (blk.meta.block_id, self.name, pm.offset, "dctv")
-        ki = (blk.meta.block_id, self.name, pm.offset, "dcti")
-        if cache is not None:
-            values, idx = cache.get(kv), cache.get(ki)
-            if values is not None and idx is not None:
-                w = max(values.shape[0] - 1, 0).bit_length()
-                blk._touch_pageheat(self.name, pm,
-                                    values.nbytes + (self.n * w + 7) // 8)
-                return values, idx
-        values, idx = lw.dct_indices(self._page(), pm.dtype, pm.shape)
+        (values, idx), cached = self._parsed(("dctv", "dcti"), lw.dct_indices)
         # index expansion materializes no values: count the packed
         # stream's size (width bits per row), i.e. the encoded form
         w = max(values.shape[0] - 1, 0).bit_length()
-        blk._account_decoded(values.nbytes + (self.n * w + 7) // 8)
-        blk._touch_pageheat(self.name, pm, values.nbytes + (self.n * w + 7) // 8)
-        if cache is not None:
-            cache.put(kv, values)
-            cache.put(ki, idx)
+        moved = values.nbytes + (self.n * w + 7) // 8
+        if not cached:
+            self.blk._account_decoded(moved)
+        self.blk._touch_pageheat(self.name, self.pm, moved)
         return values, idx
 
     # -- device-resident hot tier --------------------------------------
@@ -413,27 +426,39 @@ class EncodedColumn:
     def gather(self, rows: np.ndarray) -> np.ndarray:
         """Values at `rows` only. rle/dct/dbp pay the rows (and, for
         dbp, the miniblocks) touched; anything else falls back to the
-        full-column read (counted as such)."""
+        full-column read (counted as such).
+
+        A block with a column cache (every query path) reads the page's
+        parsed form from it, checksummed once, at the fill; a block
+        opened with column_cache=None parses and checksums its page at
+        every call."""
         from tempo_tpu.encoding.vtpu import lightweight as lw
 
         rows = np.asarray(rows, np.int64)
         pm = self.pm
         if self.codec == "rle":
-            values, lengths = self.runs()
+            (values, lengths), cached = self._runs()
             out = lw.rle_gather(values, lengths, rows)
-            self.blk._account_decoded(out.nbytes)
-            return out
-        if self.codec == "dct":
-            out = lw.dct_gather(self._page(), pm.dtype, pm.shape, rows)
-            self.blk._account_decoded(out.nbytes)
-            return out
-        if self.codec == "dbp":
-            out, touched_rows = lw.dbp_gather(self._page(), pm.dtype, pm.shape, rows)
-            self.blk._account_decoded(touched_rows * np.dtype(pm.dtype).itemsize
-                                      * (out.shape[1] if out.ndim > 1 else 1))
-            return out
-        col = self.blk.read_columns(self.rg, [self.name])[self.name]
-        return col[rows]
+            decoded = out.nbytes
+        elif self.codec == "dct":
+            # the form _dct_indices() keeps, without its accounting: a
+            # gather counts its output and ships nothing
+            (values, idx), cached = self._parsed(("dctv", "dcti"), lw.dct_indices)
+            if len(rows) and (rows.min() < 0 or rows.max() >= self.n):
+                raise IndexError(f"dct gather rows out of range [0, {self.n})")
+            out = np.ascontiguousarray(values[idx[rows]])
+            decoded = out.nbytes
+        elif self.codec == "dbp":
+            parts, cached = self._parsed(("dbpb", "dbps"), lw.dbp_gather_parts)
+            out, touched_rows = lw.dbp_gather_rows(*parts, pm.dtype, pm.shape, rows)
+            decoded = (touched_rows * np.dtype(pm.dtype).itemsize
+                       * (out.shape[1] if out.ndim > 1 else 1))
+        else:
+            col = self.blk.read_columns(self.rg, [self.name])[self.name]
+            return col[rows]
+        self.blk._account_decoded(decoded)
+        gathers_total.inc(codec=self.codec, source="cached" if cached else "parsed")
+        return out
 
 
 class VtpuBackendBlock:

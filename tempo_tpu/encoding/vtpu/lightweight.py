@@ -246,11 +246,19 @@ def _pack_bits(z: np.ndarray, w: int) -> bytes:
     return np.packbits(bits.ravel(), bitorder="little").tobytes()
 
 
+def _bits_at(padded: np.ndarray, bit_off: np.ndarray, w: int) -> np.ndarray:
+    """The w-bit values that start at the bit offsets `bit_off` (any
+    shape) of a stream padded by 8 bytes: one fancy-index gather of
+    8-byte windows and a shift instead of a per-bit unpack (w <=
+    DBP_MAX_WIDTH <= 32, so bit_in_byte + w <= 39 bits always fit the
+    64-bit window)."""
+    windows = np.lib.stride_tricks.sliding_window_view(padded, 8)[bit_off >> 3]
+    vals = np.ascontiguousarray(windows).view("<u8")[..., 0]
+    return (vals >> (bit_off & 7).astype(np.uint64)) & np.uint64((1 << w) - 1)
+
+
 def _unpack_bits(raw: memoryview, n: int, w: int) -> np.ndarray:
-    """Vectorized extraction: for each value, gather an 8-byte window at
-    its starting byte and shift — one fancy-index gather instead of a
-    per-bit unpack (w <= DBP_MAX_WIDTH <= 32, so bit_in_byte + w <= 39
-    bits always fit the 64-bit window)."""
+    """The first n values of a packed stream."""
     if w == 0 or n == 0:
         return np.zeros(n, np.uint64)
     need = (n * w + 7) // 8
@@ -258,11 +266,7 @@ def _unpack_bits(raw: memoryview, n: int, w: int) -> np.ndarray:
         raise _Truncated(f"packed stream is {len(raw)} bytes, need {need}")
     padded = np.zeros(need + 8, np.uint8)
     padded[:need] = np.frombuffer(raw[:need], np.uint8)
-    bit_off = np.arange(n, dtype=np.int64) * w
-    byte_off = bit_off >> 3
-    windows = np.lib.stride_tricks.sliding_window_view(padded, 8)[byte_off]
-    vals = windows.copy().view("<u8").reshape(n)
-    return (vals >> (bit_off & 7).astype(np.uint64)) & np.uint64((1 << w) - 1)
+    return _bits_at(padded, np.arange(n, dtype=np.int64) * w, w)
 
 
 def _as_2d(arr: np.ndarray) -> np.ndarray:
@@ -397,75 +401,73 @@ def dbp_decode(page: bytes, dtype: str, shape: tuple) -> np.ndarray:
     return np.ascontiguousarray(out.astype(dt, copy=False).reshape(shape))
 
 
-def dbp_gather(page: bytes, dtype: str, shape: tuple, rows: np.ndarray):
-    """Decode ONLY the rows requested: (values (len(rows), *shape[1:]),
-    miniblock rows touched). Each requested row costs its miniblock's
-    delta window cumsum'd from the nearest anchor — a selective query's
-    later column reads scale with the surviving rows, not the page."""
-    from tempo_tpu.encoding.vtpu.codec import CorruptPage
-
+def dbp_gather_parts(page: bytes, dtype: str, shape: tuple):
+    """A dbp page parsed into what a gather reads, two arrays a cache
+    can keep: bases (k, n_anchors + 1) u64, the absolute value at every
+    miniblock's first row (first value, then anchors); and one u8 array
+    of the k widths followed by the packed streams end to end, padded by
+    the 8 bytes the window read wants. All of dbp_parts' checks ran:
+    version, widths, body CRC, lengths."""
     first, anchors, widths, streams, n = dbp_parts(page, dtype, shape)
+    bases = np.empty((len(widths), anchors.shape[1] + 1), np.uint64)
+    bases[:, 0] = first if n else 0
+    bases[:, 1:] = anchors
+    packed = np.frombuffer(bytes(widths) + b"".join(streams) + b"\x00" * 8, np.uint8)
+    return bases, packed
+
+
+def dbp_gather_rows(bases: np.ndarray, packed: np.ndarray,
+                    dtype: str, shape: tuple, rows: np.ndarray):
+    """dbp_gather from the parts dbp_gather_parts made, every touched
+    miniblock in one pass: the (touched miniblocks, A-1) matrix of delta
+    indices, one fancy index of 8-byte windows into the padded stream,
+    one cumsum along the miniblock, the block base added (first value or
+    anchor: both are the absolute value at the block's first row), the
+    requested offsets picked."""
     dt = np.dtype(dtype)
+    n = shape[0] if shape else 0
     rows = np.asarray(rows, np.int64)
-    k = len(widths)
+    k = bases.shape[0]
     if len(rows) == 0 or n == 0:
         return np.empty((0,) + tuple(shape[1:]), dt), 0
     if rows.min() < 0 or rows.max() >= n:
         raise IndexError(f"dbp gather rows out of range [0, {n})")
     A = DBP_MINIBLOCK
-    mbs = np.unique(rows // A)  # touched miniblocks
+    mb = rows // A
+    mbs, pos = np.unique(mb, return_inverse=True)  # touched miniblocks
     mb_lo = mbs * A
-    mb_hi = np.minimum(mb_lo + A, n)
+    mb_rows = np.minimum(mb_lo + A, n) - mb_lo
+    off = rows - mb_lo[pos]
+    # delta d[i] carries row i+1: a miniblock's rows past its first need
+    # deltas [lo, lo + its rows - 1) of the stream; the page's last
+    # miniblock may be short, and what would lie past it is masked to 0
+    j = np.arange(A - 1, dtype=np.int64)
+    live = j[None, :] < (mb_rows - 1)[:, None]
+    didx = np.where(live, mb_lo[:, None] + j[None, :], 0)
     out = np.empty((len(rows), k), np.uint64)
-    try:
-        for c in range(k):
-            w = widths[c]
-            prev = (anchors[c][np.maximum(mbs - 1, 0)] if anchors.shape[1]
-                    else np.zeros(len(mbs), np.uint64))
-            base = np.where(mbs == 0, first[c], prev)
-            # per touched miniblock: unpack its (<= A-1) deltas,
-            # prefix-sum from the block base (first value or anchor:
-            # both are the absolute value at the block's first row),
-            # then pick the requested offsets
-            vals = np.empty((len(mbs), A), np.uint64)
-            for j in range(len(mbs)):
-                lo, hi = int(mb_lo[j]), int(mb_hi[j])
-                # delta d[i] carries row i+1: rows (lo, hi) need deltas
-                # [lo, hi-1) of the stream
-                z = _unpack_window(streams[c], lo, hi - lo - 1, w, n - 1)
-                d = _unzigzag(z)
-                np.cumsum(d, out=d)
-                vals[j, 0] = base[j]
-                vals[j, 1 : hi - lo] = base[j] + d
-            pos = np.searchsorted(mb_lo, rows // A * A)
-            out[:, c] = vals[pos, rows - mb_lo[pos]]
-    except _Truncated as e:
-        raise CorruptPage(f"dbp page truncated: {e}") from e
+    widths = packed[:k].astype(np.int64)
+    stream_bytes = (max(n - 1, 0) * widths + 7) // 8
+    stream_bit = (k + np.cumsum(stream_bytes) - stream_bytes) * 8
+    for c in range(k):
+        w = int(widths[c])  # 0, a constant sub-column: every delta reads 0
+        z = _bits_at(packed, didx * w + stream_bit[c], w)
+        # column 0 is the block's first row, column o the row at offset o
+        d = np.zeros((len(mbs), A), np.uint64)
+        d[:, 1:] = _unzigzag(np.where(live, z, np.uint64(0)))
+        np.cumsum(d, axis=1, out=d)  # wraps mod 2^64 — exact modular prefix
+        out[:, c] = bases[c, mb] + d[pos, off]
     return (
         np.ascontiguousarray(out.astype(dt, copy=False).reshape((len(rows),) + tuple(shape[1:]))),
-        int((mb_hi - mb_lo).sum()),
+        int(mb_rows.sum()),
     )
 
 
-def _unpack_window(raw: memoryview, start: int, count: int, w: int, total: int) -> np.ndarray:
-    """Unpack values [start, start+count) of a packed stream of `total`
-    values (the miniblock window of dbp_gather)."""
-    if w == 0 or count <= 0:
-        return np.zeros(max(count, 0), np.uint64)
-    if start + count > total:
-        raise _Truncated(f"window [{start}, {start + count}) past {total} values")
-    need = (total * w + 7) // 8
-    if len(raw) < need:
-        raise _Truncated(f"packed stream is {len(raw)} bytes, need {need}")
-    lo_byte = (start * w) >> 3
-    hi_byte = min(((start + count) * w + 7) >> 3, len(raw))
-    window = np.zeros(hi_byte - lo_byte + 8, np.uint8)
-    window[: hi_byte - lo_byte] = np.frombuffer(raw[lo_byte:hi_byte], np.uint8)
-    bit_off = np.arange(start, start + count, dtype=np.int64) * w - (lo_byte << 3)
-    byte_off = bit_off >> 3
-    windows = np.lib.stride_tricks.sliding_window_view(window, 8)[byte_off]
-    vals = windows.copy().view("<u8").reshape(count)
-    return (vals >> (bit_off & 7).astype(np.uint64)) & np.uint64((1 << w) - 1)
+def dbp_gather(page: bytes, dtype: str, shape: tuple, rows: np.ndarray):
+    """Decode ONLY the rows requested: (values (len(rows), *shape[1:]),
+    miniblock rows touched). Each requested row costs its miniblock's
+    delta window cumsum'd from the nearest anchor — a selective query's
+    later column reads scale with the surviving rows, not the page."""
+    return dbp_gather_rows(*dbp_gather_parts(page, dtype, shape), dtype, shape, rows)
 
 
 def rle_gather(values: np.ndarray, lengths: np.ndarray, rows: np.ndarray) -> np.ndarray:
@@ -486,8 +488,8 @@ def rle_gather(values: np.ndarray, lengths: np.ndarray, rows: np.ndarray) -> np.
 #        width bits)
 # The parquet RLE_DICTIONARY analog for columns whose runs are too
 # short for rle: predicates resolve against the TINY page dictionary
-# and compare packed indices; gather unpacks only the requested rows'
-# bit windows. Rows may be vectors (parent_span_id limb pairs).
+# and compare packed indices; a gather indexes the expanded indices.
+# Rows may be vectors (parent_span_id limb pairs).
 
 
 def dct_probe(arr: np.ndarray) -> tuple[int, int] | None:
@@ -576,37 +578,6 @@ def dct_decode(page: bytes, dtype: str, shape: tuple) -> np.ndarray:
     if shape[0] == 0:
         return np.empty(shape, np.dtype(dtype))
     return np.ascontiguousarray(values[idx].reshape(shape))
-
-
-def dct_gather(page: bytes, dtype: str, shape: tuple, rows: np.ndarray) -> np.ndarray:
-    """Rows of a dct column by unpacking ONLY the requested rows' bit
-    windows (one gather, no full index expansion)."""
-    from tempo_tpu.encoding.vtpu.codec import CorruptPage
-
-    values, w, stream, n = dct_parts(page, dtype, shape)
-    rows = np.asarray(rows, np.int64)
-    if len(rows) == 0:
-        return np.empty((0,) + tuple(shape[1:]), np.dtype(dtype))
-    if rows.min() < 0 or rows.max() >= n:
-        raise IndexError(f"dct gather rows out of range [0, {n})")
-    if w == 0:
-        return np.broadcast_to(values[0], (len(rows),) + tuple(shape[1:])).copy()
-    try:
-        need = (n * w + 7) // 8
-        if len(stream) < need:
-            raise _Truncated(f"packed stream is {len(stream)} bytes, need {need}")
-        padded = np.zeros(need + 8, np.uint8)
-        padded[:need] = np.frombuffer(stream[:need], np.uint8)
-        bit_off = rows * w
-        byte_off = bit_off >> 3
-        windows = np.lib.stride_tricks.sliding_window_view(padded, 8)[byte_off]
-        idx = (windows.copy().view("<u8").reshape(len(rows))
-               >> (bit_off & 7).astype(np.uint64)) & np.uint64((1 << w) - 1)
-    except _Truncated as e:
-        raise CorruptPage(f"dct page truncated: {e}") from e
-    if (idx >= values.shape[0]).any():
-        raise CorruptPage("dct index out of dictionary range")
-    return np.ascontiguousarray(values[idx.astype(np.int64)])
 
 
 # ---------------------------------------------------------------------------

@@ -752,7 +752,7 @@ class TestOneDispatchABucket:
         assert _ids(warm) == _ids(hot) == _ids(cold) and _ids(cold)
 
     def test_a_tier_answered_duration_leaves_the_hits_the_cached_column(
-            self, device_tier, monkeypatch):
+            self, device_tier):
         """The host path's duration compare reads the decoded column and
         hands it to the hit collection; a mask the tier answered leaves
         none behind. Where the host cache holds the decoded column the
@@ -769,20 +769,20 @@ class TestOneDispatchABucket:
         blk = from_version("vtpu1").open_block(meta, db.backend, db.cfg.block)
         req = SearchRequest(tags={"service.name": _svc(traces)}, min_duration_ns=1,
                             start_seconds=1, end_seconds=1 << 33, limit=0)
-        gathers = []
-        real = lw.dbp_gather
-        monkeypatch.setattr(lw, "dbp_gather",
-                            lambda *a, **k: gathers.append(1) or real(*a, **k))
+        from tempo_tpu.encoding.vtpu.block import gathers_total
+
+        def dbp_gathers():
+            return gathers_total.total(codec="dbp")
+
         colcache._shared_device = None
         cold = blk.search(req)          # decodes duration_nano into the host cache
-        del gathers[:]
+        g0 = dbp_gathers()
         cold = blk.search(req)
-        off = len(gathers)
+        off = dbp_gathers() - g0
         colcache._shared_device = device_tier
         blk.search(req)                 # admits
-        del gathers[:]
-        d0 = _resident_dispatches()
+        g0, d0 = dbp_gathers(), _resident_dispatches()
         hot = blk.search(req)
         assert _resident_dispatches() > d0
-        assert len(gathers) == off
+        assert dbp_gathers() - g0 == off > 0
         assert _ids(hot) == _ids(cold) and _ids(cold)

@@ -146,7 +146,7 @@ class TestEncodedSpaceReads:
                 assert touched <= arr.shape[0] + lw.DBP_MINIBLOCK
 
     @pytest.mark.parametrize("seed", SEEDS)
-    def test_dct_indices_and_gather_match_decode(self, seed):
+    def test_dct_indices_match_decode(self, seed):
         rng = np.random.default_rng(600 + seed)
         for _ in range(8):
             arr = _random_array(rng, "dct")
@@ -155,8 +155,6 @@ class TestEncodedSpaceReads:
             values, idx = lw.dct_indices(page, arr.dtype.str, arr.shape)
             if arr.shape[0]:
                 assert (values[idx].reshape(arr.shape) == full).all()
-                rows = np.sort(rng.choice(arr.shape[0], min(13, arr.shape[0]), replace=False))
-                assert (lw.dct_gather(page, arr.dtype.str, arr.shape, rows) == full[rows]).all()
 
     @pytest.mark.parametrize("seed", SEEDS)
     def test_device_decode_parity(self, seed):

@@ -176,6 +176,9 @@ FAMILY_SERIES_BUDGETS = {
     "tempodb_blocklist_length": 64,
     "tempodb_inspected_bytes_total": 64,
     "tempodb_decoded_bytes_total": 64,
+    # codec (rle | dct | dbp) x source (cached | parsed) enums: blocks,
+    # columns and tenants must NEVER become labels here
+    "tempodb_gathers_total": 6,
     "tempodb_compaction_runs_total": 64,
     "tempodb_compaction_errors_total": 64,
     "tempodb_compaction_blocks_compacted_total": 64,
