@@ -63,7 +63,7 @@ class Facts:
 
     def __init__(self, scrapes: tuple, counts: dict, new_cache_files: int, trace: dict | None):
         self.scrapes = scrapes      # /metrics (before, after) the window
-        self.counts = counts        # {"queries": n} or {"pushes": n} answered in the window
+        self.counts = counts        # {"queries": n, "pushes": n} answered in the window, each its own
         self.new_cache_files = new_cache_files  # compile cache files new over the window
         self.trace = trace          # xplane.py's reduction of the capture, or None
 

@@ -7,22 +7,49 @@ blocks: additive answers (counts, quantile samples) add across blocks,
 re-sent traces included; set answers (hits, a trace's spans) union.
 Rows of all blocks are laid end to end, which gives both for free.
 
-`Bf16Counts`, `CoarseQuantiles` and `LeakyTenants` are the controls: the
-same reference with one stated guarantee broken, put in the program's
-place to show that the comparison fails it (see ../PERF.md, section 2).
+A configuration that says `compaction_in_run` lets the compactor merge
+blocks while the window runs, and a merge combines the copies of a span
+that its inputs hold (`trace_id`, `span_id` equal). What the store then
+rightly counts depends on which blocks have been merged so far: on a
+partition of the loaded blocks into compaction outputs, within a group a
+span counted once, across groups once a group. `Reference.states` holds
+one view of the reference per partition (one, every copy counted, without
+the key), and check.py accepts an additive answer that one of them gives
+whole. Set answers do not depend on the partition.
+
+`Bf16Counts`, `CoarseQuantiles`, `LeakyTenants` and `HalfCombined` are
+the controls: the same reference with one stated guarantee broken, put in
+the program's place to show that the comparison fails it (see ../PERF.md,
+section 2).
 """
 
 from __future__ import annotations
+
+import copy
 
 import numpy as np
 
 from corpus import OP_NAMES, SERVICES, Block
 
 PAGE_ROWS = 32768  # rows of one page / row group of a flushed block
+MAX_PARTITION_BLOCKS = 4  # 15 partitions; 5 blocks would be 52, 8 blocks 4,140
+
+
+def partitions(n: int) -> list:
+    """Every partition of blocks 0..n-1 into groups: 1, 2, 5, 15 for n = 1..4."""
+    if n > MAX_PARTITION_BLOCKS:
+        raise ValueError(
+            f"compaction_in_run with {n} blocks a tenant: the reference enumerates the "
+            f"partitions of at most {MAX_PARTITION_BLOCKS} blocks into compaction outputs")
+    out = [[]]
+    for b in range(n):
+        out = [p[:i] + [p[i] + [b]] + p[i + 1:] for p in out for i in range(len(p))] \
+            + [p + [[b]] for p in out]
+    return out
 
 
 class Reference:
-    def __init__(self, blocks: list):
+    def __init__(self, blocks: list, compaction_in_run: bool = False):
         b = Block.concat(blocks)
         c, s = b.cols, b.spans
         t = b.n_traces
@@ -36,6 +63,24 @@ class Reference:
         self.service = c["service"].reshape(t, s)
         self.name = c["name"].reshape(t, s)
         self.http_status = c["http_status"].reshape(t, s)
+        self._start = c["start_unix_nano"].astype(np.int64).reshape(t, s)
+        self._keep = None  # the rows an additive answer counts; None: every copy
+        self.states, self.merges = [self], 0
+        if compaction_in_run:
+            block_of = np.repeat(np.arange(len(blocks)), [x.num_spans for x in blocks])
+            _, span_key = np.unique(np.concatenate([c["trace_id"], c["span_id"]], axis=1),
+                                    axis=0, return_inverse=True)
+            self.states = [
+                self._counting(_first_copies(span_key.ravel(), block_of, p), len(blocks) - len(p))
+                for p in reversed(partitions(len(blocks)))]  # un-compacted first
+
+    def _counting(self, keep: np.ndarray, merges: int) -> "Reference":
+        """This reference, its additive answers taken over the rows of `keep`.
+        `merges`: the loaded blocks less the groups, which jobs only raise."""
+        state = copy.copy(self)
+        state._keep = keep.reshape(self.dur.shape)
+        state.states, state.merges = [state], merges
+        return state
 
     # -- sets ---------------------------------------------------------------
     def find(self, trace_hex: str):
@@ -48,20 +93,30 @@ class Reference:
     def _hits(self, mask) -> frozenset:
         return frozenset(self.hexes[mask.any(axis=1)])
 
-    def search_tags(self, service: str, min_duration_ns: int) -> frozenset:
+    def _in(self, window):
+        """start=<s>&end=<s> of a search, upstream's meaning and the
+        program's: a span counts where it overlaps the range."""
+        if window is None:
+            return True
+        return ((self._start + self.dur >= window[0] * 10**9)
+                & (self._start <= window[1] * 10**9))
+
+    def search_tags(self, service: str, min_duration_ns: int, window=None) -> frozenset:
         """tags=service.name=<service>&minDuration=<d>: traces of the
         service with a span at least that long."""
         return self._hits((self.service == SERVICES.index(service))
-                          & (self.dur >= min_duration_ns))
+                          & (self.dur >= min_duration_ns) & self._in(window))
 
-    def traceql_filter(self, status: int, duration_ns: int) -> frozenset:
+    def traceql_filter(self, status: int, duration_ns: int, window=None) -> frozenset:
         """{ span.http.status_code = <status> && duration > <d> }: one
         span has to meet both."""
-        return self._hits((self.http_status == status) & (self.dur > duration_ns))
+        return self._hits((self.http_status == status) & (self.dur > duration_ns)
+                          & self._in(window))
 
     # -- counts -------------------------------------------------------------
     def _matching(self, service: str, duration_ns: int):
-        return (self.service == SERVICES.index(service)) & (self.dur > duration_ns)
+        m = (self.service == SERVICES.index(service)) & (self.dur > duration_ns)
+        return m if self._keep is None else m & self._keep
 
     def _count(self, mask) -> int:
         return int(mask.sum())
@@ -87,6 +142,16 @@ class Reference:
         d = self.dur[self._matching(service, duration_ns)]
         return {q: (float(np.quantile(d, q, method="lower")) / 1e9,
                     float(np.quantile(d, q, method="higher")) / 1e9) for q in qs}
+
+
+def _first_copies(span_key: np.ndarray, block_of: np.ndarray, partition: list) -> np.ndarray:
+    """The rows a store in this state counts: within each group of the
+    partition the first copy of a span, whichever block of it holds it."""
+    keep = np.zeros(span_key.shape[0], bool)
+    for group in partition:
+        rows = np.flatnonzero(np.isin(block_of, group))
+        keep[rows[np.unique(span_key[rows], return_index=True)[1]]] = True
+    return keep
 
 
 def _round_bf16(x: np.ndarray) -> np.ndarray:
@@ -131,6 +196,22 @@ class CoarseQuantiles(Reference):
         return out
 
 
+class HalfCombined(Reference):
+    """Control for a configuration that compacts inside a run: a merge
+    that combines the copies of every other re-sent trace and keeps both
+    copies of the rest. No partition of the blocks gives its counts: a
+    merge combines every copy its inputs hold, or it has not run."""
+
+    def __init__(self, blocks: list):
+        super().__init__(blocks)
+        first = np.zeros(len(self.hexes), bool)
+        first[list(self._row_of.values())] = True
+        resent = np.flatnonzero(~first)  # the second copies, in store order
+        keep = np.ones(self.dur.shape, bool)
+        keep[resent[::2]] = False
+        self._keep = keep
+
+
 class LeakyTenants(Reference):
     """Control for the multi-tenant cells: searches see every tenant's
     blocks, which the configuration says a tenant never does."""
@@ -139,8 +220,8 @@ class LeakyTenants(Reference):
         super().__init__(blocks)
         self._all = Reference(blocks + others)
 
-    def search_tags(self, service, min_duration_ns):
-        return self._all.search_tags(service, min_duration_ns)
+    def search_tags(self, *args):
+        return self._all.search_tags(*args)
 
-    def traceql_filter(self, status, duration_ns):
-        return self._all.traceql_filter(status, duration_ns)
+    def traceql_filter(self, *args):
+        return self._all.traceql_filter(*args)

@@ -11,6 +11,8 @@ guarantees) under a traffic mix (traffic/<name>.json). One run starts ONE
 the mix, lets the mix's closed-loop clients run for --seconds, compares
 every answer of the window with the plain reference and prints one JSON
 result line last on stdout. Everything before the window is `setup_s`.
+A mix may hold writers beside readers (`roles` in its file): one window
+then yields the write and the read metrics side by side.
 
 There is no CPU fallback: unless the server reports a TPU the run fails
 with no result line. `--cpu-dry-run` is the one explicit rehearsal (tiny
@@ -50,7 +52,7 @@ import check  # noqa: E402
 import corpus  # noqa: E402
 import layers  # noqa: E402
 import traffic as tr  # noqa: E402
-from reference import Reference  # noqa: E402
+from reference import Reference, partitions  # noqa: E402
 from server import BenchFailure, Child, merge  # noqa: E402
 
 PUSH_TRACES = 512  # traces per OTLP request while the store is loaded
@@ -92,6 +94,12 @@ def store_data(config: dict, tenants: list, dry_traces: int = 0) -> dict:
     return data
 
 
+def merged(scrape: dict, tenant: str) -> bool:
+    """Whether a compaction job of the tenant had ended by this scrape of /metrics."""
+    return any(v > 0 for k, v in scrape.items() if f'tenant="{tenant}"' in k
+               and k.startswith("tempodb_compaction_blocks_compacted_total"))
+
+
 def percentile(values: list, q: float) -> float:
     return float(np.percentile(np.asarray(values, dtype=np.float64), q))
 
@@ -111,6 +119,19 @@ class Run:
         self.base_s = (int(time.time()) // tr.STEP_S) * tr.STEP_S - 600
         self.preload = traffic.get("preload", True)
         self.trace_dir = None
+        self.roles, self.ops = tr.roles_of(traffic), tr.ops_of(traffic)
+        self.client_roles = tr.client_roles(self.roles)
+        if len(self.client_roles) > 8:
+            raise BenchFailure("more than 8 clients: the warm-up's are numbered 90 to 97")
+        # `push_age_s`: the pushes' spans are that old at the run's start, not the store's age
+        self.push_base_s = (int(time.time()) - traffic["push_age_s"]
+                            if "push_age_s" in traffic else self.base_s)
+        if (self.preload and "push_age_s" in traffic
+                and tr.range_of(self.push_base_s)["start"] < tr.range_of(self.base_s)["end"]):
+            raise BenchFailure(f"push_age_s {traffic['push_age_s']}: the pushes' spans would "
+                               "fall into the store's query_range window")
+        if config.get("compaction_in_run"):
+            partitions(config["blocks_per_tenant"])  # more than the reference enumerates: refused
 
     # -- set-up ---------------------------------------------------------------
     def start_server(self) -> None:
@@ -185,24 +206,26 @@ class Run:
             f"{self.data['blocks_per_tenant']} block(s), {dt:.1f}s ({spans / dt:.0f} spans/s)")
 
     def warm_up(self) -> None:
-        """Every operation of the deck, one client: `warmup_per_op` times or with
-        each of its `warmup_ms`; then all clients at once for `warmup_burst_s`."""
+        """Every operation of every role's deck, one client: `warmup_per_op` times or
+        with each of its `warmup_ms`; then all clients of all roles at once for
+        `warmup_burst_s`."""
         t0 = time.perf_counter()
         c = tr.Client(99, self.args.seed, self.src, self.child.port, [])
         n = 0
-        per_op = self.traffic.get("warmup_per_op", 2)
-        for entry in self.traffic["deck"]:
-            for ms in tr.warm_literals(entry, per_op):
-                rec = c.send(tr.build(entry, c.rng, self.src, (self.args.seed, 99, n), ms=ms))
-                n += 1
-                if not rec.ok:
-                    raise BenchFailure(f"warm-up {entry['op']} -> status {rec.status}")
-                if entry["op"] == "push":
-                    self.warm_spans += rec.req.spans
+        for role in self.roles:
+            per_op = role.get("warmup_per_op", self.traffic.get("warmup_per_op", 2))
+            for entry in role["deck"]:
+                for ms in tr.warm_literals(entry, per_op):
+                    rec = c.send(tr.build(entry, c.rng, self.src, (self.args.seed, 99, n), ms=ms))
+                    n += 1
+                    if not rec.ok:
+                        raise BenchFailure(f"warm-up {entry['op']} -> status {rec.status}")
+                    if entry["op"] == "push":
+                        self.warm_spans += rec.req.spans
         # then all clients at once: concurrent queries are folded in batches, whose
         # shapes a single client never meets
-        burst = [tr.Client(90 + i, self.args.seed, self.src, self.child.port, [])
-                 for i in range(self.traffic["clients"])]
+        burst = [tr.Client(90 + i, self.args.seed, self.src, self.child.port, [], role=role)
+                 for i, role in enumerate(self.client_roles)]
         for b in burst:
             b.stop_at = time.perf_counter() + self.traffic.get("warmup_burst_s", 0)
             b.start()
@@ -210,8 +233,16 @@ class Run:
             b.join()
             n += len(b.records)
             self.warm_spans += sum(r.req.spans for r in b.records if r.ok)
-            if not all(r.ok for r in b.records):
-                raise BenchFailure("a request of the warm-up burst failed")
+            for r in b.records:
+                if r.ok:
+                    continue
+                if r.req.op != "find_acked":
+                    raise BenchFailure(f"a request of the warm-up burst failed: {r.req.op} -> "
+                                       f"{r.status}: {r.req.path[:120]}")
+                # an acknowledged trace that is not there is the program's answer, and wrong:
+                # the run goes on and says so in `readback_wrong`
+                say(f"warm-up burst: find_acked -> {r.status}: {r.req.path}")
+                self.warm_readback_wrong += 1
         if not self.preload:
             self.flush()
         say(f"warm-up: {n} requests, {time.perf_counter() - t0:.1f}s")
@@ -256,12 +287,12 @@ class Run:
             done.set()
 
     def window(self) -> dict:
-        n_clients, seconds = self.traffic["clients"], self.args.seconds
+        seconds = self.args.seconds
         traced = bool(self.args.trace)
         done = threading.Event() if traced else None
-        logs = [[] for _ in range(n_clients)]
-        clients = [tr.Client(i, self.args.seed, self.src, self.child.port, logs[i], done)
-                   for i in range(n_clients)]
+        logs = [[] for _ in self.client_roles]
+        clients = [tr.Client(i, self.args.seed, self.src, self.child.port, logs[i], done, role)
+                   for i, role in enumerate(self.client_roles)]
         cap: dict = {}
         before, files_before = self.child.metrics(), self.cache_files()
         t0 = time.perf_counter()
@@ -300,15 +331,16 @@ class Run:
 
     # -- after the window -----------------------------------------------------
     def readback(self, records: list) -> dict:
-        """Write cells: /flush, then read a seeded sample of acknowledged
+        """Cells that push: /flush, then read a seeded sample of acknowledged
         traces from every part of the window back by id, and count the
-        store's spans against the acknowledged ones."""
+        spans the store holds in the pushes' own time range against the
+        acknowledged ones."""
         self.flush()
         acked = [r for r in records if r.req.op == "push" and r.ok]
         rng = np.random.default_rng([self.args.seed, 5])
         picks = sorted({0, len(acked) - 1,
                         *np.linspace(0, len(acked) - 1, READBACK_TRACES // 2).astype(int)})
-        wrong = asked = 0
+        wrong, asked = self.warm_readback_wrong, 0
         c = tr.Client(98, self.args.seed, self.src, self.child.port, [])
         for i in picks if acked else []:
             body, ids = acked[i].req.args
@@ -323,7 +355,7 @@ class Run:
         # by (name): the interpreted plan; the fused rate() would compile a
         # program for this run's own number of blocks (16 s, my chip run)
         doc = self.child.get_json("/api/metrics/query_range",
-                                  {"q": "{} | rate() by (name)", **self.src.range})
+                                  {"q": "{} | rate() by (name)", **tr.range_of(self.push_base_s)})
         got = round(sum(float(v[1]) for s in doc["data"]["result"] for v in s["values"])
                     * tr.STEP_S)
         gap = max(acked_spans - got, got - acked_spans - unsure, 0)
@@ -371,14 +403,14 @@ class Run:
         t0 = time.perf_counter()
         store = corpus.make_store(args.seed, self.data, self.base_s) if self.preload else {}
         pool = tr.make_pool(traffic, args.seed, self.data["spans_per_trace"],
-                            self.base_s) if "pool_bodies" in traffic else []
+                            self.push_base_s) if "pool_bodies" in traffic else []
         multitenant = bool(self.config["server"].get("multitenancy_enabled"))
         self.src = tr.Source(traffic, self.tenants, multitenant, self.base_s,
                              {t: np.array([h for b in bl for h in corpus.trace_hex(b)],
                                           dtype=object) for t, bl in store.items()}, pool)
         say(f"data drawn in {time.perf_counter() - t0:.1f}s"
             + (f"; pool of {len(pool)} bodies x {pool[0].n_spans} spans" if pool else ""))
-        self.warm_spans = 0
+        self.warm_spans = self.warm_readback_wrong = 0
         self.child.wait_ready(300)
         say(f"server ready {time.perf_counter() - T_PROCESS:.1f}s after process start")
         dev = self.check_backend()
@@ -389,16 +421,20 @@ class Run:
             self.dry_send(pool)
         self.warm_up()
         setup_s = time.perf_counter() - T_PROCESS
-        say(f"set-up {setup_s:.1f}s; window of {args.seconds}s, {traffic['clients']} "
-            f"closed-loop clients, trace={args.trace}")
+        say(f"set-up {setup_s:.1f}s; window of {args.seconds}s, "
+            + " + ".join(f"{role['clients']} {role['name']}" for role in self.roles)
+            + f", closed-loop, trace={args.trace}")
 
         w = self.window()
         records = w["records"]
+        for r in [r for r in records if not r.ok][:10]:  # beside the server's log, they say why
+            say(f"not as expected: {r.req.op} -> {r.status} (expected {r.req.expect}) "
+                f"{r.t0 - w['t0']:.2f}s into the window, after {r.t1 - r.t0:.2f}s: {r.req.path[:120]}")
         status = self.child.get_json("/status/device")["backend"]
         peak = max((d.get("peak_bytes_in_use") or 0 for d in status["devices"]), default=0)
         say(f"device memory peak: {peak} bytes")
         numbers = {}
-        if any(e["op"] == "push" for e in traffic["deck"]):
+        if "push" in self.ops:
             numbers.update(self.readback(records))
         numbers["server_exit"] = self.child.shutdown()
         errors = self.child.log_errors()
@@ -408,11 +444,22 @@ class Run:
 
         # the reference, once the window has closed and the server is gone
         t0 = time.perf_counter()
-        refs = {t: Reference(bl) for t, bl in store.items()}
-        numbers.update(check.compare(records, refs))
+        # a tenant whose blocks no job has touched when the last answer is in has one state
+        refs = {t: Reference(bl, bool(self.config.get("compaction_in_run"))
+                             and merged(w["scrapes"][1], t)) for t, bl in store.items()}
+        for k, v in check.compare(records, refs).items():
+            # an acknowledged trace read back wrong, inside the window or after it: one number
+            numbers[k] = numbers.get(k, 0) + v if k == "readback_wrong" else v
         say(f"reference: {numbers['_compared_items']} items compared in "
             f"{time.perf_counter() - t0:.1f}s")
         correct, compared = check.verdict(numbers)
+        if not correct:  # the answers that moved a number, for whoever looks into the run
+            moved = [r for r in records if r.ok and not check.verdict(
+                check.compare([r], refs))[0]]
+            for r in moved[:10]:
+                say(f"  wrong: {r.req.op} {r.req.path[:110]} answered " + (
+                    repr(r.answer)[:200] if isinstance(r.answer, dict)
+                    else f"{len(r.answer or ())} ids"))
 
         trace = self.reduce_trace(w["capture"]) if args.trace else None
         metrics = self.metrics(w, setup_s, trace)
@@ -445,16 +492,19 @@ class Run:
     def metrics(self, w: dict, setup_s: float, trace) -> dict:
         records, t0, seconds = w["records"], w["t0"], w["seconds"]
         in_window = [r for r in records if r.ok and r.t1 <= t0 + seconds]
-        lat_ms = [(r.t1 - r.t0) * 1000.0 for r in records]
-        pushes = [r for r in in_window if r.req.op == "push"]
+        for role in self.roles:
+            if not any(self.client_roles[r.client] is role for r in in_window):
+                raise BenchFailure(f"role {role['name']!r} got no answer inside the window")
         values = {"setup_s": setup_s}
-        if pushes:
+        if "push" in self.ops:
             # back-to-back writers hold the server at capacity: the rate is the metric, the
             # pushes' tail swings with where the flushes fall ("per operation" prints it)
-            values["ingest_spans_per_s"] = sum(r.req.spans for r in pushes) / seconds
-        else:
-            values["queries_per_s"] = len(in_window) / seconds
-            values["query_p95_ms"] = percentile(lat_ms, 95)
+            values["ingest_spans_per_s"] = sum(
+                r.req.spans for r in in_window if r.req.op == "push") / seconds
+        if self.ops - {"push"}:  # beside the writers or alone: over the records that are no push
+            values["queries_per_s"] = sum(r.req.op != "push" for r in in_window) / seconds
+            values["query_p95_ms"] = percentile(
+                [(r.t1 - r.t0) * 1000.0 for r in records if r.req.op != "push"], 95)
         by_op: dict = {}
         for r in records:
             by_op.setdefault(r.req.op, []).append((r.t1 - r.t0) * 1000.0)
@@ -468,6 +518,11 @@ class Run:
                                  "tempo_ingester_blocks_flushed_total"))}
         say("device dispatches and blocks flushed in the window: "
             + json.dumps({k: v for k, v in sorted(grew.items()) if v}))
+        compactor = {k: (after[k] - before.get(k, 0.0), after[k]) for k in sorted(after)
+                     if k.startswith("tempodb_compaction_") and after[k]}
+        say("compactor in the window (tempodb_compaction_*): "
+            + json.dumps({k: d for k, (d, _) in compactor.items() if d})
+            + "; since the server's start: " + json.dumps({k: v for k, (_, v) in compactor.items()}))
         new_files = w["cache_files"][1] - w["cache_files"][0]
         if new_files:
             say("compiled inside the window: " + ", ".join(sorted(new_files)))
@@ -476,9 +531,10 @@ class Run:
                     for m in self.bench["end_to_end"]
                     if self.cell["name"] in m.get("workloads", [self.cell["name"]])}
 
-        done = sum(1 for r in records if r.ok and r.t1 <= w["t_end"])
+        done = [r for r in records if r.ok and r.t1 <= w["t_end"]]
+        pushes = sum(r.req.op == "push" for r in done)
         facts = layers.Facts(
-            scrapes=w["scrapes"], counts={"pushes" if pushes else "queries": done},
+            scrapes=w["scrapes"], counts={"pushes": pushes, "queries": len(done) - pushes},
             new_cache_files=len(new_files), trace=trace)
         out = {}
         for m in self.bench["per_layer"]:

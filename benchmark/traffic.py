@@ -11,6 +11,9 @@ continuous ranges, so nearly every query is new to the result cache.
 The vocabulary of operations is the HTTP API of the `-target=all`
 process: find, search_tags, traceql_filter, rate_by_name, rate_total,
 rate_by_service, quantiles, push.
+
+A file may split its clients into `roles` (writers beside readers), each
+closed-loop over a deck of its own; without the key the file is one role.
 """
 
 from __future__ import annotations
@@ -28,6 +31,7 @@ import wire
 from corpus import SERVICES
 
 STEP_S = 60
+FINDS = ("find", "find_acked")  # a stored trace by ID; one a push of this run carried
 HIT_LIMIT = 1_000_000  # above every hit set: searches return them whole
 QUANTILES = (0.5, 0.99)
 PROTOBUF = "application/x-protobuf"
@@ -70,16 +74,45 @@ def deal(deck: list, size: int) -> list:
     return [e for e, n in zip(deck, counts) for _ in range(n)]
 
 
+def roles_of(traffic: dict) -> list:
+    """The file's roles, `{name, clients, deck, deck_size}` each (and
+    `warmup_per_op`, where a role has its own). A file without `roles` is
+    one role made of its top-level keys."""
+    if "roles" in traffic:
+        return traffic["roles"]
+    return [{"name": "clients", **{k: traffic[k] for k in ("clients", "deck", "deck_size")}}]
+
+
+def client_roles(roles: list) -> list:
+    """The role of client 0, 1, ...: clients are numbered through the roles."""
+    return [role for role in roles for _ in range(role["clients"])]
+
+
+def ops_of(traffic: dict) -> set:
+    """Every operation some role's deck holds."""
+    return {e["op"] for role in roles_of(traffic) for e in role["deck"]}
+
+
+def range_of(base_s: int) -> dict:
+    """The query_range window around spans that start within a second of
+    `base_s`: whole steps, the step before theirs to the step after."""
+    lo = base_s // STEP_S * STEP_S
+    return {"start": lo - STEP_S, "end": lo + 2 * STEP_S, "step": STEP_S}
+
+
 class Source:
     """What the operations draw from: the tenants, their trace ids, the
-    time range of the data and (for pushes) the pool of encoded bodies."""
+    time range of the data and (for pushes) the pool of encoded bodies,
+    and the pushes this run has had acknowledged so far."""
 
     def __init__(self, traffic: dict, tenants: list, multitenant: bool, base_s: int,
                  hexes: dict | None = None, pool: list | None = None):
         self.traffic, self.tenants, self.multitenant = traffic, tenants, multitenant
         self.hexes = hexes or {}
         self.pool = pool or []
-        self.range = {"start": base_s - STEP_S, "end": base_s + 2 * STEP_S, "step": STEP_S}
+        self.range = range_of(base_s)
+        self._acked: list = []  # (tenant, body, ids) of every push answered 200, any client's
+        self._acked_lock = threading.Lock()
         pop = traffic.get("tenant_popularity", {"dist": "uniform"})
         w = np.ones(len(tenants))
         if pop["dist"] == "zipfian":
@@ -88,6 +121,15 @@ class Source:
 
     def headers(self, tenant: str) -> dict:
         return {"X-Scope-OrgID": tenant} if self.multitenant else {}
+
+    def acknowledged(self, req: "Request") -> None:
+        with self._acked_lock:
+            self._acked.append((req.tenant, *req.args))
+
+    def draw_acked(self, rng):
+        """One acknowledged push, or None while there is none."""
+        with self._acked_lock:
+            return self._acked[int(rng.integers(0, len(self._acked)))] if self._acked else None
 
 
 def _us(rng, lo_hi_ms, ms: float | None = None) -> int:
@@ -121,6 +163,11 @@ def build(entry: dict, rng, src: Source, nonce: tuple, ms: float | None = None) 
     warm-up passes its duration literal, `ms` (see warm_literals)."""
     op = entry["op"]
     tenant = src.tenants[int(rng.choice(len(src.tenants), p=src.tenant_p))]
+    if op == "find" and entry.get("of") == "acked" and (acked := src.draw_acked(rng)):
+        tenant, body, ids = acked
+        k = int(rng.integers(0, body.n_traces))
+        h = ids[k].tobytes().hex()
+        return Request("find_acked", tenant, (h, body.span_sets[k]), "GET", f"/api/traces/{h}")
     if op == "find":
         if rng.random() < entry.get("absent_share", 0.0):
             h = rng.integers(0, 256, 16, dtype=np.uint8).tobytes().hex()
@@ -135,16 +182,20 @@ def build(entry: dict, rng, src: Source, nonce: tuple, ms: float | None = None) 
         return Request(op, tenant, (body, ids), "POST", "/v1/traces",
                        body=body.patched(ids), spans=body.n_spans)
     service = SERVICES[int(rng.integers(0, len(SERVICES)))]
+    # `range: "store"`: the search carries the store's time range, as Grafana's Explore does
+    window = (src.range["start"], src.range["end"]) if entry.get("range") == "store" else None
+    in_range = {"start": window[0], "end": window[1]} if window else {}
     if op == "search_tags":
         us = _us(rng, entry["min_duration_ms"], ms)
-        return Request(op, tenant, (service, us * 1000), "GET", _get("/api/search", {
-            "tags": f"service.name={service}", "minDuration": f"{us}us", "limit": HIT_LIMIT}))
+        return Request(op, tenant, (service, us * 1000, window), "GET", _get("/api/search", {
+            "tags": f"service.name={service}", "minDuration": f"{us}us", "limit": HIT_LIMIT,
+            **in_range}))
     us = _us(rng, entry["duration_ms"], ms)
     if op == "traceql_filter":
         status = int(rng.choice(entry["status"]))
         q = f"{{ span.http.status_code = {status} && duration > {us}us }}"
-        return Request(op, tenant, (status, us * 1000), "GET",
-                       _get("/api/search", {"q": q, "limit": HIT_LIMIT}))
+        return Request(op, tenant, (status, us * 1000, window), "GET",
+                       _get("/api/search", {"q": q, "limit": HIT_LIMIT, **in_range}))
     sel = f'{{ resource.service.name = "{service}" && duration > {us}us }}'
     q = {
         "rate_by_name": f"{sel} | rate() by (name)",
@@ -166,7 +217,7 @@ _SERIES_LABEL = {"rate_by_name": "name", "rate_total": None,
 def parse(req: Request, status: int, body: bytes):
     if status != 200:
         return None
-    if req.op == "find":
+    if req.op in FINDS:
         return wire.span_ids(body)
     if req.op == "push":
         return None
@@ -198,9 +249,10 @@ class Client(threading.Thread):
     answered. Runs until `stop_at`, finishing the request in flight."""
 
     def __init__(self, n: int, seed: int, src: Source, port: int, records: list,
-                 hold: threading.Event | None = None):
+                 hold: threading.Event | None = None, role: dict | None = None):
         super().__init__(daemon=True, name=f"client-{n}")
         self.n, self.seed, self.src, self.port = n, seed, src, port
+        self.role = role or roles_of(src.traffic)[0]  # whose deck this client is dealt
         self.records = records  # this client's own list
         self.rng = np.random.default_rng([seed, n, 7])
         self.timeout = float(src.traffic["timeout_s"])
@@ -212,7 +264,7 @@ class Client(threading.Thread):
 
     def next_request(self) -> Request:
         if not self._hand:
-            self._hand = deal(self.src.traffic["deck"], self.src.traffic["deck_size"])
+            self._hand = deal(self.role["deck"], self.role["deck_size"])
             self.rng.shuffle(self._hand)
         self._sent += 1
         return build(self._hand.pop(), self.rng, self.src, (self.seed, self.n, self._sent))
@@ -228,7 +280,7 @@ class Client(threading.Thread):
 
     def send(self, req: Request) -> Record:
         headers = self.src.headers(req.tenant)
-        if req.op == "find":
+        if req.op in FINDS:
             headers["Accept"] = "application/protobuf"
         if req.body is not None:
             headers["Content-Type"] = PROTOBUF
@@ -240,6 +292,8 @@ class Client(threading.Thread):
             self._conn = None
             return Record(req, self.n, t0, t0 + self.timeout, 0)
         t1 = time.perf_counter()
+        if req.op == "push" and status == req.expect:
+            self.src.acknowledged(req)  # before the next request of any client is drawn
         return Record(req, self.n, t0, t1, status, parse(req, status, body))
 
     def run(self) -> None:
@@ -250,7 +304,8 @@ class Client(threading.Thread):
 
 
 def make_pool(traffic: dict, seed: int, spans: int, base_s: int) -> list:
-    """`pool_bodies` pushes of `push_traces` traces, encoded once."""
+    """`pool_bodies` pushes of `push_traces` traces, encoded once; their
+    spans start within a second of `base_s`."""
     from corpus import encode_push, make_block
 
     return [wire.PatchableBody(encode_push(
