@@ -5,11 +5,13 @@ keyed by source hash AND build host: -march=native output only runs on
 the CPU it was built for, so a binary copied in from another machine is
 never loaded) and loaded via ctypes — no pybind11 in this image.
 All entry points hold no Python state and release the GIL for the
-duration of the C call (ctypes does this for us), so page encode/decode
-and k-way merge planning run concurrently with device work.
+duration of the C call (ctypes does this for us), so page encode/decode,
+k-way merge planning and the OTLP receiver's scan of a push run
+concurrently with device work and with each other.
 
 `lib()` returns the loaded binding or None when no compiler/headers are
-available; callers (encoding/vtpu/codec.py) fall back to stdlib paths.
+available; callers fall back: encoding/vtpu/codec.py to stdlib paths,
+receivers/otlp.py to its Python scanner.
 """
 
 from __future__ import annotations
@@ -21,6 +23,7 @@ import os
 import platform
 import subprocess
 import threading
+from typing import NamedTuple
 
 import numpy as np
 
@@ -45,6 +48,62 @@ def _check(r: int) -> int:
     if r < 0:
         raise NativeError(ERR.get(r, f"native error {r}"))
     return r
+
+
+# why ttpu_otlp_scan declines a body (codec.cc's OTLP_* enum): the
+# closed list behind tempo_tpu_ingest_decode_requests_total's `reason`
+OTLP_DECLINED = {
+    1: "malformed", 2: "value_type", 3: "promoted_type", 4: "duplicate_key",
+    5: "empty_key", 6: "key_encoding", 7: "id_length", 8: "out_of_range",
+    9: "too_large",
+}
+
+_SPANS, _ATTRS, _RES, _STRS, _SLOTS = range(5)  # codec.cc's C_* enum
+# the arrays ttpu_otlp_scan fills, in codec.cc's A_* order: (name, dtype,
+# width, which capacity sizes it). Names with "_" stay inside the scan.
+_OTLP_ARRAYS = (
+    ("start_unix_nano", np.uint64, 1, _SPANS),
+    ("duration_nano", np.uint64, 1, _SPANS),
+    ("trace_id", np.uint32, 4, _SPANS),
+    ("span_id", np.uint32, 2, _SPANS),
+    ("parent_span_id", np.uint32, 2, _SPANS),
+    ("name", np.uint32, 1, _SPANS),
+    ("http_method", np.uint32, 1, _SPANS),
+    ("http_url", np.uint32, 1, _SPANS),
+    ("service", np.uint32, 1, _SPANS),
+    ("http_status", np.uint16, 1, _SPANS),
+    ("kind", np.uint8, 1, _SPANS),
+    ("status_code", np.uint8, 1, _SPANS),
+    ("attr_num", np.float64, 1, _ATTRS),
+    ("attr_span", np.uint32, 1, _ATTRS),
+    ("attr_key", np.uint32, 1, _ATTRS),
+    ("attr_str", np.uint32, 1, _ATTRS),
+    ("attr_scope", np.uint8, 1, _ATTRS),
+    ("attr_vtype", np.uint8, 1, _ATTRS),
+    ("_res_num", np.float64, 1, _RES),
+    ("_res_key", np.uint32, 1, _RES),
+    ("_res_str", np.uint32, 1, _RES),
+    ("_res_vtype", np.uint8, 1, _RES),
+    ("str_off", np.uint32, 1, _STRS),
+    ("str_len", np.uint32, 1, _STRS),
+    ("_str_stamp", np.uint32, 1, _STRS),
+    ("str_used", np.uint8, 1, _STRS),
+    ("_slots", np.uint32, 1, _SLOTS),
+)
+
+
+class OtlpColumns(NamedTuple):
+    """What one ttpu_otlp_scan found: a SpanBatch's columns, with every
+    string column (name, service, http_method, http_url, attr_key,
+    attr_str) holding a local code into the table of unique slices
+    `(str_off, str_len)` of the body; entry 0 is the empty string, and
+    `str_used` marks the entries some row refers to."""
+
+    cols: dict
+    attrs: dict
+    str_off: np.ndarray
+    str_len: np.ndarray
+    str_used: np.ndarray
 
 
 def _host_tag() -> str:
@@ -88,7 +147,8 @@ def _build() -> str | None:
             return so
         detail = getattr(err, "stderr", b"") or b""
         log.warning("native codec build failed (%s %s): the default page "
-                    "codec degrades from zstd_shuffle to zlib", err,
+                    "codec degrades from zstd_shuffle to zlib, and OTLP "
+                    "decode to the Python scanner", err,
                     detail.decode("utf-8", "replace")[-500:])
         return None
     os.replace(tmp, so)
@@ -175,6 +235,12 @@ class _Binding:
                                 ctypes.POINTER(ctypes.c_uint32),
                                 ctypes.POINTER(ctypes.c_uint32),
                                 u8p, ctypes.c_size_t]
+        self._oscan = lib.ttpu_otlp_scan
+        self._oscan.restype = ctypes.c_longlong
+        self._oscan.argtypes = [u8p, ctypes.c_size_t,
+                                ctypes.POINTER(ctypes.c_void_p),
+                                ctypes.POINTER(ctypes.c_uint64),
+                                ctypes.POINTER(ctypes.c_uint64)]
         self._u8p = u8p
 
     # -- helpers -----------------------------------------------------------
@@ -228,6 +294,51 @@ class _Binding:
         if r != n_elems:
             raise NativeError(f"decoded {r} elems, expected {n_elems}")
         return out
+
+    def _otlp_work(self, want: list) -> tuple:
+        """This thread's arrays for ttpu_otlp_scan, each holding at least
+        the rows `want` asks for (C_* order, slots apart): (capacities,
+        views in A_* order, the pointer array). They only grow, so a
+        thread carves them anew only for a body larger than any before."""
+        work = getattr(self._tls, "otlp", None)
+        if work is not None and all(h >= w for h, w in zip(work[0], want)):
+            return work
+        caps = [max(w, h) for w, h in zip(want, work[0])] if work else list(want)
+        caps.append(1 << (2 * caps[_STRS] - 1).bit_length())  # hash slots
+        views = [np.empty(caps[per] * width, dt)
+                 for _, dt, width, per in _OTLP_ARRAYS]
+        ptrs = (ctypes.c_void_p * len(views))(*[v.ctypes.data for v in views])
+        work = self._tls.otlp = (caps, views, ptrs)
+        return work
+
+    def otlp_scan(self, body, caps: list | None = None) -> OtlpColumns | str:
+        """One pass over an OTLP ExportTraceServiceRequest body with the
+        interpreter lock released: the columns it fills, or the reason it
+        declines (a value of OTLP_DECLINED) where the Python scanner of
+        receivers/otlp.py could answer otherwise. `caps` overrides the
+        first guess of (spans, attr rows, resource attrs, unique strings);
+        arrays that prove too short are carved again at the counts the
+        scan returns and the body is scanned once more."""
+        p, n = self._buf(body)
+        want = caps or [n // 32 + 16, n // 8 + 16, 64, n // 32 + 64]
+        counts = (ctypes.c_uint64 * 4)()
+        for _ in range(2):
+            have, views, ptrs = self._otlp_work(want)
+            r = self._oscan(p, n, ptrs, (ctypes.c_uint64 * 5)(*have), counts)
+            if r != -1:
+                break
+            want = list(counts)
+        if r > 0:
+            return OTLP_DECLINED[r]
+        _check(r)
+        out = {}
+        for (name, _, width, per), v in zip(_OTLP_ARRAYS, views):
+            if not name.startswith("_"):
+                a = v[:counts[per] * width].copy()
+                out[name] = a.reshape(-1, width) if width > 1 else a
+        strs = [out.pop(k) for k in ("str_off", "str_len", "str_used")]
+        attrs = {k: out.pop(k) for k in list(out) if k.startswith("attr_")}
+        return OtlpColumns(out, attrs, *strs)
 
     PAGE_CODECS = {"none": 0, "zlib": 1, "zstd": 2, "zstd_shuffle": 3}
 

@@ -426,3 +426,502 @@ long long ttpu_kway_merge_u192(const uint64_t* const* keys_hi,
 }
 
 }  // extern "C"
+
+// ---------------------------------------------------------------------------
+// OTLP/HTTP protobuf scan
+// ---------------------------------------------------------------------------
+//
+// One pass over an ExportTraceServiceRequest body that fills the columns
+// of a SpanBatch (model/columnar.py) the way receivers/otlp.py's Python
+// scanner and model/batchbuild.BatchBuilder fill them. That scanner is the
+// definition; this one never guesses: wherever the two could differ it
+// declines with a reason and the caller runs the Python scanner instead.
+// It reads nothing outside [body, body + n) and writes nothing beyond the
+// capacities it is given.
+
+namespace {
+
+enum {  // decline reasons; native/__init__.py's OTLP_DECLINED names them
+  OTLP_MALFORMED = 1,   // truncated or overlong length or varint, a wire
+                        // type protowire does not know, or a field the
+                        // scan reads under another wire type than its own
+  OTLP_VALUE_TYPE = 2,  // AnyValue array, kvlist, bytes or absent
+  OTLP_PROMOTED_TYPE = 3,  // http.status_code / http.method / http.url /
+                           // service.name not of its column's type
+  OTLP_DUPLICATE_KEY = 4,  // within one span's or one resource's attrs
+  OTLP_EMPTY_KEY = 5,
+  OTLP_KEY_ENCODING = 6,  // a key that is not UTF-8: "replace" could
+                          // fold two such keys into one
+  OTLP_ID_LENGTH = 7,     // an id longer than its column
+  OTLP_OUT_OF_RANGE = 8,  // kind or status code outside uint8
+  OTLP_TOO_LARGE = 9,     // offsets would not fit uint32
+};
+
+enum {  // the caller's arrays; native/__init__.py's _OTLP_ARRAYS mirrors
+  A_START, A_DUR, A_TID, A_SID, A_PID, A_NAME, A_HMETH, A_HURL, A_SVC,
+  A_HSTAT, A_KIND, A_STATUS,                              // per span
+  A_ANUM, A_ASPAN, A_AKEY, A_ASTR, A_ASCOPE, A_AVT,       // per attr row
+  A_RNUM, A_RKEY, A_RSTR, A_RVT,        // per attr of the open resource
+  A_SOFF, A_SLEN, A_SSTAMP, A_SUSED,    // per unique string
+  A_SLOTS,                              // hash slots, a power of two
+  A_COUNT
+};
+
+enum { C_SPANS, C_ATTRS, C_RES, C_STRS, C_SLOTS, C_COUNT };
+
+const uint8_t VT_STR = 0, VT_INT = 1, VT_FLOAT = 2, VT_BOOL = 3;
+const uint8_t SCOPE_SPAN = 0, SCOPE_RESOURCE = 1;
+
+struct Field {
+  uint64_t num;
+  int wt;
+  uint64_t v;       // varint, fixed64, fixed32
+  size_t off, len;  // length-delimited
+};
+
+struct AnyVal {
+  uint8_t vt;
+  double num;
+  int64_t i;
+  size_t off, len;  // VT_STR
+};
+
+struct Scan {
+  const uint8_t* b;
+  uint64_t cap[C_COUNT];
+  uint64_t *start, *dur;
+  uint32_t *tid, *sid, *pid, *name, *hmeth, *hurl, *svc;
+  uint16_t* hstat;
+  uint8_t *kind, *status;
+  double* anum;
+  uint32_t *aspan, *akey, *astr;
+  uint8_t *ascope, *avt;
+  double* rnum;
+  uint32_t *rkey, *rstr;
+  uint8_t* rvt;
+  uint32_t *soff, *slen, *sstamp;
+  uint8_t* sused;
+  uint32_t* slots;
+  uint64_t n_spans = 0, n_attrs = 0, n_strs = 1, res_max = 0;
+  uint64_t res_n = 0;    // attrs of the open resource, service.name apart
+  uint32_t cur_svc = 0;  // its service.name
+  uint32_t stamp = 0;    // one per span and per resource: a key whose
+                         // entry already holds it repeats in that scope
+  bool strs_full = false;
+
+  // protowire.read_varint, but a value past 64 bits is refused
+  bool varint(size_t end, size_t& pos, uint64_t& out) const {
+    uint64_t r = 0;
+    for (int shift = 0; shift < 70; shift += 7) {
+      if (pos >= end) return false;
+      uint8_t c = b[pos++];
+      if (shift == 63 && (c & 0x7E)) return false;
+      r |= (uint64_t)(c & 0x7F) << shift;
+      if (!(c & 0x80)) {
+        out = r;
+        return true;
+      }
+    }
+    return false;
+  }
+
+  // one step of protowire.iter_fields over [pos, end)
+  bool next(size_t end, size_t& pos, Field& f) const {
+    uint64_t tag;
+    f.v = 0, f.off = f.len = 0;
+    if (!varint(end, pos, tag)) return false;
+    f.num = tag >> 3;
+    f.wt = (int)(tag & 7);
+    switch (f.wt) {
+      case 0:
+        return varint(end, pos, f.v);
+      case 1:
+        if (end - pos < 8) return false;
+        memcpy(&f.v, b + pos, 8);
+        pos += 8;
+        return true;
+      case 2: {
+        uint64_t ln;
+        if (!varint(end, pos, ln) || ln > end - pos) return false;
+        f.off = pos;
+        f.len = (size_t)ln;
+        pos += f.len;
+        return true;
+      }
+      case 5: {
+        if (end - pos < 4) return false;
+        uint32_t v32;
+        memcpy(&v32, b + pos, 4);
+        f.v = v32;
+        pos += 4;
+        return true;
+      }
+      default:
+        return false;
+    }
+  }
+
+  // local code of the slice in the table of unique strings; 0 is ""
+  uint32_t intern(size_t off, size_t len) {
+    if (len == 0 || strs_full) return 0;
+    uint64_t mask = cap[C_SLOTS] - 1;
+    uint64_t h = ttpu_hash64(b + off, len, 0) & mask;
+    for (;; h = (h + 1) & mask) {
+      uint32_t e = slots[h];
+      if (e == 0) break;
+      e -= 1;
+      if (slen[e] == len && memcmp(b + soff[e], b + off, len) == 0) return e;
+    }
+    if (n_strs >= cap[C_STRS]) {
+      strs_full = true;  // the caller asks again with room for any body
+      return 0;
+    }
+    uint32_t e = (uint32_t)n_strs++;
+    soff[e] = (uint32_t)off;
+    slen[e] = (uint32_t)len;
+    sstamp[e] = 0;
+    sused[e] = 0;
+    slots[h] = e + 1;
+    return e;
+  }
+
+  // a key's code, or a reason: empty, not UTF-8, or seen in this scope
+  int key(size_t off, size_t len, uint32_t& code) {
+    if (len == 0) return OTLP_EMPTY_KEY;
+    if (!utf8(b + off, len)) return OTLP_KEY_ENCODING;
+    code = intern(off, len);
+    if (strs_full) return 0;
+    if (sstamp[code] == stamp) return OTLP_DUPLICATE_KEY;
+    sstamp[code] = stamp;
+    return 0;
+  }
+
+  // what bytes.decode("utf-8") accepts without replacing anything
+  static bool utf8(const uint8_t* p, size_t n) {
+    size_t i = 0;
+    while (i < n) {
+      uint8_t c = p[i];
+      if (c < 0x80) {
+        i++;
+        continue;
+      }
+      size_t need;
+      uint32_t cp, lo;
+      if ((c & 0xE0) == 0xC0) need = 1, cp = c & 0x1F, lo = 0x80;
+      else if ((c & 0xF0) == 0xE0) need = 2, cp = c & 0x0F, lo = 0x800;
+      else if ((c & 0xF8) == 0xF0) need = 3, cp = c & 0x07, lo = 0x10000;
+      else return false;
+      if (n - i <= need) return false;
+      for (size_t k = 1; k <= need; k++) {
+        if ((p[i + k] & 0xC0) != 0x80) return false;
+        cp = (cp << 6) | (p[i + k] & 0x3F);
+      }
+      if (cp < lo || cp > 0x10FFFF || (cp >= 0xD800 && cp <= 0xDFFF))
+        return false;
+      i += need + 1;
+    }
+    return true;
+  }
+
+  bool is(size_t off, size_t len, const char* lit) const {
+    return len == strlen(lit) && memcmp(b + off, lit, len) == 0;
+  }
+
+  // otlp._decode_anyvalue: the first of fields 1..7 decides
+  int any_value(size_t off, size_t len, AnyVal& out) const {
+    size_t pos = off, end = off + len;
+    Field f;
+    while (pos < end) {
+      if (!next(end, pos, f)) return OTLP_MALFORMED;
+      switch (f.num) {
+        case 1:
+          if (f.wt != 2) return OTLP_MALFORMED;
+          out.vt = VT_STR, out.num = 0.0, out.off = f.off, out.len = f.len;
+          return 0;
+        case 2:
+          if (f.wt != 0) return OTLP_MALFORMED;
+          out.vt = VT_BOOL, out.num = f.v ? 1.0 : 0.0;
+          return 0;
+        case 3:
+          if (f.wt != 0) return OTLP_MALFORMED;
+          out.vt = VT_INT, out.i = (int64_t)f.v, out.num = (double)out.i;
+          return 0;
+        case 4:
+          if (f.wt != 1) return OTLP_MALFORMED;
+          out.vt = VT_FLOAT;
+          memcpy(&out.num, &f.v, 8);
+          return 0;
+        case 5:
+        case 6:
+        case 7:
+          return OTLP_VALUE_TYPE;
+      }
+    }
+    return OTLP_VALUE_TYPE;  // no value at all: Python's None
+  }
+
+  // otlp._decode_keyvalue: the last key and the last value count
+  int key_value(size_t off, size_t len, size_t& koff, size_t& klen,
+                AnyVal& val) const {
+    size_t pos = off, end = off + len;
+    Field f;
+    bool have = false;
+    koff = klen = 0;
+    while (pos < end) {
+      if (!next(end, pos, f)) return OTLP_MALFORMED;
+      if (f.num != 1 && f.num != 2) continue;
+      if (f.wt != 2) return OTLP_MALFORMED;
+      if (f.num == 1) {
+        koff = f.off, klen = f.len;
+      } else {
+        int r = any_value(f.off, f.len, val);
+        if (r) return r;
+        have = true;
+      }
+    }
+    return have ? 0 : OTLP_VALUE_TYPE;
+  }
+
+  void attr_row(uint32_t span, uint8_t scope, uint32_t k, uint8_t vt,
+                uint32_t s, double num) {
+    if (n_attrs < cap[C_ATTRS]) {
+      uint64_t j = n_attrs;
+      aspan[j] = span, ascope[j] = scope, akey[j] = k, avt[j] = vt;
+      astr[j] = s, anum[j] = num;
+      sused[k] = 1, sused[s] = 1;
+    }
+    n_attrs++;
+  }
+
+  struct SpanRow {
+    uint8_t tid[16], sid[8], pid[8];
+    size_t name_off, name_len;
+    uint64_t kind, status, start, end;
+    uint16_t hstat;
+    uint32_t hmeth, hurl;
+  };
+
+  // one of a span's attributes, promoted as BatchBuilder.add_span does
+  int span_attr(size_t off, size_t len, uint32_t row, SpanRow& sp) {
+    size_t koff, klen;
+    AnyVal v = {};
+    int r = key_value(off, len, koff, klen, v);
+    if (r) return r;
+    uint32_t k = 0;
+    if ((r = key(koff, klen, k))) return r;
+    if (is(koff, klen, "http.status_code")) {
+      if (v.vt != VT_INT || v.i < 0 || v.i > 65535) return OTLP_PROMOTED_TYPE;
+      sp.hstat = (uint16_t)v.i;
+    } else if (is(koff, klen, "http.method")) {
+      if (v.vt != VT_STR) return OTLP_PROMOTED_TYPE;
+      sp.hmeth = intern(v.off, v.len);
+    } else if (is(koff, klen, "http.url")) {
+      if (v.vt != VT_STR) return OTLP_PROMOTED_TYPE;
+      sp.hurl = intern(v.off, v.len);
+    } else {
+      attr_row(row, SCOPE_SPAN, k, v.vt,
+               v.vt == VT_STR ? intern(v.off, v.len) : 0, v.num);
+    }
+    return 0;
+  }
+
+  int id(const Field& f, uint8_t* dst, size_t w) const {
+    if (f.wt != 2) return OTLP_MALFORMED;
+    if (f.len > w) return OTLP_ID_LENGTH;
+    memset(dst, 0, w);  // right-justified, as bytes.rjust
+    memcpy(dst + (w - f.len), b + f.off, f.len);
+    return 0;
+  }
+
+  static void be32(const uint8_t* src, uint32_t* dst, size_t words) {
+    for (size_t k = 0; k < words; k++, src += 4)
+      dst[k] = (uint32_t)src[0] << 24 | (uint32_t)src[1] << 16 |
+               (uint32_t)src[2] << 8 | (uint32_t)src[3];
+  }
+
+  // otlp._decode_span_into + BatchBuilder.add_span
+  int span(size_t off, size_t len) {
+    size_t pos = off, end = off + len;
+    Field f;
+    SpanRow sp;
+    memset(&sp, 0, sizeof sp);
+    uint32_t row = (uint32_t)n_spans;
+    stamp++;
+    int r;
+    while (pos < end) {
+      if (!next(end, pos, f)) return OTLP_MALFORMED;
+      switch (f.num) {
+        case 1:
+          if ((r = id(f, sp.tid, 16))) return r;
+          break;
+        case 2:
+          if ((r = id(f, sp.sid, 8))) return r;
+          break;
+        case 4:
+          if ((r = id(f, sp.pid, 8))) return r;
+          break;
+        case 5:
+          if (f.wt != 2) return OTLP_MALFORMED;
+          sp.name_off = f.off, sp.name_len = f.len;
+          break;
+        case 6:
+        case 7:
+        case 8:
+          if (f.wt == 2) return OTLP_MALFORMED;
+          (f.num == 6 ? sp.kind : f.num == 7 ? sp.start : sp.end) = f.v;
+          break;
+        case 9:
+          if (f.wt != 2) return OTLP_MALFORMED;
+          if ((r = span_attr(f.off, f.len, row, sp))) return r;
+          break;
+        case 15: {
+          if (f.wt != 2) return OTLP_MALFORMED;
+          size_t p2 = f.off, e2 = f.off + f.len;
+          Field g;
+          while (p2 < e2) {
+            if (!next(e2, p2, g)) return OTLP_MALFORMED;
+            if (g.num != 3) continue;
+            if (g.wt == 2) return OTLP_MALFORMED;
+            sp.status = g.v;
+          }
+          break;
+        }
+      }
+    }
+    if (sp.kind > 255 || sp.status > 255) return OTLP_OUT_OF_RANGE;
+    uint32_t nm = intern(sp.name_off, sp.name_len);
+    if (n_spans < cap[C_SPANS]) {
+      be32(sp.tid, tid + 4 * (size_t)row, 4);
+      be32(sp.sid, sid + 2 * (size_t)row, 2);
+      be32(sp.pid, pid + 2 * (size_t)row, 2);
+      start[row] = sp.start;
+      dur[row] = sp.end >= sp.start ? sp.end - sp.start : 0;
+      kind[row] = (uint8_t)sp.kind, status[row] = (uint8_t)sp.status;
+      hstat[row] = sp.hstat;
+      name[row] = nm, hmeth[row] = sp.hmeth, hurl[row] = sp.hurl;
+      svc[row] = cur_svc;
+      sused[nm] = 1, sused[sp.hmeth] = 1, sused[sp.hurl] = 1;
+    }
+    n_spans++;
+    uint64_t held = res_n < cap[C_RES] ? res_n : cap[C_RES];
+    for (uint64_t j = 0; j < held; j++)
+      attr_row(row, SCOPE_RESOURCE, rkey[j], rvt[j], rstr[j], rnum[j]);
+    n_attrs += res_n - held;
+    return 0;
+  }
+
+  // one of the open resource's attributes (BatchBuilder.begin_resource)
+  int resource_attr(size_t off, size_t len) {
+    size_t koff, klen;
+    AnyVal v = {};
+    int r = key_value(off, len, koff, klen, v);
+    if (r) return r;
+    uint32_t k = 0;
+    if ((r = key(koff, klen, k))) return r;
+    if (is(koff, klen, "service.name")) {
+      if (v.vt != VT_STR) return OTLP_PROMOTED_TYPE;
+      cur_svc = intern(v.off, v.len);
+      return 0;
+    }
+    uint32_t s = v.vt == VT_STR ? intern(v.off, v.len) : 0;
+    if (res_n < cap[C_RES]) {
+      rkey[res_n] = k, rvt[res_n] = v.vt, rstr[res_n] = s, rnum[res_n] = v.num;
+    }
+    if (++res_n > res_max) res_max = res_n;
+    return 0;
+  }
+
+  // one ResourceSpans: every Resource in it first (their attributes are
+  // one dict to the Python scanner, wherever they stand), then the spans
+  int resource_spans(size_t off, size_t len) {
+    size_t end = off + len;
+    Field f, g;
+    int r;
+    res_n = 0, cur_svc = 0;
+    stamp++;
+    for (size_t pos = off; pos < end;) {
+      if (!next(end, pos, f)) return OTLP_MALFORMED;
+      if (f.num != 1 && f.num != 2) continue;
+      if (f.wt != 2) return OTLP_MALFORMED;
+      if (f.num == 2) continue;
+      for (size_t p2 = f.off, e2 = f.off + f.len; p2 < e2;) {
+        if (!next(e2, p2, g)) return OTLP_MALFORMED;
+        if (g.num != 1) continue;
+        if (g.wt != 2) return OTLP_MALFORMED;
+        if ((r = resource_attr(g.off, g.len))) return r;
+      }
+    }
+    sused[cur_svc] = 1;  // the builder adds every group's service
+    for (size_t pos = off; pos < end;) {
+      next(end, pos, f);  // the first pass has been over these fields
+      if (f.num != 2) continue;
+      for (size_t p2 = f.off, e2 = f.off + f.len; p2 < e2;) {
+        if (!next(e2, p2, g)) return OTLP_MALFORMED;
+        if (g.num != 2) continue;
+        if (g.wt != 2) return OTLP_MALFORMED;
+        if ((r = span(g.off, g.len))) return r;
+      }
+    }
+    return 0;
+  }
+
+  int request(size_t n) {
+    Field f;
+    int r;
+    for (size_t pos = 0; pos < n;) {
+      if (!next(n, pos, f)) return OTLP_MALFORMED;
+      if (f.num != 1) continue;
+      if (f.wt != 2) return OTLP_MALFORMED;
+      if ((r = resource_spans(f.off, f.len))) return r;
+    }
+    return 0;
+  }
+};
+
+}  // namespace
+
+extern "C" {
+
+// Scans `body` into the arrays `arr` points at (A_* order), each holding
+// the rows `cap` says (C_* order; cap[C_SLOTS] a power of two, at least
+// twice cap[C_STRS]). Returns 0 with the counts in `counts` (C_* order,
+// slots apart); TTPU_ERR_CAP where an array was too short, with the
+// capacities that will do in `counts`; or the reason (> 0) it declines.
+long long ttpu_otlp_scan(const uint8_t* body, size_t n, void* const* arr,
+                         const uint64_t* cap, uint64_t* counts) {
+  if (n >= (1ULL << 31)) return OTLP_TOO_LARGE;
+  uint64_t slots = cap[C_SLOTS];
+  if (cap[C_STRS] < 1 || slots < 2 * cap[C_STRS] || (slots & (slots - 1)))
+    return TTPU_ERR_ARG;
+  Scan s;
+  s.b = body;
+  memcpy(s.cap, cap, sizeof s.cap);
+  s.start = (uint64_t*)arr[A_START], s.dur = (uint64_t*)arr[A_DUR];
+  s.tid = (uint32_t*)arr[A_TID], s.sid = (uint32_t*)arr[A_SID];
+  s.pid = (uint32_t*)arr[A_PID], s.name = (uint32_t*)arr[A_NAME];
+  s.hmeth = (uint32_t*)arr[A_HMETH], s.hurl = (uint32_t*)arr[A_HURL];
+  s.svc = (uint32_t*)arr[A_SVC], s.hstat = (uint16_t*)arr[A_HSTAT];
+  s.kind = (uint8_t*)arr[A_KIND], s.status = (uint8_t*)arr[A_STATUS];
+  s.anum = (double*)arr[A_ANUM], s.aspan = (uint32_t*)arr[A_ASPAN];
+  s.akey = (uint32_t*)arr[A_AKEY], s.astr = (uint32_t*)arr[A_ASTR];
+  s.ascope = (uint8_t*)arr[A_ASCOPE], s.avt = (uint8_t*)arr[A_AVT];
+  s.rnum = (double*)arr[A_RNUM], s.rkey = (uint32_t*)arr[A_RKEY];
+  s.rstr = (uint32_t*)arr[A_RSTR], s.rvt = (uint8_t*)arr[A_RVT];
+  s.soff = (uint32_t*)arr[A_SOFF], s.slen = (uint32_t*)arr[A_SLEN];
+  s.sstamp = (uint32_t*)arr[A_SSTAMP], s.sused = (uint8_t*)arr[A_SUSED];
+  s.slots = (uint32_t*)arr[A_SLOTS];
+  memset(s.slots, 0, slots * sizeof(uint32_t));
+  s.soff[0] = s.slen[0] = s.sstamp[0] = 0;  // entry 0: the empty string
+  s.sused[0] = 0;
+  int r = s.request(n);
+  if (r) return r;
+  counts[C_SPANS] = s.n_spans, counts[C_ATTRS] = s.n_attrs;
+  counts[C_RES] = s.res_max, counts[C_STRS] = s.n_strs;
+  if (s.strs_full) counts[C_STRS] = n / 3 + 2;  // 3 bytes a unique string
+  if (s.strs_full || s.n_spans > cap[C_SPANS] || s.n_attrs > cap[C_ATTRS] ||
+      s.res_max > cap[C_RES])
+    return TTPU_ERR_CAP;
+  return 0;
+}
+
+}  // extern "C"

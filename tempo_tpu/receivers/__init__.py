@@ -17,6 +17,7 @@ import gzip
 import json
 import zlib
 
+from tempo_tpu import native
 from tempo_tpu.model.trace import Trace
 from tempo_tpu.receivers import jaeger, otlp, zipkin
 from tempo_tpu.util import metrics
@@ -32,6 +33,17 @@ spans_decoded_total = metrics.counter(
     "Spans decoded at the receiver boundary, by decode path "
     "(columnar = straight to SpanBatch, object = via Trace objects)",
 )
+
+
+decode_requests_total = metrics.counter(
+    "tempo_tpu_ingest_decode_requests_total",
+    "OTLP/HTTP protobuf bodies by the scanner that answered: native (one "
+    "pass of native/codec.cc outside the interpreter lock), or python with "
+    "the reason the native scan declined the body",
+)
+decode_requests_total.inc(0, scanner="native", reason="")
+for _reason in ("no_library", *native.OTLP_DECLINED.values()):
+    decode_requests_total.inc(0, scanner="python", reason=_reason)
 
 
 class UnsupportedPayload(ValueError):
@@ -59,7 +71,8 @@ def decode_http_columnar(path: str, content_type: str, body: bytes):
     if ct == "application/json":
         batch = otlp.decode_traces_json_columnar(json.loads(body or b"{}"))
     else:
-        batch = otlp.decode_traces_request_columnar(body)
+        batch = otlp.decode_traces_request_columnar(
+            body, scanned=decode_requests_total.inc)
     if batch.num_spans:
         spans_decoded_total.inc(batch.num_spans, path="columnar")
     return batch

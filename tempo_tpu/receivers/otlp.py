@@ -25,6 +25,16 @@ from __future__ import annotations
 import base64
 import binascii
 
+import numpy as np
+
+from tempo_tpu import native
+from tempo_tpu.model.batchbuild import BatchBuilder
+from tempo_tpu.model.columnar import (
+    ATTR_COLUMNS,
+    SPAN_COLUMNS,
+    Dictionary,
+    SpanBatch,
+)
 from tempo_tpu.model.trace import Span, Trace
 from tempo_tpu.receivers import protowire as w
 
@@ -179,13 +189,57 @@ def _decode_span_into(b, buf: bytes) -> None:
                status, _decode_attrs(attr_bufs) if attr_bufs else None)
 
 
-def decode_traces_request_columnar(buf: bytes, dictionary=None):
+def decode_traces_request_columnar(buf: bytes, dictionary=None, scanned=None):
     """Decode ExportTraceServiceRequest/TracesData bytes directly into a
     SpanBatch: one pass over the wire, no Span/Trace objects and no
     per-trace regrouping (trace identity IS the trace_id column; the
-    ingester regroups by ID columns anyway). Spans land in wire order."""
-    from tempo_tpu.model.batchbuild import BatchBuilder
+    ingester regroups by ID columns anyway). Spans land in wire order.
 
+    The pass is native/codec.cc's ttpu_otlp_scan, outside the interpreter
+    lock, wherever its answer is certain to be this module's: bodies whose
+    attribute values are strings, bools, ints and doubles, with ids no
+    longer than their columns. It declines, and the Python scanner below
+    answers as it always has (the batch, or WireError), on anything
+    malformed or truncated, an unknown wire type or a known field under
+    another's, an array, kvlist, bytes or absent value, a promoted
+    attribute (http.status_code, http.method, http.url, service.name)
+    whose value is not of its column's type, a key that repeats within one
+    span or one resource, is empty or is not UTF-8, an over-long id, a
+    kind or status code beyond uint8, and where the library did not build.
+    `scanned(scanner=, reason=)`, where given, hears which of the two
+    answers, before the Python one can raise."""
+    lib = native.lib()
+    scan = lib.otlp_scan(buf) if lib is not None else "no_library"
+    declined = isinstance(scan, str)
+    if scanned is not None:
+        scanned(scanner="python" if declined else "native",
+                reason=scan if declined else "")
+    if declined:
+        return _scan_columnar(buf, dictionary)
+    return _batch_from_scan(buf, scan, dictionary)
+
+
+def _batch_from_scan(buf: bytes, scan, dictionary=None):
+    """The SpanBatch of a native scan: only the unique strings the rows
+    refer to are decoded and given to the dictionary, and each code column
+    is mapped from the scan's local codes with one gather."""
+    d = dictionary or Dictionary()
+    codes = np.zeros(scan.str_off.shape[0], np.uint32)
+    offs, lens = scan.str_off.tolist(), scan.str_len.tolist()
+    for i in np.flatnonzero(scan.str_used).tolist():
+        o = offs[i]
+        codes[i] = d.add(buf[o:o + lens[i]].decode("utf-8", "replace"))
+    cols = {k: scan.cols[k] for k in SPAN_COLUMNS}
+    attrs = {k: scan.attrs[k] for k in ATTR_COLUMNS}
+    for k in ("name", "service", "http_method", "http_url"):
+        cols[k] = codes[cols[k]]
+    for k in ("attr_key", "attr_str"):
+        attrs[k] = codes[attrs[k]]
+    return SpanBatch(cols=cols, attrs=attrs, dictionary=d)
+
+
+def _scan_columnar(buf: bytes, dictionary=None):
+    """The Python scanner: the definition the native scan is held to."""
     b = BatchBuilder(dictionary)
     for field, wt, rs in w.iter_fields(buf):
         if field != 1:
@@ -214,8 +268,6 @@ def decode_traces_request_columnar(buf: bytes, dictionary=None):
 def decode_traces_json_columnar(doc: dict, dictionary=None):
     """OTLP/JSON TracesData directly into a SpanBatch (columnar twin of
     decode_traces_json; spans land in document order)."""
-    from tempo_tpu.model.batchbuild import BatchBuilder
-
     b = BatchBuilder(dictionary)
     for rs in doc.get("resourceSpans", doc.get("resource_spans", [])) or []:
         resource_attrs = _json_attrs((rs.get("resource") or {}).get("attributes", []))

@@ -244,6 +244,9 @@ FAMILY_SERIES_BUDGETS = {
     # codec enums (rle | dct | dbp) — tenants/columns must NEVER become
     # labels here; per-tenant ingest cost lives in the usage counters
     "tempo_tpu_ingest_spans_decoded_total": 4,
+    # which OTLP scanner answered a body: native, or python with the reason
+    # the native scan declined (native.OTLP_DECLINED + no_library, closed)
+    "tempo_tpu_ingest_decode_requests_total": 12,
     "tempo_tpu_ingest_device_encode_pages_total": 8,
     "tempo_tpu_ingest_encode_fallback_total": 8,
     # tenant x kind cost counters (usage accountant eviction bounds tenant)

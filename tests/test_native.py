@@ -224,3 +224,157 @@ def test_compactor_native_merge_matches_device_plan(tmp_path, lib, monkeypatch):
     rows1 = np.concatenate([b1.read_columns(rg, ["trace_id"])["trace_id"] for rg in b1.index().row_groups])
     rows2 = np.concatenate([b2.read_columns(rg, ["trace_id"])["trace_id"] for rg in b2.index().row_groups])
     np.testing.assert_array_equal(rows1, rows2)
+
+
+# -- the OTLP scan -----------------------------------------------------------
+#
+# What the scan answers is held to the Python scanner in
+# tests/test_receivers.py; these are the binding's own obligations.
+
+
+def _otlp_body(n_traces, seed, n_spans=4):
+    from tempo_tpu.model.synth import make_trace
+    from tempo_tpu.receivers import otlp
+
+    return otlp.encode_traces_request(
+        [make_trace(seed=seed * 100 + i, n_spans=n_spans) for i in range(n_traces)])
+
+
+def _scans_equal(a, b):
+    for x, y in ((a.cols, b.cols), (a.attrs, b.attrs)):
+        assert x.keys() == y.keys()
+        for k in x:
+            np.testing.assert_array_equal(x[k], y[k])
+    for x, y in zip(a[2:], b[2:]):
+        np.testing.assert_array_equal(x, y)
+
+
+def _in_thread(fn):
+    """fn's result from a thread of its own: the scan's arrays are per
+    thread and only grow, so a new thread starts with none."""
+    import threading
+
+    out = []
+    t = threading.Thread(target=lambda: out.append(fn()))
+    t.start()
+    t.join(timeout=60)
+    assert not t.is_alive() and out
+    return out[0]
+
+
+def test_otlp_scan_returns_columns_at_their_counts(lib):
+    from tempo_tpu.model.columnar import ATTR_COLUMNS, SPAN_COLUMNS
+
+    scan = lib.otlp_scan(_otlp_body(3, seed=1))
+    assert set(scan.cols) == set(SPAN_COLUMNS) and set(scan.attrs) == set(ATTR_COLUMNS)
+    for name, (dtype, width) in SPAN_COLUMNS.items():
+        assert scan.cols[name].dtype == dtype
+        assert scan.cols[name].shape == ((12, width) if width else (12,))
+    m = scan.attrs["attr_span"].shape[0]
+    assert m > 0
+    for name, (dtype, _) in ATTR_COLUMNS.items():
+        assert scan.attrs[name].dtype == dtype and scan.attrs[name].shape == (m,)
+    k = scan.str_off.shape[0]
+    assert scan.str_len.shape == scan.str_used.shape == (k,)
+    assert (scan.str_off[0], scan.str_len[0]) == (0, 0)  # "" is entry 0
+    for col in ("name", "service", "http_method", "http_url"):
+        assert scan.cols[col].max() < k
+    assert scan.attrs["attr_key"].max() < k and scan.attrs["attr_str"].max() < k
+    # the arrays are the caller's own, not views of the thread's scratch
+    again = lib.otlp_scan(_otlp_body(3, seed=2))
+    assert not np.array_equal(again.cols["trace_id"], scan.cols["trace_id"])
+
+
+def test_otlp_scan_empty_body(lib):
+    scan = lib.otlp_scan(b"")
+    assert scan.cols["trace_id"].shape == (0, 4)
+    assert scan.attrs["attr_num"].shape == (0,)
+    assert scan.str_off.shape == (1,)
+
+
+def test_otlp_scan_declines_with_a_listed_reason(lib):
+    assert lib.otlp_scan(b"\x0a\x7f") == "malformed"
+    assert lib.otlp_scan(b"\x0b") in native.OTLP_DECLINED.values()
+
+
+@pytest.mark.parametrize("caps", [[1, 1, 1, 1], [1, 4096, 64, 4096],
+                                  [4096, 1, 64, 4096], [4096, 4096, 1, 4096],
+                                  [4096, 4096, 64, 2]])
+def test_otlp_scan_asks_again_where_its_arrays_were_too_small(lib, caps):
+    """Each capacity in turn too small for the body: the second call, at
+    the counts the first returned, gives the answer a roomy call gives."""
+    from tempo_tpu.receivers import protowire as pw
+
+    body = bytearray(_otlp_body(6, seed=3))
+    # a resource with two attrs beside service.name, so that capacity counts
+    res, kv = bytearray(), bytearray()
+    for key in (b"zone", b"rack"):
+        kv.clear()
+        pw.put_bytes_field(kv, 1, key)
+        pw.put_bytes_field(kv, 2, b"\x0a\x01a")
+        pw.put_bytes_field(res, 1, bytes(kv))
+    rs = bytearray()
+    pw.put_bytes_field(rs, 1, bytes(res))
+    pw.put_bytes_field(rs, 2, b"\x12\x04\x2a\x02op")
+    pw.put_bytes_field(body, 1, bytes(rs))
+    body = bytes(body)
+    want = lib.otlp_scan(body)
+    assert not isinstance(want, str) and want.cols["trace_id"].shape[0] == 25
+    _scans_equal(_in_thread(lambda: lib.otlp_scan(body, caps=caps)), want)
+
+
+def test_otlp_scan_threads_each_get_their_own_answer(lib):
+    """Four threads scanning different bodies at once, over and over."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    bodies = [_otlp_body(4 + 3 * i, seed=10 + i, n_spans=3 + i) for i in range(4)]
+    want = [lib.otlp_scan(b) for b in bodies]
+
+    def work(i):
+        for _ in range(50):
+            _scans_equal(lib.otlp_scan(bodies[i]), want[i])
+        return True
+
+    with ThreadPoolExecutor(4) as pool:
+        assert all(f.result(timeout=120) for f in [pool.submit(work, i) for i in range(4)])
+
+
+def test_otlp_scan_reads_nothing_past_the_body(lib):
+    """Truncated and bit-flipped bodies, each laid so that its last byte is
+    the last byte before a page no one may read: an overrun kills the
+    process instead of passing unseen."""
+    import ctypes
+    import mmap
+
+    body = _otlp_body(16, seed=4, n_spans=8)
+    page = mmap.PAGESIZE
+    pages = len(body) // page + 1
+    mm = mmap.mmap(-1, (pages + 1) * page)
+    hold = ctypes.c_char.from_buffer(mm)
+    base = ctypes.addressof(hold)
+    libc = ctypes.CDLL(None, use_errno=True)
+    libc.mprotect.argtypes = [ctypes.c_void_p, ctypes.c_size_t, ctypes.c_int]
+    assert libc.mprotect(base + pages * page, page, 0) == 0  # PROT_NONE
+    try:
+        def at_the_end(data: bytes):
+            view = np.frombuffer(mm, np.uint8, len(data), pages * page - len(data))
+            view[:] = np.frombuffer(data, np.uint8)
+            return view
+
+        rng = np.random.default_rng(5)
+        answered = declined = 0
+        for cut in range(0, len(body), max(1, len(body) // 150)):
+            got = lib.otlp_scan(at_the_end(body[:cut]))
+            declined += isinstance(got, str)
+        for at in rng.integers(0, len(body), 150).tolist():
+            flipped = bytearray(body)
+            flipped[at] ^= 1 << int(rng.integers(0, 8))
+            got = lib.otlp_scan(at_the_end(bytes(flipped)))
+            answered += not isinstance(got, str)
+        assert declined > 100 and answered > 0
+        whole = lib.otlp_scan(at_the_end(body))
+        _scans_equal(whole, lib.otlp_scan(body))
+    finally:
+        libc.mprotect(base + pages * page, page, mmap.PROT_READ | mmap.PROT_WRITE)
+        del hold
+        mm.close()

@@ -3,10 +3,12 @@ Jaeger thrift-binary (payload built with a minimal thrift writer), and
 the HTTP shim dispatch. Mirrors the reference's receiver coverage
 (integration/e2e/receivers_test.go exercises every protocol)."""
 
+import functools
 import gzip
 import json
 import struct
 
+import numpy as np
 import pytest
 
 from tempo_tpu import receivers
@@ -19,6 +21,7 @@ from tempo_tpu.model.trace import (
     Trace,
 )
 from tempo_tpu.receivers import jaeger, otlp, zipkin
+from tempo_tpu.receivers import protowire as pw
 
 
 def _span_index(traces):
@@ -384,6 +387,413 @@ class TestColumnarDecode:
         assert receivers.spans_decoded_total.value(path="columnar") == col0 + 3
         receivers.decode_http("/v1/traces", "application/x-protobuf", body)
         assert receivers.spans_decoded_total.value(path="object") == obj0 + 3
+
+
+# --- the two OTLP/HTTP protobuf scanners ------------------------------------
+#
+# receivers/otlp.py's Python scanner is the definition; native/codec.cc's
+# scan answers only where its answer is certain to be the same. Each case
+# below is one body, with the reason the native scan must decline it for
+# ("" where it must not).
+
+
+def _kv(key, any_value: bytes) -> bytes:
+    out = bytearray()
+    pw.put_bytes_field(out, 1, key if isinstance(key, bytes) else key.encode())
+    pw.put_bytes_field(out, 2, any_value)
+    return bytes(out)
+
+
+def _any(field: int, value) -> bytes:
+    """An AnyValue with one field set: 1 string, 2 bool, 3 int, 4 double,
+    5 array, 6 kvlist, 7 bytes."""
+    out = bytearray()
+    if field in (2, 3):
+        pw.put_varint_field(out, field, value)
+    elif field == 4:
+        pw.put_double_field(out, field, value)
+    else:
+        pw.put_bytes_field(out, field, value)
+    return bytes(out)
+
+
+def _span(tid=b"\x01" * 16, sid=b"\x02" * 8, pid=None, name=b"op", kind=2,
+          start=1_000, end=3_000, attrs=(), status=None, extra=b"") -> bytes:
+    out = bytearray()
+    if tid is not None:
+        pw.put_bytes_field(out, 1, tid)
+    if sid is not None:
+        pw.put_bytes_field(out, 2, sid)
+    if pid is not None:
+        pw.put_bytes_field(out, 4, pid)
+    pw.put_bytes_field(out, 5, name)
+    pw.put_varint_field(out, 6, kind)
+    pw.put_fixed64_field(out, 7, start)
+    pw.put_fixed64_field(out, 8, end)
+    for a in attrs:
+        pw.put_bytes_field(out, 9, a)
+    if status is not None:
+        st = bytearray()
+        pw.put_varint_field(st, 3, status)
+        pw.put_bytes_field(out, 15, bytes(st))
+    return bytes(out) + extra
+
+
+def _resource_spans(spans=(), resource_attrs=None, extra=b"",
+                    resource_last=False) -> bytes:
+    """One ResourceSpans field of a request; resource_attrs None leaves
+    the Resource out, a list of lists writes several Resource messages."""
+    parts = []
+    groups = ([] if resource_attrs is None else
+              resource_attrs if resource_attrs and isinstance(resource_attrs[0], list)
+              else [resource_attrs])
+    for group in groups:
+        res = bytearray()
+        for a in group:
+            pw.put_bytes_field(res, 1, a)
+        part = bytearray()
+        pw.put_bytes_field(part, 1, bytes(res))
+        parts.append(bytes(part))
+    ss = bytearray()
+    for sp in spans:
+        pw.put_bytes_field(ss, 2, sp)
+    scope = bytearray()
+    pw.put_bytes_field(scope, 2, bytes(ss))
+    parts.insert(0 if resource_last else len(parts), bytes(scope))
+    out = bytearray()
+    pw.put_bytes_field(out, 1, b"".join(parts) + extra)
+    return bytes(out)
+
+
+_SVC = _kv("service.name", _any(1, b"shop"))
+_UNKNOWN = (b"\xa0\x06\x07"  # field 100, varint
+            b"\xa1\x06" + b"\x01" * 8 +  # fixed64
+            b"\xa2\x06\x03abc"  # length-delimited
+            b"\xa5\x06" + b"\x02" * 4)  # fixed32
+
+
+@functools.cache
+def _w_shape(n_traces, spans):
+    from tempo_tpu.model import synth
+    from tempo_tpu.model.trace import batch_to_traces
+
+    return otlp.encode_traces_request(
+        batch_to_traces(synth.make_batch(n_traces, spans, seed=3)))
+
+
+def _one(*attrs, **kw) -> bytes:
+    """A request of one span under service `shop` with these attributes."""
+    return _resource_spans([_span(attrs=attrs, **kw)], [_SVC])
+
+
+_SCAN_CASES = {
+    "w_shape_64x16": (lambda: _w_shape(64, 16), ""),
+    "empty_body": (lambda: b"", ""),
+    "several_resources": (lambda: b"".join(
+        _resource_spans(
+            [_span(sid=bytes([i, j] * 4), name=b"op%d" % j,
+                   attrs=[_kv("k", _any(1, b"v%d" % j))]) for j in range(3)],
+            [_kv("service.name", _any(1, b"svc%d" % i)),
+             _kv("zone", _any(1, b"z%d" % (i % 2))), _kv("replicas", _any(3, i))])
+        for i in range(4)), ""),
+    "resource_without_service_name": (lambda: _resource_spans(
+        [_span()], [_kv("zone", _any(1, b"a"))]), ""),
+    "no_resource_at_all": (lambda: _resource_spans([_span()], None), ""),
+    "resource_after_its_spans": (lambda: _resource_spans(
+        [_span(), _span(sid=b"\x03" * 8)],
+        [_SVC, _kv("zone", _any(1, b"a"))], resource_last=True), ""),
+    "two_resource_messages": (lambda: _resource_spans(
+        [_span()], [[_SVC], [_kv("zone", _any(1, b"a"))]]), ""),
+    "group_without_spans": (lambda: _resource_spans(
+        [], [_kv("service.name", _any(1, b"idle")), _kv("zone", _any(1, b"q"))])
+        + _resource_spans([_span()], [_SVC]), ""),
+    "service_name_as_span_attr": (lambda: _one(
+        _kv("service.name", _any(1, b"inner"))), ""),
+    "http_keys_on_the_resource": (lambda: _resource_spans(
+        [_span()], [_SVC, _kv("http.method", _any(3, 5))]), ""),
+    "value_string": (lambda: _one(_kv("k", _any(1, b"v"))), ""),
+    "value_empty_string": (lambda: _one(_kv("k", _any(1, b""))), ""),
+    "value_bool": (lambda: _one(_kv("t", _any(2, 1)), _kv("f", _any(2, 0))), ""),
+    "value_negative_int": (lambda: _one(_kv("k", _any(3, -7))), ""),
+    "value_int_beyond_double": (lambda: _one(_kv("k", _any(3, 2**63 - 1))), ""),
+    "value_double": (lambda: _one(_kv("k", _any(4, -2.5)),
+                                  _kv("n", _any(4, float("nan")))), ""),
+    "value_array": (lambda: _one(_kv("k", _any(5, b"\x0a\x03\x0a\x01x"))),
+                    "value_type"),
+    "value_kvlist": (lambda: _one(_kv("k", _any(6, b"\x0a" + bytes([len(
+        _kv("in", _any(3, 1)))]) + _kv("in", _any(3, 1))))), "value_type"),
+    "value_bytes": (lambda: _one(_kv("k", _any(7, b"\x00\xff"))), "value_type"),
+    "value_absent": (lambda: _one(_kv("k", b"")), "value_type"),
+    "value_field_missing": (lambda: _one(b"\x0a\x01k"), "value_type"),
+    "value_array_on_a_resource": (lambda: _resource_spans(
+        [_span()], [_SVC, _kv("k", _any(5, b""))]), "value_type"),
+    "promoted_values": (lambda: _one(
+        _kv("http.status_code", _any(3, 503)), _kv("http.method", _any(1, b"GET")),
+        _kv("http.url", _any(1, b"http://a/b")), _kv("k", _any(1, b"v"))), ""),
+    "http_status_code_as_string": (lambda: _one(
+        _kv("http.status_code", _any(1, b"200"))), "promoted_type"),
+    "http_status_code_70000": (lambda: _one(
+        _kv("http.status_code", _any(3, 70_000))), "promoted_type"),
+    "http_status_code_negative": (lambda: _one(
+        _kv("http.status_code", _any(3, -1))), "promoted_type"),
+    "http_method_as_int": (lambda: _one(
+        _kv("http.method", _any(3, 1))), "promoted_type"),
+    "http_url_as_bool": (lambda: _one(
+        _kv("http.url", _any(2, 1))), "promoted_type"),
+    "service_name_as_int": (lambda: _resource_spans(
+        [_span()], [_kv("service.name", _any(3, 9))]), "promoted_type"),
+    "repeated_key": (lambda: _one(
+        _kv("k", _any(1, b"a")), _kv("j", _any(3, 1)), _kv("k", _any(1, b"b"))),
+        "duplicate_key"),
+    "repeated_promoted_key": (lambda: _one(
+        _kv("http.method", _any(1, b"GET")), _kv("http.method", _any(1, b"PUT"))),
+        "duplicate_key"),
+    "repeated_resource_key": (lambda: _resource_spans(
+        [_span()], [[_SVC, _kv("zone", _any(1, b"a"))],
+                    [_kv("zone", _any(1, b"b"))]]), "duplicate_key"),
+    "same_key_in_two_spans": (lambda: _resource_spans(
+        [_span(attrs=[_kv("k", _any(1, b"a"))]),
+         _span(sid=b"\x03" * 8, attrs=[_kv("k", _any(1, b"b"))])],
+        [_SVC, _kv("k", _any(1, b"r"))]), ""),
+    "empty_key": (lambda: _one(_kv("", _any(1, b"v")), _kv("k", _any(3, 1))),
+                  "empty_key"),
+    "empty_resource_key": (lambda: _resource_spans(
+        [_span()], [_SVC, _kv("", _any(1, b"v"))]), "empty_key"),
+    "name_not_utf8": (lambda: _one(name=b"caf\xe9 \xff\xfe"), ""),
+    "value_not_utf8": (lambda: _one(_kv("k", _any(1, b"\xc3(")),
+                                    _kv("j", _any(1, b"\xef\xbf\xbd("))), ""),
+    "key_utf8": (lambda: _one(_kv("clé", _any(1, b"v")),
+                              _kv("鍵\U0001f511", _any(3, 1))), ""),
+    "key_not_utf8": (lambda: _one(_kv(b"\xff", _any(1, b"a")),
+                                  _kv(b"\xfe", _any(1, b"b"))), "key_encoding"),
+    "key_utf8_surrogate": (lambda: _one(_kv(b"\xed\xa0\x80", _any(1, b"a"))),
+                           "key_encoding"),
+    "key_utf8_overlong": (lambda: _one(_kv(b"\xc0\xaf", _any(1, b"a"))),
+                          "key_encoding"),
+    "ids_absent": (lambda: _one(tid=None, sid=None), ""),
+    "ids_0_bytes": (lambda: _one(tid=b"", sid=b"", pid=b""), ""),
+    "ids_4_bytes": (lambda: _one(tid=b"\x01\x02\x03\x04", sid=b"\x05\x06\x07\x08",
+                                 pid=b"\x09\x0a\x0b\x0c"), ""),
+    "ids_full": (lambda: _one(tid=bytes(range(16)), sid=bytes(range(8)),
+                              pid=bytes(range(8, 16))), ""),
+    "trace_id_20_bytes": (lambda: _one(tid=bytes(range(20))), "id_length"),
+    "span_id_9_bytes": (lambda: _one(sid=bytes(range(9))), "id_length"),
+    "parent_id_16_bytes": (lambda: _one(pid=bytes(range(16))), "id_length"),
+    "end_before_start": (lambda: _one(start=5_000, end=4_000), ""),
+    "times_at_u64_max": (lambda: _one(start=0, end=2**64 - 1), ""),
+    "times_as_varints": (lambda: _one(
+        extra=b"\x38\x90\x4e" b"\x40\xa0\x9c\x01"), ""),
+    "status_codes": (lambda: _resource_spans(
+        [_span(status=2), _span(sid=b"\x03" * 8, status=1, kind=5)], [_SVC]), ""),
+    "kind_300": (lambda: _one(kind=300), "out_of_range"),
+    "status_256": (lambda: _one(status=256), "out_of_range"),
+    "name_repeated_last_wins": (lambda: _one(extra=b"\x2a\x03two"), ""),
+    "unknown_fields_everywhere": (lambda: _UNKNOWN + _resource_spans(
+        [_span(attrs=[_kv("k", _UNKNOWN + _any(1, b"v")) + _UNKNOWN],
+               extra=_UNKNOWN, status=2)],
+        [_SVC + _UNKNOWN], extra=_UNKNOWN) + _UNKNOWN, ""),
+    "junk_after_the_value_is_not_read": (lambda: _one(
+        _kv("k", _any(3, 4) + b"\xff\xff")), ""),
+    "group_wire_types": (lambda: _one(extra=b"\xa3\x06"), "malformed"),
+    "wire_type_7": (lambda: b"\xa7\x06" + _one(), "malformed"),
+    "name_as_varint": (lambda: _one(extra=b"\x28\x05"), "malformed"),
+    "trace_id_as_varint": (lambda: _one(extra=b"\x08\x05"), "malformed"),
+    "kind_as_bytes": (lambda: _one(extra=b"\x32\x011"), "malformed"),
+    "span_as_varint": (lambda: b"\x0a\x04\x12\x02\x10\x01", "malformed"),
+    "resource_spans_as_fixed32": (lambda: b"\x0d\x00\x00\x00\x00", "malformed"),
+    "int_value_as_fixed64": (lambda: _one(
+        _kv("k", b"\x19" + b"\x01" * 8)), "malformed"),
+    "varint_of_eleven_bytes": (lambda: _one(
+        extra=b"\xa0\x06" + b"\xff" * 10 + b"\x01"), "malformed"),
+    "varint_past_64_bits": (lambda: _one(
+        extra=b"\xa0\x06" + b"\xff" * 9 + b"\x7f"), "malformed"),
+    "length_past_the_end": (lambda: _one() + b"\x0a\x7fabc", "malformed"),
+    "inner_length_past_its_message": (lambda: _resource_spans(
+        [_span(extra=b"\x2a\x7f")], [_SVC]), "malformed"),
+}
+
+
+def _resolved(batch):
+    """The batch with every code column resolved to its strings."""
+    d = batch.dictionary.entries
+    out = {}
+    for cols, coded in ((batch.cols, ("name", "service", "http_method", "http_url")),
+                        (batch.attrs, ("attr_key", "attr_str"))):
+        for k, v in cols.items():
+            out[k] = [d[c] for c in v.tolist()] if k in coded else v
+    return out
+
+
+def _assert_same_batch(got, want):
+    a, b = _resolved(got), _resolved(want)
+    assert a.keys() == b.keys()
+    for k in a:
+        if isinstance(a[k], list):
+            assert a[k] == b[k], k
+        else:
+            assert a[k].dtype == b[k].dtype and a[k].shape == b[k].shape, k
+            assert np.array_equal(a[k], b[k], equal_nan=k == "attr_num"), k
+    assert set(got.dictionary.entries) == set(want.dictionary.entries)
+
+
+def _outcome(fn):
+    try:
+        return fn()
+    except Exception as e:  # the handler's 400 or 500 is decided by the class
+        return type(e)
+
+
+def _assert_same_outcome(body, expect=None):
+    """decode_traces_request_columnar answers `body` as the Python scanner
+    does, the batch or the exception class, and heard `expect` (scanner,
+    reason) where given."""
+    said = []
+    got = _outcome(lambda: otlp.decode_traces_request_columnar(
+        body, scanned=lambda scanner, reason: said.append((scanner, reason))))
+    want = _outcome(lambda: otlp._scan_columnar(body))
+    assert len(said) == 1
+    if expect is not None:
+        assert said[0] == expect
+    if isinstance(want, type):
+        assert got is want and said[0][0] == "python"
+    else:
+        _assert_same_batch(got, want)
+    return said[0]
+
+
+@pytest.fixture(params=["library", "no_library"])
+def scanner(request, monkeypatch):
+    """Both arms of decode_traces_request_columnar: the native scan where
+    it built, and the Python scanner alone where native.lib() is None."""
+    from tempo_tpu import native
+
+    if request.param == "no_library":
+        monkeypatch.setattr(native, "lib", lambda: None)
+    else:
+        assert native.lib() is not None, "native codec library failed to build"
+    return request.param
+
+
+class TestScannerEquivalence:
+    @pytest.mark.parametrize("case", sorted(_SCAN_CASES))
+    def test_native_scan_is_the_python_scan_or_declines(self, scanner, case):
+        make, reason = _SCAN_CASES[case]
+        expect = (("python", "no_library") if scanner == "no_library" else
+                  ("python", reason) if reason else ("native", ""))
+        _assert_same_outcome(make(), expect)
+
+    @pytest.mark.parametrize("chunk", range(4))
+    def test_truncated_bodies(self, scanner, chunk):
+        body = _w_shape(64, 16)
+        step = len(body) // 100
+        for cut in range(chunk * 25 * step + 1, (chunk + 1) * 25 * step, step):
+            _assert_same_outcome(body[:cut])
+
+    @pytest.mark.parametrize("chunk", range(4))
+    def test_flipped_bytes(self, scanner, chunk):
+        body = _w_shape(64, 16)
+        rng = np.random.default_rng(chunk)
+        native_answers = 0
+        for at in rng.integers(0, len(body), 25).tolist():
+            flipped = bytearray(body)
+            flipped[at] ^= 1 << int(rng.integers(0, 8))
+            native_answers += _assert_same_outcome(bytes(flipped))[0] == "native"
+        # most flips land in a string or an id and leave the wire intact
+        assert (native_answers > 0) == (scanner == "library")
+
+    def test_callers_dictionary_is_honoured(self, scanner):
+        from tempo_tpu.model.columnar import Dictionary
+
+        d = Dictionary(["", "already", "shop"])
+        batch = otlp.decode_traces_request_columnar(_one(), dictionary=d)
+        assert batch.dictionary is d
+        assert d.entries[:3] == ["", "already", "shop"]
+        assert batch.cols["service"].tolist() == [2]
+        _assert_same_batch(batch, otlp._scan_columnar(
+            _one(), Dictionary(["", "already", "shop"])))
+
+    def test_generated_traces(self, scanner):
+        from hypothesis import given, settings
+        from hypothesis import strategies as st
+
+        scalars = st.one_of(
+            st.text(max_size=6), st.booleans(),
+            st.integers(-(2**63), 2**63 - 1), st.floats(allow_nan=False))
+        values = st.one_of(scalars, st.lists(scalars, max_size=2),
+                           st.dictionaries(st.text(max_size=3), scalars, max_size=2))
+        attrs = st.dictionaries(
+            st.one_of(st.text(max_size=4), st.sampled_from(
+                ["http.method", "http.url", "http.status_code", "service.name"])),
+            values, max_size=4)
+        spans = st.builds(
+            Span, trace_id=st.binary(min_size=16, max_size=16),
+            span_id=st.binary(min_size=8, max_size=8),
+            parent_span_id=st.binary(min_size=8, max_size=8),
+            name=st.text(max_size=8), kind=st.integers(0, 5),
+            start_unix_nano=st.integers(0, 2**62),
+            duration_nano=st.integers(0, 2**40),
+            status_code=st.integers(0, 2), attributes=attrs)
+        traces = st.lists(st.builds(
+            lambda batches: Trace(trace_id=b"\0" * 16, batches=batches),
+            st.lists(st.tuples(attrs, st.lists(spans, max_size=3)), max_size=3)),
+            max_size=3)
+
+        heard = set()
+
+        @settings(max_examples=150, deadline=None, database=None, derandomize=True)
+        @given(traces)
+        def run(ts):
+            heard.add(_assert_same_outcome(otlp.encode_traces_request(ts)))
+
+        run()
+        if scanner == "library":  # the strategy reaches both answers
+            assert {("native", ""), ("python", "value_type")} <= heard
+
+
+class TestScannerCounter:
+    def test_native_body_counts_once_and_its_spans_as_columnar(self):
+        body = _w_shape(2, 3)
+        n0 = receivers.decode_requests_total.value(scanner="native", reason="")
+        col0 = receivers.spans_decoded_total.value(path="columnar")
+        receivers.decode_http_columnar("/v1/traces", "application/x-protobuf", body)
+        assert receivers.decode_requests_total.value(
+            scanner="native", reason="") == n0 + 1
+        assert receivers.spans_decoded_total.value(path="columnar") == col0 + 6
+
+    def test_declined_body_counts_under_its_reason(self):
+        body = _SCAN_CASES["value_kvlist"][0]()
+        p0 = receivers.decode_requests_total.value(
+            scanner="python", reason="value_type")
+        col0 = receivers.spans_decoded_total.value(path="columnar")
+        batch = receivers.decode_http_columnar(
+            "/v1/traces", "application/x-protobuf", body)
+        assert batch.num_spans == 1
+        assert receivers.decode_requests_total.value(
+            scanner="python", reason="value_type") == p0 + 1
+        assert receivers.spans_decoded_total.value(path="columnar") == col0 + 1
+
+    def test_malformed_body_counts_before_it_is_refused(self):
+        body = _w_shape(1, 2)[:-3]
+        p0 = receivers.decode_requests_total.value(
+            scanner="python", reason="malformed")
+        with pytest.raises(pw.WireError):
+            receivers.decode_http_columnar(
+                "/v1/traces", "application/x-protobuf", body)
+        assert receivers.decode_requests_total.value(
+            scanner="python", reason="malformed") == p0 + 1
+
+    def test_every_series_is_there_from_import(self):
+        from tempo_tpu import native
+
+        have = {(s["scanner"], s["reason"])
+                for s, _ in receivers.decode_requests_total.series()}
+        assert have >= {("native", ""), ("python", "no_library")} | {
+            ("python", r) for r in native.OTLP_DECLINED.values()}
+
+    def test_json_bodies_do_not_count(self):
+        before = sum(v for _, v in receivers.decode_requests_total.series())
+        receivers.decode_http_columnar("/v1/traces", "application/json", b"{}")
+        assert sum(v for _, v in receivers.decode_requests_total.series()) == before
 
 
 # --- zipkin v1 thrift ------------------------------------------------------
