@@ -38,6 +38,19 @@ inflight_push_gauge = metrics.gauge(
     "tempo_distributor_inflight_push_bytes",
     "Bytes of push payloads currently being fanned out",
 )
+generator_batches_total = metrics.counter(
+    "tempo_tpu_generator_batches_total",
+    "Pushes handed to a metrics-generator, one a generator a push reaches: "
+    "path=\"batch\" the SpanBatch itself to an in-process generator, "
+    "path=\"segment\" serialized bytes to a remote one",
+)
+for _path in ("batch", "segment"):
+    generator_batches_total.inc(0, path=_path)
+generator_push_seconds_total = metrics.counter(
+    "tempo_tpu_generator_push_seconds_total",
+    "Wall seconds of the distributor's generator tee, every generator's "
+    "processors included",
+)
 
 
 class RateLimited(Exception):
@@ -305,22 +318,42 @@ class Distributor:
     def _send_to_generators(self, tenant: str, batch: SpanBatch) -> None:
         if not self.generator_ring or not self.generator_clients:
             return
+        t0 = time.perf_counter()
+        try:
+            with tracing.span("generator/push", spans=batch.num_spans):
+                self._tee(tenant, batch)
+        finally:
+            generator_push_seconds_total.inc(time.perf_counter() - t0)
+
+    def _tee(self, tenant: str, batch: SpanBatch) -> None:
         size = self.overrides.for_tenant(tenant).metrics_generator_ring_size
         targets = self.generator_ring.shuffle_shard(tenant, size)
         if not targets:
             return
-        # single-assignment by trace token within the shard
-        tid = batch.cols["trace_id"]
-        tokens = hashing.np_token_for_ids(tenant, tid)
-        idx = tokens % np.uint32(len(targets))
+        # single-assignment by trace token within the shard; a shard of one
+        # takes the whole batch
+        idx = None
+        if len(targets) > 1:
+            idx = hashing.np_token_for_ids(tenant, batch.cols["trace_id"]) % np.uint32(len(targets))
         for i, inst in enumerate(targets):
             client = self.generator_clients.get(inst.instance_id)
             if client is None:
                 continue
-            rows = np.flatnonzero(idx == i)
-            if len(rows) == 0:
-                continue
+            sub = batch
+            if idx is not None:
+                rows = np.flatnonzero(idx == i)
+                if len(rows) == 0:
+                    continue
+                if len(rows) < batch.num_spans:
+                    sub = batch.select(rows)
             try:
-                client.push_segment(tenant, fmt.serialize_batch(batch.select(rows)))
+                # an in-process generator reads the batch as it is (and
+                # copies what it keeps); a remote one gets a segment
+                if hasattr(client, "push_batch"):
+                    generator_batches_total.inc(path="batch")
+                    client.push_batch(tenant, sub)
+                else:
+                    generator_batches_total.inc(path="segment")
+                    client.push_segment(tenant, fmt.serialize_batch(sub))
             except Exception:
                 log.exception("generator push failed (non-fatal)")

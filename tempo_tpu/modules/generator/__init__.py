@@ -67,6 +67,8 @@ class Generator:
         self.instance(tenant).push_batch(fmt.deserialize_batch(data))
 
     def push_batch(self, tenant: str, batch) -> None:
+        """The in-process hand-off: the processors only read `batch` and
+        copy what they keep across pushes."""
         self.instance(tenant).push_batch(batch)
 
     def collect(self, tenant: str) -> list:

@@ -258,12 +258,12 @@ class TestLiveStoredParity:
 
         live = {}
         inst = app.generator.instance("single-tenant")
-        for (name, labels), cur in inst.registry.counters.items():
-            if name not in (REQ_TOTAL, REQ_FAILED):
+        for s in inst.registry.collect():
+            if s.name not in (REQ_TOTAL, REQ_FAILED):
                 continue
-            lab = dict(labels)
+            lab = dict(s.labels)
             slot = live.setdefault((lab["client"], lab["server"]), [0, 0])
-            slot[0 if name == REQ_TOTAL else 1] = int(cur[0])
+            slot[0 if s.name == REQ_TOTAL else 1] = int(s.value)
         live = {k: tuple(v) for k, v in live.items()}
         assert got == live
         assert got  # the parity is not 0 == 0
@@ -281,8 +281,7 @@ class TestLiveStoredParity:
         assert proc.pending_servers  # the root server too
         proc.expire(now=200.0)
         assert not proc.pending_clients and not proc.pending_servers
-        got = {labels: cur[0] for (name, labels), cur in reg.counters.items()
-               if name == EXPIRED_TOTAL}
+        got = {s.labels: s.value for s in reg.collect() if s.name == EXPIRED_TOTAL}
         assert got == {
             (("store", "client"), ("reason", "expired")): 1.0,
             (("store", "server"), ("reason", "expired")): 1.0,

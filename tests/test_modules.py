@@ -538,6 +538,60 @@ class TestGenerator:
         assert vals[("traces_service_graph_request_failed_total", (("client", "A"), ("server", "B")))] == 1.0
         assert p.distinct_edges_estimate() >= 1.0
 
+    def test_tee_hands_an_in_process_generator_the_batch(self, monkeypatch):
+        """The tee gives an in-process generator the push's SpanBatch itself
+        (no serialize_batch), a client with only push_segment still gets
+        bytes, each path is counted, and both publish the same series."""
+        from tempo_tpu.modules import distributor as dmod
+        from tempo_tpu.modules.distributor import Distributor
+        from tempo_tpu.modules.generator import Generator
+
+        serialized = []
+        real = dmod.fmt.serialize_batch
+        monkeypatch.setattr(dmod.fmt, "serialize_batch",
+                            lambda b, *a, **k: serialized.append(b) or real(b, *a, **k))
+
+        class SegmentOnly:  # what a remote generator offers
+            def __init__(self, gen):
+                self.gen = gen
+
+            def push_segment(self, tenant, data):
+                self.gen.push_segment(tenant, data)
+
+        overrides = Overrides(Limits())
+        gen_ring = Ring(MemoryKV(), replication_factor=1)
+        gen_ring.register("generator-0")
+        local, remote = Generator(overrides), Generator(overrides)
+        handed = []
+        push_batch = local.push_batch
+        monkeypatch.setattr(local, "push_batch",
+                            lambda t, b: handed.append(b) or push_batch(t, b))
+
+        def tee(client):
+            return Distributor(Ring(MemoryKV()), {}, overrides, generator_ring=gen_ring,
+                               generator_clients={"generator-0": client})
+
+        by_batch, by_segment = tee(local), tee(SegmentOnly(remote))
+        counter = dmod.generator_batches_total
+        before = {p: counter.value(path=p) for p in ("batch", "segment")}
+        batches = [synth.make_graph_batch(8, 6, seed=40 + i) for i in range(3)]
+        for b in batches:
+            by_batch._send_to_generators("acme", b)
+        assert serialized == [] and [id(b) for b in handed] == [id(b) for b in batches]
+        assert counter.value(path="batch") - before["batch"] == 3
+        assert counter.value(path="segment") == before["segment"]
+        for b in batches:
+            by_segment._send_to_generators("acme", b)
+        assert len(serialized) == 3
+        assert counter.value(path="segment") - before["segment"] == 3
+        assert dmod.generator_push_seconds_total.value() > 0
+
+        def series(gen):
+            return [(s.name, s.labels, s.value, s.exemplar) for s in gen.collect("acme")]
+
+        assert series(local) == series(remote)
+        assert any(s[0] == "traces_service_graph_request_total" for s in series(local))
+
     def test_registry_staleness_and_limits(self):
         from tempo_tpu.modules.generator.registry import ManagedRegistry
 
