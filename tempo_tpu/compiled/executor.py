@@ -32,7 +32,6 @@ import logging
 
 import numpy as np
 
-from tempo_tpu.backend.base import NotFound
 from tempo_tpu.compiled import cache as cache_mod
 from tempo_tpu.compiled.lower import (
     NO_MATCH,
@@ -67,8 +66,8 @@ class _Unit:
 
 def _bind_unit(blk, rg, lowered):
     """The row group's device payload, or None -> interpreter fallback.
-    Reads happen here (inside the caller's guard_block), so a block
-    deleted later cannot corrupt the dispatch."""
+    Reads happen here (inside the caller's guard_block), so the
+    dispatch reads nothing from the backend."""
     cols, pkeys = [], []
     for (kind, col, *_rest) in lowered.colsig:
         enc = blk.encoded_column(rg, col)
@@ -366,16 +365,8 @@ def run_query_range(db, tenant, plans, lowereds, metas):
                 s.stats["decodedBytes"] += getattr(blk, "decoded_bytes", 0)
             staged["subs"] = subs
 
-        try:
-            db.guard_block(tenant, m.block_id, run)
-        except NotFound:
-            # deleted by compaction mid-query: benign, its spans live on
-            # in the compaction output; any OTHER failure propagates so
-            # the caller falls back to the interpreter (which retries
-            # with its own semantics) instead of silently dropping data
-            log.warning("compiled metrics: block %s deleted mid-query",
-                        m.block_id)
-            continue
+        # a failure propagates: the caller falls back to the interpreter
+        db.guard_block(tenant, m.block_id, run)
         # commit-whole: the block's units and fallback partials land
         # only after guard_block succeeds
         units.extend(staged["units"])

@@ -23,6 +23,7 @@ import numpy as np
 
 from tempo_tpu import encoding as encoding_registry
 from tempo_tpu.backend import TypedBackend, make_raw_backend
+from tempo_tpu.backend.base import NotFound
 from tempo_tpu.db.blocklist import Blocklist, Poller
 from tempo_tpu.db.compaction import CompactionConfig, CompactionDriver
 from tempo_tpu.db.pool import JobPool
@@ -48,7 +49,8 @@ find_block_probes = metrics.counter(
     "tempodb_find_block_probes_total",
     "Blocks a trace-by-ID lookup opened: those, live or compacted within "
     "two polls, that passed the trace-ID range, time and block-ID shard "
-    "tests of TempoDB.find",
+    "tests of TempoDB.find, and those an ingester's find job read because "
+    "it flushed them after the find was planned",
 )
 
 
@@ -176,11 +178,15 @@ class TempoDB:
     def block_failure_recorder(self, tenant: str):
         """Callback feeding the blocklist quarantine: one failed block
         read, weighted double for checksum failures (definitively the
-        block's fault, where a connection reset may not be). Handed to
-        the mesh search/metrics paths, which attribute errors per block."""
+        block's fault, where a connection reset may not be), and not at
+        all for NotFound (a missing object says nothing against the
+        block's bytes). Used by guard_block and handed to the mesh
+        search/metrics paths, which attribute errors per block."""
         from tempo_tpu.encoding.vtpu.codec import CorruptPage
 
         def record(block_id: str, e: Exception):
+            if isinstance(e, NotFound):
+                return
             self.blocklist.record_block_failure(
                 tenant, block_id, f"{type(e).__name__}: {e}",
                 weight=2 if isinstance(e, CorruptPage) else 1,
@@ -193,22 +199,17 @@ class TempoDB:
 
     def guard_block(self, tenant: str, block_id: str, fn, benign: tuple = ()):
         """Run one block-scoped read job under failure-domain accounting:
-        failures count toward the block's quarantine (checksum failures
-        count double — definitively the block's fault), successes reset
-        the streak. NotFound passes through unweighted (a block deleted
-        by compaction mid-query is a benign race, not a bad block), as
-        do exception types in `benign` (engine bailouts like the
-        vectorized TraceQL path's Unsupported). Transient errors get a
-        short in-place retry (faults.with_retries) before any of that —
-        per-op retries are what let a multi-block query converge under a
+        failures count toward the block's quarantine (block_failure_recorder
+        says which), successes reset the streak; exception types in
+        `benign` (engine bailouts like the vectorized TraceQL path's
+        Unsupported) count neither way. Transient errors get a short
+        in-place retry (faults.with_retries) before any of that — per-op
+        retries are what let a multi-block query converge under a
         sustained backend fault rate."""
-        from tempo_tpu.backend.base import NotFound as _NotFound
         from tempo_tpu.backend.faults import with_retries
 
         try:
             out = with_retries(fn)
-        except _NotFound:
-            raise
         except Exception as e:
             if not isinstance(e, benign):
                 self.block_failure_recorder(tenant)(block_id, e)
@@ -297,6 +298,22 @@ class TempoDB:
     # reader
     # ------------------------------------------------------------------
 
+    def block_meta(self, tenant: str, block_id: str):
+        """The meta of a block a job was cut for, whether or not a
+        compaction swap has landed since: the in-memory list (live, then
+        compacted), then the backend's meta.json, then its
+        meta.compacted.json. A compacted input keeps its data for
+        compacted_retention_s, so a job planned from one blocklist
+        snapshot reads that snapshot's blocks; NotFound here means the
+        block was cleared past its retention, an error like any other."""
+        meta = self.blocklist.meta(tenant, block_id)
+        if meta is not None:
+            return meta
+        try:
+            return self.backend.block_meta(tenant, block_id)
+        except NotFound:
+            return self.backend.compacted_block_meta(tenant, block_id).meta
+
     def find(self, tenant: str, trace_id: bytes,
              block_start: str = "0" * 32, block_end: str = "f" * 32,
              time_start: int = 0, time_end: int = 0) -> Trace | None:
@@ -335,6 +352,11 @@ class TempoDB:
             if c.compacted_time >= lookback and c.meta.block_id not in live
             and include(c.meta)
         ]
+        return self.find_in_blocks(tenant, trace_id, metas)
+
+    def find_in_blocks(self, tenant: str, trace_id: bytes, metas: list) -> Trace | None:
+        """Trace-by-ID in the given blocks (find's candidates, or the
+        blocks an ingester flushed after a find was planned)."""
         find_block_probes.inc(len(metas))
 
         def job(meta):
@@ -346,13 +368,13 @@ class TempoDB:
         results, errors = self.pool.run_jobs(
             [lambda m=m: self.guard_block(tenant, m.block_id, lambda: job(m)) for m in metas]
         )
-        fatal = _fatal(errors)
+        # a failed block read could hide spans of this trace; surface it
+        # rather than return a silently incomplete trace. NotFound is the
+        # one exception: find probes compacted inputs beyond its snapshot,
+        # and an input cleared before two polls were up has its spans in
+        # the compaction output, which the output's own shard job reads
+        fatal = [e for e in errors if not isinstance(e, NotFound)]
         if fatal:
-            # a failed block read could hide spans of this trace; surface it
-            # rather than return a silently incomplete trace (NotFound is
-            # the benign cleared-after-compaction race: that data lives
-            # in the compaction output, which the shard job of the
-            # output's own slice reads)
             raise fatal[0]
         return combine_traces([r for r in results if r is not None])
 
@@ -405,12 +427,11 @@ class TempoDB:
             [lambda m=m: self.guard_block(tenant, m.block_id, lambda: job(m)) for m in metas],
             stop_when=enough,
         )
-        fatal = _fatal(errors)
-        if fatal:
+        if errors:
             # strict by design: degradation (partial results within a
             # failed-shard budget) is the FRONTEND's call, not something
             # the storage layer silently decides per block
-            raise fatal[0]
+            raise errors[0]
         for r in results:
             out.merge(r, limit=req.limit)
         return out
@@ -502,7 +523,7 @@ class TempoDB:
 
         def run():
             with tracing.span("tempodb/search_block", block=str(block_id)):
-                meta = self.backend.block_meta(tenant, block_id)
+                meta = self.block_meta(tenant, block_id)
                 blk = self.encoding_for(meta.version).open_block(
                     meta, self.backend, self.cfg.block)
                 return blk.search(req, start_row_group=start_row_group,
@@ -532,9 +553,8 @@ class TempoDB:
         results, errors = self.pool.run_jobs(
             [lambda m=m: self.guard_block(tenant, m.block_id, lambda: job(m)) for m in metas]
         )
-        fatal = _fatal(errors)
-        if fatal:
-            raise fatal[0]
+        if errors:
+            raise errors[0]
         by_id: dict[bytes, list] = {}
         for traces, bytes_read, pruned, coalesced, decoded in results:
             if stats is not None:
@@ -556,9 +576,8 @@ class TempoDB:
                 return blk.collect_spans_for_ids(hex_ids)
 
             full, errors = self.pool.run_jobs([lambda m=m: complete(m) for m in metas])
-            fatal = _fatal(errors)
-            if fatal:
-                raise fatal[0]
+            if errors:
+                raise errors[0]
             by_id = {}
             for traces in full:
                 for t in traces:
@@ -632,7 +651,7 @@ class TempoDB:
                  for m in metas]
             )
             straddled = False
-            if structural and not _fatal(errors):
+            if structural and not errors:
                 counts: dict = {}
                 for _local, _b, _n, seen, _d in results:
                     for tid in seen:
@@ -643,8 +662,8 @@ class TempoDB:
                 # or a trace straddling blocks under a structural query):
                 # the object engine below answers exactly
                 pass
-            elif _fatal(errors):
-                raise _fatal(errors)[0]
+            elif errors:
+                raise errors[0]
             else:
                 partials: dict = {}
                 for local, bytes_read, n_traces, _seen, decoded in results:
@@ -682,8 +701,6 @@ class TempoDB:
         removed. Run by the compactor's retention cycle (one owner — the
         same instance that may clear compacted blocks), or explicitly at
         startup."""
-        from tempo_tpu.backend.base import NotFound as _NF
-
         grace = self.cfg.orphan_grace_s if grace_s is None else grace_s
         now = now or time.time()
         removed: list[tuple[str, str]] = []
@@ -695,7 +712,7 @@ class TempoDB:
                 try:
                     read(tenant, block_id)
                     return False
-                except _NF:
+                except NotFound:
                     continue
                 except Exception:
                     return None  # unknown: skip this cycle
@@ -765,14 +782,6 @@ class TempoDB:
             # drains write-behind queues and closes memcached sockets
             self._cache_client.stop()
             self._cache_client = None
-
-
-def _fatal(errors) -> list:
-    """Drop the benign deleted-mid-query race (NotFound) from a job-pool
-    error list; everything left must be surfaced, never swallowed."""
-    from tempo_tpu.backend.base import NotFound
-
-    return [e for e in errors if not isinstance(e, NotFound)]
 
 
 def _overlaps(meta, start: int, end: int) -> bool:

@@ -144,6 +144,18 @@ class Blocklist:
         with self._lock:
             return list(self._compacted.get(tenant, []))
 
+    def meta(self, tenant: str, block_id: str) -> BlockMeta | None:
+        """One block's meta, live (quarantined too) or compacted, or None
+        when this list does not hold the ID."""
+        with self._lock:
+            for m in self._metas.get(tenant, ()):
+                if m.block_id == block_id:
+                    return m
+            for c in self._compacted.get(tenant, ()):
+                if c.meta.block_id == block_id:
+                    return c.meta
+        return None
+
     def apply_poll_results(self, metas, compacted):
         with self._lock:
             self._metas = {t: list(v) for t, v in metas.items()}

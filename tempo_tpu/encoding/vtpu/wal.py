@@ -107,6 +107,10 @@ class VtpuWalBlock:
                 idx = int(os.path.basename(seg)[: -len(SEG_SUFFIX)])
                 with open(seg, "rb") as f:
                     yield idx, fmt.deserialize_batch(f.read())
+            except FileNotFoundError:
+                # cleared under the read: the block was published first
+                # (modules/ingester.py, publish before retire)
+                continue
             except Exception as e:  # corrupt/truncated segment
                 log.warning("wal: dropping corrupt segment %s: %s", seg, e)
 

@@ -471,17 +471,14 @@ class Frontend:
     def _find_traced(self, tenant: str, trace_id: bytes):
         hex_id = trace_id.hex()
         bounds = create_block_boundaries(self.cfg.query_shards)
-        descs = [{"kind": "find", "trace_id": hex_id, "mode": "ingesters"}]
+        # planned_at: every "blocks" job reads the blocklist after this
+        # instant, so the ingesters job also answers for blocks flushed
+        # since (modules/ingester.py, publish before retire)
+        job = {"kind": "find", "trace_id": hex_id, "planned_at": time.time()}
+        descs = [{**job, "mode": "ingesters"}]
         for i in range(len(bounds) - 1):
-            descs.append(
-                {
-                    "kind": "find",
-                    "trace_id": hex_id,
-                    "mode": "blocks",
-                    "block_start": bounds[i],
-                    "block_end": bounds[i + 1],
-                }
-            )
+            descs.append({**job, "mode": "blocks",
+                          "block_start": bounds[i], "block_end": bounds[i + 1]})
         # trace-by-ID is bloom-pruned point work, the protected class:
         # zero-byte estimate = only the tenant concurrency cap applies
         with self._admit(tenant, 0, protected=True, what="find"):

@@ -9,6 +9,27 @@ Array-first twist: a push is grouped by trace once and a live trace is a
 list of row ranges of those grouped batches, so cutting traces to the WAL
 is batch concatenation (one batch a push), and completing a block is the
 engine's sorted-batch write — object trees never appear on the write path.
+
+Publish before retire. A span is readable from at least one tier at every
+instant between its push's acknowledgement and the end of its retention,
+because every mover makes the destination readable before it retires
+the source, and a reader reads one snapshot, or the younger tier first:
+
+- cut (live -> head WAL): the idle traces are picked but left live, their
+  batch is built outside the lock, and one lock hold appends it to the
+  head and removes exactly the cut segments from the live traces; a find
+  snapshots the live traces and the WAL blocks in one hold.
+- flush (WAL block -> backend block): the block is published to the
+  blocklist, moves from `completing` to `flushed` in one hold with the
+  time of its publish, and only then is its WAL cleared. A find's job
+  descriptors carry the time of its plan (`planned_at`); the ingester's
+  job also reads each flushed block published since, which the plan's
+  "blocks" jobs may have missed.
+- compaction (inputs -> output): the output's meta is written before the
+  inputs' meta.json become meta.compacted.json, and their data stays for
+  compacted_retention_s; a job resolves a block ID through
+  TempoDB.block_meta, so it reads the blocks of the snapshot it was cut
+  from whether or not a swap has landed since.
 """
 
 from __future__ import annotations
@@ -145,12 +166,13 @@ class TenantInstance:
         # each cut's delta into registered per-query accumulators
         self.standing = standing
         self.lock = threading.Lock()
+        self._cut_lock = threading.Lock()  # one cut at a time (see the cut)
         self.live: dict[bytes, LiveTrace] = {}
         self.head = db.wal.new_block(tenant)
         self.head_created = time.time()
         self.completing: list = []  # wal blocks cut from head
         self._inflight: set = set()  # block ids being completed right now
-        self.flushed: list = []  # (meta, flushed_at) — cleared after timeout
+        self.flushed: list = []  # (meta, published_at) — cleared after timeout
         self.traces_created = 0
         self.spans_dropped_too_large = 0
         self._pushes = 0  # arrival number of the newest push (_Push.seq)
@@ -268,7 +290,8 @@ class TenantInstance:
             ends = np.cumsum(bounds[:, 1] - bounds[:, 0]).tolist()
             with self.lock:
                 for (key, lt, j, seg), end in zip(found, ends):
-                    if self.live.get(key) is lt and lt.segments[j] is seg:
+                    if (self.live.get(key) is lt and j < len(lt.segments)
+                            and lt.segments[j] is seg):
                         spans = seg[2] - seg[1]
                         lt.segments[j] = (packed, end - spans, end)
                         packed.live_rows += spans
@@ -285,61 +308,65 @@ class TenantInstance:
 
     def _cut_complete_traces_traced(self, now: float | None, immediate: bool) -> int:
         now = now or time.time()
-        cut = []
-        touched: dict[int, _Push] = {}
-        with self.lock:
-            for key, lt in list(self.live.items()):
-                if immediate or now - lt.last_touch > self.cfg.max_trace_idle_s:
-                    cut.append((key, lt))
-                    del self.live[key]
-                    for push, lo, hi in lt.segments:
-                        push.live_rows -= hi - lo
-                        touched[id(push)] = push
-            # pushes this cut took most of, but not all (see _repack)
-            sparse = [p for p in touched.values() if p.sparse()]
-        live_traces_gauge.set(len(self.live), tenant=self.tenant)
-        if not cut:
-            return 0
+        # one cut at a time: a picked trace stays live until its cut's
+        # append, so two cuts must not pick the same segments
+        with self._cut_lock:
+            with self.lock:
+                # (key, trace, segments picked, their spans and bytes)
+                cut = [(key, lt, len(lt.segments), lt.span_count, lt.byte_count)
+                       for key, lt in self.live.items()
+                       if immediate or now - lt.last_touch > self.cfg.max_trace_idle_s]
+                segments = [seg for _, lt, _, _, _ in cut for seg in lt.segments]
+            if not cut:
+                return 0
+            cut_bytes = sum(nbytes for *_, nbytes in cut)
+            # one batch a push (a whole push as it is), so concat remaps one
+            # dictionary a push, not one a trace; built outside the lock
+            batch = SpanBatch.concat(_range_batches(segments)).sorted_by_trace()
+            # accounting: the wal_head pool is charged BEFORE _gov_bytes is
+            # bumped, so a concurrent complete/drop releasing _gov_bytes
+            # never subs bytes whose matching add hasn't landed (Pool.sub
+            # clamps at 0: a premature sub would discard the deficit and
+            # the later add would leak forever)
+            wal_pool = self.governor.pool("wal_head")
+            wal_pool.add(cut_bytes)
+            touched: dict[int, _Push] = {}
+            try:
+                # the WAL is appended here, at the cut; a push
+                # (ingester/append) only reaches the live traces. One hold
+                # appends to the head (which cut_block_if_ready swaps into
+                # completing under the same lock) and retires the cut
+                # segments from the live traces, so a reader's snapshot
+                # holds each span exactly once
+                with self.lock, tracing.span("ingester/wal_append", spans=batch.num_spans):
+                    self.head.append(batch)
+                    self.head._gov_bytes = getattr(self.head, "_gov_bytes", 0) + cut_bytes
+                    # WAL segment identity of this cut (block id + segment
+                    # index): the standing fold below carries it so a
+                    # concurrent rebuild that already replayed the segment
+                    # can dedupe the in-flight fold exactly
+                    seg_key = (f"{self.head.block_id}:"
+                               f"{getattr(self.head, '_next_seg', 1) - 1}")
+                    for key, lt, n, spans, nbytes in cut:
+                        for push, lo, hi in lt.segments[:n]:
+                            push.live_rows -= hi - lo
+                            touched[id(push)] = push
+                        if len(lt.segments) == n:
+                            del self.live[key]
+                        else:  # spans pushed since the pick stay live
+                            del lt.segments[:n]
+                            lt.span_count -= spans
+                            lt.byte_count -= nbytes
+                    # pushes this cut took most of, but not all (see _repack)
+                    sparse = [p for p in touched.values() if p.sparse()]
+                    n_live = len(self.live)
+            except BaseException:
+                wal_pool.sub(cut_bytes)  # nothing appended: the traces stay live
+                raise
+        self.governor.pool("live_traces").sub(cut_bytes)
+        live_traces_gauge.set(n_live, tenant=self.tenant)
         if sparse:
             self._repack(sparse)
-        cut_bytes = sum(lt.byte_count for _, lt in cut)
-        # one batch a push (a whole push as it is), so concat remaps one
-        # dictionary a push, not one a trace
-        batch = SpanBatch.concat(
-            _range_batches([seg for _, lt in cut for seg in lt.segments])
-        ).sorted_by_trace()
-        # append under the lock: cut_block_if_ready swaps self.head into
-        # completing under it, and a completing block may already be mid
-        # write_wal_block/clear() — an unlocked append can land on a block
-        # that is then cleared, silently losing the cut traces (caught by
-        # tests/test_race_stress.py::test_concurrent_push_cut_flush_search)
-        # accounting: the traces left self.live above, so the live pool
-        # gives the bytes back even if the append below fails (a failed
-        # append loses the cut — PR-6 territory — and leaked accounting
-        # would ratchet phantom pressure until pushes are refused).
-        # The wal_head pool is charged BEFORE _gov_bytes is bumped: a
-        # concurrent complete/drop releasing _gov_bytes must never sub
-        # bytes whose matching add hasn't landed (Pool.sub clamps at 0,
-        # so a premature sub would silently discard the deficit and the
-        # later add would leak forever).
-        self.governor.pool("live_traces").sub(cut_bytes)
-        wal_pool = self.governor.pool("wal_head")
-        wal_pool.add(cut_bytes)
-        try:
-            # the WAL is appended here, at the cut; a push (ingester/append)
-            # only reaches the live traces
-            with self.lock, tracing.span("ingester/wal_append", spans=batch.num_spans):
-                self.head.append(batch)
-                self.head._gov_bytes = getattr(self.head, "_gov_bytes", 0) + cut_bytes
-                # WAL segment identity of this cut (block id + segment
-                # index): the standing fold below carries it so a
-                # concurrent rebuild that already replayed the segment
-                # can dedupe the in-flight fold exactly
-                seg_key = (f"{self.head.block_id}:"
-                           f"{getattr(self.head, '_next_seg', 1) - 1}")
-        except BaseException:
-            wal_pool.sub(cut_bytes)  # append failed: nothing to account
-            raise
         # park the just-cut columns device-side under the WAL segment's
         # identity: the standing fold below and live-tail search then
         # evaluate where the data already sits (zero h2d per query).
@@ -379,19 +406,19 @@ class TenantInstance:
             self.head_created = now
             return blk
 
-    def complete_one(self, blk, now: float | None = None):
+    def complete_one(self, blk):
         """One completing WAL block -> backend block; the WAL dir is
-        removed only after the backend write succeeded, so there is no
-        window where the data is visible nowhere (reference:
-        CompleteBlock:308 + handleFlush flush.go:297; single op here
-        because the write already lands in the object store).
+        removed only after the backend write published the block and the
+        block moved from `completing` to `flushed`, so there is no window
+        where the data is visible nowhere (reference: CompleteBlock:308 +
+        handleFlush flush.go:297; single op here because the write
+        already lands in the object store).
 
         Claim-guarded: the synchronous drain (sweep immediate /
         flush_all) and the flush-queue workers can both reach the same
         block; whoever claims it first completes it, the other returns
         None (a double write_wal_block after clear() would overwrite the
         good backend block with an empty one)."""
-        now = now or time.time()
         with self.lock:
             if blk.block_id in self._inflight or blk not in self.completing:
                 return None
@@ -410,12 +437,13 @@ class TenantInstance:
             with self.lock:
                 self._inflight.discard(blk.block_id)
             raise
+        published = time.time()  # the blocklist holds the block from here on
         with self.lock:
             self._inflight.discard(blk.block_id)
             if blk in self.completing:
                 self.completing.remove(blk)
             if meta is not None:
-                self.flushed.append((meta, now))
+                self.flushed.append((meta, published))
         blk.clear()
         self._release_block_accounting(blk)
         if meta is not None:
@@ -454,17 +482,16 @@ class TenantInstance:
         except Exception:
             log.exception("clearing dropped block %s failed", blk.block_id)
 
-    def complete_and_flush(self, now: float | None = None) -> list:
+    def complete_and_flush(self) -> list:
         """Synchronous drain of all completing blocks (deterministic
         test/shutdown path; the background path goes through the
         flush queues)."""
-        now = now or time.time()
         out = []
         with self.lock:
             todo = list(self.completing)
         for blk in todo:
             try:
-                meta = self.complete_one(blk, now)
+                meta = self.complete_one(blk)
                 if meta is not None:
                     out.append(meta)
             except Exception:
@@ -501,23 +528,37 @@ class TenantInstance:
             self._release_block_accounting(blk)
 
     # -- queries over not-yet-backend state ------------------------------
-    def find_trace_by_id(self, trace_id: bytes) -> Trace | None:
+    def find_trace_by_id(self, trace_id: bytes,
+                         planned_at: float | None = None) -> Trace | None:
+        """The trace's spans in the live traces and the WAL, and, with
+        `planned_at` (when the find's plan was cut: its "blocks" jobs
+        read the blocklist after it), in the blocks flushed since then,
+        which those jobs may have read the blocklist too early to see.
+        Without it the caller reads the blocklist itself, afterwards."""
         key = trace_id.rjust(16, b"\x00")[-16:]
-        parts = []
-        with self.lock:
+        with self.lock:  # one snapshot: a cut moves spans in one hold
             lt = self.live.get(key)
             segments = list(lt.segments) if lt else []
+            wal_blocks = [self.head] + list(self.completing)
+        parts = []
         if segments:
             parts.extend(batch_to_traces(SpanBatch.concat(_range_batches(segments))))
         limbs = np.frombuffer(key, dtype=">u4").astype(np.uint32)
-        with self.lock:
-            wal_blocks = [self.head] + list(self.completing)
         for blk in wal_blocks:
             for seg in blk.iter_batches():
                 rows = np.flatnonzero((seg.cols["trace_id"] == limbs[None, :]).all(axis=1))
                 if len(rows):
                     parts.extend(batch_to_traces(seg.select(rows)))
-        return combine_traces(parts)
+        if planned_at is not None:
+            # read after the WAL: a block cleared under the reads above
+            # has moved to `flushed` by now
+            hex_id = key.hex()
+            with self.lock:
+                metas = [m for m, at in self.flushed
+                         if at >= planned_at and m.min_id <= hex_id <= m.max_id]
+            if metas:
+                parts.append(self.db.find_in_blocks(self.tenant, trace_id, metas))
+        return combine_traces([p for p in parts if p is not None])
 
     def live_batches(self) -> list[SpanBatch]:
         """All not-yet-flushed columnar data (for SearchRecent). WAL
@@ -616,10 +657,11 @@ class Ingester:
             raise
         self.instance(tenant).push_segment(data)
 
-    def find_trace_by_id(self, tenant: str, trace_id: bytes) -> Trace | None:
+    def find_trace_by_id(self, tenant: str, trace_id: bytes,
+                         planned_at: float | None = None) -> Trace | None:
         with self.lock:
             inst = self.instances.get(tenant)
-        return inst.find_trace_by_id(trace_id) if inst else None
+        return inst.find_trace_by_id(trace_id, planned_at) if inst else None
 
     def live_batches(self, tenant: str) -> list[SpanBatch]:
         with self.lock:

@@ -12,7 +12,6 @@ from dataclasses import dataclass
 
 import numpy as np
 
-from tempo_tpu.backend.base import NotFound
 from tempo_tpu.encoding.common import SearchRequest, SearchResponse, TraceSearchMetadata
 from tempo_tpu.encoding.vtpu import format as fmt
 from tempo_tpu.model.columnar import SpanBatch
@@ -48,14 +47,18 @@ class Querier:
         return [self.ingester_clients[r.instance_id] for r in reps if r.instance_id in self.ingester_clients]
 
     def find_trace_by_id(self, tenant: str, trace_id: bytes, mode: str = "all",
-                         block_start: str = "0" * 32, block_end: str = "f" * 32):
+                         block_start: str = "0" * 32, block_end: str = "f" * 32,
+                         planned_at: float | None = None):
         """mode: ingesters | blocks | all (reference: querier.go:186 +
-        the frontend's mode param, pkg/api)."""
+        the frontend's mode param, pkg/api). planned_at: when the
+        frontend planned the find (Ingester.find_trace_by_id); "all"
+        reads the blocks itself, after the ingesters."""
         parts = []
         if mode in ("ingesters", "all"):
             for client in self._replica_clients(tenant, trace_id):
                 try:
-                    t = client.find_trace_by_id(tenant, trace_id)
+                    t = client.find_trace_by_id(tenant, trace_id,
+                                                planned_at=planned_at)
                     if t is not None:
                         parts.append(t)
                 except Exception:
@@ -122,16 +125,8 @@ class Querier:
             return self._search_block_batch_cached(tenant, block_ids, req, rc)
         searcher = self.db.mesh_searcher() if not self.external_endpoints else None
         if searcher is not None and len(block_ids) > 1:
-            # only a definitive NotFound (deleted by compaction between
-            # shard planning and execution) skips a block; a transient
-            # meta-read error raises so the worker retries the job
-            metas = []
-            for bid in block_ids:
-                try:
-                    metas.append(self.db.backend.block_meta(tenant, bid))
-                except NotFound:
-                    log.warning("search job: block %s deleted mid-query", bid)
-            if metas and all(m.version == "vtpu1" for m in metas):
+            metas = [self.db.block_meta(tenant, bid) for bid in block_ids]
+            if all(m.version == "vtpu1" for m in metas):
                 blocks = (
                     self.db.encoding_for(m.version).open_block(m, self.db.backend, self.db.cfg.block)
                     for m in metas
@@ -223,13 +218,8 @@ class Querier:
             return []
         searcher = self.db.mesh_searcher() if not self.external_endpoints else None
         if searcher is not None and len(reqs) > 1 and len(block_ids) > 1:
-            metas = []
-            for bid in block_ids:
-                try:
-                    metas.append(self.db.backend.block_meta(tenant, bid))
-                except NotFound:
-                    log.warning("search job: block %s deleted mid-query", bid)
-            if metas and all(m.version == "vtpu1" for m in metas):
+            metas = [self.db.block_meta(tenant, bid) for bid in block_ids]
+            if all(m.version == "vtpu1" for m in metas):
                 blocks = (
                     self.db.encoding_for(m.version).open_block(m, self.db.backend, self.db.cfg.block)
                     for m in metas
@@ -308,12 +298,7 @@ class Querier:
 
         plan = compile_metrics_plan(query, start_s, end_s, step_s,
                                     max_series=max_series, exemplars=exemplars)
-        metas = []
-        for bid in block_ids:
-            try:
-                metas.append(self.db.backend.block_meta(tenant, bid))
-            except NotFound:  # deleted mid-query: benign; other errors
-                log.warning("metrics job: block %s deleted mid-query", bid)
+        metas = [self.db.block_meta(tenant, bid) for bid in block_ids]
         # result cache (tempo_tpu/resultcache): per-block integer-add
         # partials are reusable verbatim because blocks are immutable —
         # this tier outranks the batch tiers below (which fuse blocks
@@ -384,9 +369,7 @@ class Querier:
         acc = make_accumulator(plan)
         for m in metas:
             # per-block sub-accumulator (shared series table), merged
-            # only on success: counts have no dedupe, so a block deleted
-            # mid-evaluation must contribute nothing — its spans live on
-            # in the compaction output that replaced it
+            # only once the block has evaluated whole
             sub = type(acc)(plan, series=acc.series)
 
             def run(meta=m, sub=sub):
@@ -400,11 +383,7 @@ class Querier:
                 sub.stats["inspectedBytes"] += blk.bytes_read
                 sub.stats["decodedBytes"] += getattr(blk, "decoded_bytes", 0)
 
-            try:
-                self.db.guard_block(tenant, m.block_id, run)
-            except NotFound:
-                log.warning("metrics job: block %s deleted mid-query", m.block_id)
-                continue
+            self.db.guard_block(tenant, m.block_id, run)
             acc.counts += sub.merged_counts()
             for k, v in sub.stats.items():
                 acc.stats[k] = acc.stats.get(k, 0) + v
@@ -486,12 +465,7 @@ class Querier:
                 sub.stats["inspectedBytes"] += blk.bytes_read
                 sub.stats["decodedBytes"] += getattr(blk, "decoded_bytes", 0)
 
-            try:
-                self.db.guard_block(tenant, m.block_id, run)
-            except NotFound:
-                log.warning("metrics job: block %s deleted mid-query",
-                            m.block_id)
-                continue
+            self.db.guard_block(tenant, m.block_id, run)
             w = sub.to_wire()
             saved = int(w["stats"].get("inspectedBytes", 0))
             if w["stats"].get("seriesDropped", 0):
@@ -554,12 +528,7 @@ class Querier:
                                       exemplars=exemplars)
                  for q in queries]
         out = [None] * len(queries)
-        metas = []
-        for bid in block_ids:
-            try:
-                metas.append(self.db.backend.block_meta(tenant, bid))
-            except NotFound:
-                log.warning("metrics job: block %s deleted mid-query", bid)
+        metas = [self.db.block_meta(tenant, bid) for bid in block_ids]
         if len(plans) > 1 and metas and all(m.version == "vtpu1"
                                             for m in metas):
             from tempo_tpu import compiled
@@ -602,9 +571,7 @@ class Querier:
                      end_s: int, want: str, by: str = "service") -> dict:
         """One frontend graph job = a batch of backend blocks. Each block
         commits its partial only after evaluating WHOLE (the metrics-path
-        contract: integer partials have no dedupe, so a block deleted
-        mid-scan must contribute nothing — its spans live on in the
-        compaction output that replaced it)."""
+        contract: integer partials have no dedupe)."""
         from tempo_tpu import graph
 
         pipeline = graph.parse_root_filter(q)
@@ -640,11 +607,7 @@ class Querier:
                     else:
                         graph.merge_cp_wire(wire, sub)
                     continue
-            try:
-                meta = self.db.backend.block_meta(tenant, bid)
-            except NotFound:
-                log.warning("graph job: block %s deleted mid-query", bid)
-                continue
+            meta = self.db.block_meta(tenant, bid)
 
             def run(meta=meta):
                 blk = self.db.encoding_for(meta.version).open_block(
@@ -666,11 +629,7 @@ class Querier:
                 sub["stats"] = {**sub["stats"], **stats}
                 return sub
 
-            try:
-                sub = self.db.guard_block(tenant, bid, run)
-            except NotFound:
-                log.warning("graph job: block %s deleted mid-query", bid)
-                continue
+            sub = self.db.guard_block(tenant, bid, run)
             if rc_fp is not None:
                 rc.put(tenant, bid, "graph", rc_fp, sub,
                        bytes_saved=int(sub["stats"].get("inspectedBytes", 0)))

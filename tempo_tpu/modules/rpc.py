@@ -9,7 +9,8 @@ traces, and length-prefixed segments for live-batch transfer.
 
 Endpoints served by a role process (api/server dispatches /rpc/ here):
   POST /rpc/v1/ingester/push            body: segment   (tenant header)
-  GET  /rpc/v1/ingester/trace/{hex}     -> OTLP proto | 404
+  GET  /rpc/v1/ingester/trace/{hex}[/since/{planned_at}]
+                                        -> OTLP proto | 404
   GET  /rpc/v1/ingester/live            -> u32-len-prefixed segments
   POST /rpc/v1/generator/push           body: segment
   POST /rpc/v1/worker/pull              -> {job_id, tenant, desc} | 204
@@ -53,8 +54,10 @@ class RPCHandler:
         if path.startswith("/rpc/v1/ingester/trace/") and method == "GET":
             if self.ingester is None:
                 return 404, "text/plain", b"no ingester in this process"
-            hex_id = path.rsplit("/", 1)[-1]
-            trace = self.ingester.find_trace_by_id(tenant, bytes.fromhex(hex_id.zfill(32)))
+            hex_id, *since = path[len("/rpc/v1/ingester/trace/"):].split("/")
+            planned_at = float(since[1]) if since[:1] == ["since"] else None
+            trace = self.ingester.find_trace_by_id(
+                tenant, bytes.fromhex(hex_id.zfill(32)), planned_at)
             if trace is None:
                 return 404, "text/plain", b"not found"
             from tempo_tpu.receivers import otlp
@@ -133,13 +136,15 @@ class RemoteIngester:
                 ) from e
             raise
 
-    def find_trace_by_id(self, tenant: str, trace_id: bytes):
+    def find_trace_by_id(self, tenant: str, trace_id: bytes,
+                         planned_at: float | None = None):
         from tempo_tpu.backend.httpclient import HTTPError
 
+        since = "" if planned_at is None else f"/since/{planned_at!r}"
         try:
             _, body, _ = self.client.request(
                 "GET",
-                f"/rpc/v1/ingester/trace/{trace_id.hex()}",
+                f"/rpc/v1/ingester/trace/{trace_id.hex()}{since}",
                 headers={"X-Scope-OrgID": tenant},
                 ok=(200,),
             )

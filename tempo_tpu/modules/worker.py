@@ -107,6 +107,7 @@ def _execute_job(querier, tenant: str, desc: dict) -> dict:
             mode=desc.get("mode", "all"),
             block_start=desc.get("block_start", "0" * 32),
             block_end=desc.get("block_end", "f" * 32),
+            planned_at=desc.get("planned_at"),
         )
         if trace is None:
             return {"trace_b64": None}

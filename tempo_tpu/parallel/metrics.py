@@ -100,8 +100,7 @@ class MeshMetricsEvaluator:
         evaluate host-side to slot ids, and slot batches dispatch in
         stacked (W, R) chunks under the process-wide mesh lock.
 
-        Failure domains mirror MeshSearcher.search_blocks: a block
-        deleted mid-query (NotFound) is skipped, but any other read
+        Failure domains mirror MeshSearcher.search_blocks: any read
         error raises — a metrics job must fail loudly and let the
         worker's retry taxonomy / frontend shard budget decide, never
         return silently-reduced counts that look complete. The
@@ -156,17 +155,10 @@ class MeshMetricsEvaluator:
             stats["h2d_bytes"] += stacked.nbytes + wstack.nbytes
             pending.clear()
 
-        from tempo_tpu.backend.base import NotFound
-
         for blk in blocks:
             opened.append(blk)
-            # buffer this block's contributions and commit them only once
-            # the WHOLE block has evaluated: counts are integer adds with
-            # no dedupe, so a block deleted mid-scan (NotFound below)
-            # must contribute nothing — its spans live on in the
-            # compaction output that replaced it, and a half-committed
-            # block would double-count them in a response that carries no
-            # partial flag
+            # buffer this block's contributions and commit them once the
+            # whole block has evaluated
             blk_batches: list = []  # (slots, weights) pairs
             blk_results: list = []  # (res, view) for exemplars
             blk_spans = 0
@@ -206,10 +198,6 @@ class MeshMetricsEvaluator:
                         from tempo_tpu.ops.pallas_kernels import compress_slot_runs
 
                         blk_batches.append(compress_slot_runs(live))
-            except NotFound as e:  # deleted mid-query: benign, skip whole block
-                log.warning("mesh metrics: block %s deleted mid-query: %s",
-                            blk.meta.block_id, e)
-                continue
             except Exception as e:
                 log.warning("mesh metrics: block %s failed: %s",
                             blk.meta.block_id, e)

@@ -315,6 +315,24 @@ class MeshSearcher:
         # memoized at the factory (lru_cache on mesh/n_cols/max_codes)
         return make_sharded_tag_scan_per_shard(self.mesh, n_cols, self.max_codes)
 
+    @staticmethod
+    def _report_blocks(opened, errors, on_block_error, on_block_ok) -> None:
+        """One verdict a block opened (its first error, or ok), then the
+        first error raised: a search never answers without a block it
+        was given."""
+        failed: dict = {}
+        for bad_blk, e in errors:
+            failed.setdefault(bad_blk.meta.block_id, e)
+        for b in opened:
+            bid = b.meta.block_id
+            if bid in failed:
+                if on_block_error is not None:
+                    on_block_error(bid, failed[bid])
+            elif on_block_ok is not None:
+                on_block_ok(bid)
+        if errors:
+            raise errors[0][1]
+
     # -- search ----------------------------------------------------------
     def search_blocks(self, blocks, req, on_block_error=None,
                       on_block_ok=None) -> "object":
@@ -338,9 +356,7 @@ class MeshSearcher:
         on_block_error(block_id, exc) / on_block_ok(block_id) (the
         caller feeds quarantine accounting), and any terminal error
         fails the whole search loudly — the one result this path must
-        never produce is a silently truncated "complete" response.
-        NotFound is the benign deleted-mid-query race and only skips
-        the block."""
+        never produce is a silently truncated "complete" response."""
         import logging
 
         from tempo_tpu.encoding.common import SearchResponse
@@ -484,7 +500,7 @@ class MeshSearcher:
                         for s, (blk, i, rg, preds) in enumerate(chunk):
                             try:
                                 runs = [with_retries(e.runs) for e in unit_encs[s]]
-                            except Exception as e:  # e.g. block deleted mid-query
+                            except Exception as e:
                                 errors.append((blk, e))
                                 log.warning("mesh search: run load failed: %s", e)
                                 unit_runs.append(None)
@@ -550,7 +566,7 @@ class MeshSearcher:
                                     lambda b=blk, j=i, r=rg, n=col_name: self._col(b, j, r, n))
                                 k = min(len(accept), self.max_codes)
                                 codes[s, c, :k] = accept[:k]
-                        except Exception as e:  # e.g. block deleted mid-query
+                        except Exception as e:
                             errors.append((blk, e))
                             log.warning("mesh search: column load failed: %s", e)
                             continue
@@ -609,9 +625,6 @@ class MeshSearcher:
                     continue  # impossible in this block: no more IO for it
                 row_groups = list(with_retries(blk.index).row_groups)
             except Exception as e:
-                # a block deleted between the blocklist snapshot and the
-                # read (NotFound) must not abort the whole tenant search;
-                # anything else is surfaced below
                 errors.append((blk, e))
                 log.warning("mesh search: block %s unreadable: %s", blk.meta.block_id, e)
                 continue
@@ -635,24 +648,7 @@ class MeshSearcher:
         if not done:
             flush(pending)
 
-        from tempo_tpu.backend.base import NotFound
-
-        failed: dict = {}
-        for bad_blk, e in errors:
-            failed.setdefault(bad_blk.meta.block_id, e)
-        for b in opened:
-            bid = b.meta.block_id
-            if bid in failed:
-                # NotFound is neither a strike nor a success: a block
-                # deleted by compaction mid-query is a benign race, not
-                # quarantine evidence (same exemption as guard_block)
-                if on_block_error is not None and not isinstance(failed[bid], NotFound):
-                    on_block_error(bid, failed[bid])
-            elif on_block_ok is not None:
-                on_block_ok(bid)
-        fatal = [e for _, e in errors if not isinstance(e, NotFound)]
-        if fatal:
-            raise fatal[0]
+        self._report_blocks(opened, errors, on_block_error, on_block_ok)
 
         # same result discipline as SearchResponse.merge: newest first,
         # truncated to the limit (dedupe already applied via seen_ids)
@@ -981,22 +977,7 @@ class MeshSearcher:
         if not all(done):
             flush_multi(pending)
 
-        from tempo_tpu.backend.base import NotFound
-
-        failed: dict = {}
-        for bad_blk, e in errors:
-            failed.setdefault(bad_blk.meta.block_id, e)
-        for b in opened:
-            bid = b.meta.block_id
-            if bid in failed:
-                if on_block_error is not None and not isinstance(
-                        failed[bid], NotFound):
-                    on_block_error(bid, failed[bid])
-            elif on_block_ok is not None:
-                on_block_ok(bid)
-        fatal = [e for _, e in errors if not isinstance(e, NotFound)]
-        if fatal:
-            raise fatal[0]
+        self._report_blocks(opened, errors, on_block_error, on_block_ok)
 
         inspected = sum(b.bytes_read for b in opened)
         decoded = sum(getattr(b, "decoded_bytes", 0) for b in opened)

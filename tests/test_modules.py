@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from tempo_tpu.app import App, AppConfig
-from tempo_tpu.backend.base import BlockMeta
+from tempo_tpu.backend.base import BlockMeta, NotFound
 from tempo_tpu.db import DBConfig, _in_shard, find_block_probes
 from tempo_tpu.encoding.common import SearchRequest
 from tempo_tpu.model import synth
@@ -500,6 +500,163 @@ class TestFrontend:
         q.enqueue("t", lambda: None)
         with pytest.raises(TooManyRequests):
             q.enqueue("t", lambda: None)
+
+
+class TestPublishBeforeRetire:
+    """Spans that move between tiers stay readable (modules/ingester.py,
+    publish before retire). Each test forces, with a hook, the one
+    interleaving of a move and a read in which the mover used to retire
+    the source before the destination answered."""
+
+    TENANT = "single-tenant"
+
+    def _app(self, tmp_path):
+        return make_app(tmp_path, frontend=FrontendConfig(hedge_after_s=0),
+                        generator_enabled=False)
+
+    def test_find_between_the_cuts_two_phases(self, tmp_path, monkeypatch):
+        """A find run while the cut builds its batch (the traces picked,
+        the WAL not yet appended) sees every span; a span pushed to a
+        picked trace meanwhile stays live, and the cut takes the rest."""
+        from tempo_tpu.model.columnar import SpanBatch
+
+        app = self._app(tmp_path)
+        try:
+            traces = synth.make_traces(4, seed=71, spans_per_trace=3)
+            app.push_traces(traces)
+            (ing,) = app.ingesters.values()
+            inst = ing.instance(self.TENANT)
+            want = traces[2]
+            late = tr.traces_to_batch([want])
+            late.cols["span_id"] = late.cols["span_id"] ^ np.uint32(0x5A5A5A5A)
+            between = []
+            concat = SpanBatch.concat
+
+            def hooked(batches):
+                if not between:  # the first concat is the cut's build
+                    between.append(None)
+                    between[0] = app.find_trace(want.trace_id)
+                    inst.push_batch(late)
+                return concat(batches)
+
+            monkeypatch.setattr(SpanBatch, "concat", staticmethod(hooked))
+            assert inst.cut_complete_traces(immediate=True) == 4
+            monkeypatch.undo()
+            assert between[0] is not None, "the trace was in neither tier"
+            assert between[0].span_count() == want.span_count()
+            key = want.trace_id.rjust(16, b"\x00")[-16:]
+            assert list(inst.live) == [key]
+            assert inst.live[key].span_count == late.num_spans
+            got = app.find_trace(want.trace_id)
+            assert got.span_count() == want.span_count() + late.num_spans
+        finally:
+            app.shutdown()
+
+    @pytest.mark.parametrize("clear", ["before", "during"])
+    def test_find_planned_before_a_flush_publishes(self, tmp_path, monkeypatch,
+                                                   caplog, clear):
+        """The find's "blocks" jobs read the blocklist before the flush
+        publishes the block; its "ingesters" job runs after the WAL
+        block's clear() ("before") or while clear() removes the segments
+        it listed ("during"). The ingester answers from the block it
+        flushed after the plan."""
+        from tempo_tpu.encoding.vtpu.wal import VtpuWalBlock
+
+        app = self._app(tmp_path)
+        try:
+            traces = synth.make_traces(6, seed=72, spans_per_trace=3)
+            app.push_traces(traces)
+            (ing,) = app.ingesters.values()
+            inst = ing.instance(self.TENANT)
+            inst.cut_complete_traces(immediate=True)
+            (blk,) = [inst.cut_block_if_ready(immediate=True)]
+            want = traces[4]
+            run_jobs = app.frontend._run_jobs
+            segments = VtpuWalBlock._segments
+            armed = []
+
+            def listed_then_cleared(self):
+                out = segments(self)
+                if armed and self.block_id == blk.block_id:
+                    armed.clear()
+                    inst.complete_and_flush()
+                return out
+
+            def ordered(tenant, descs):
+                blocks = [d for d in descs if d["mode"] == "blocks"]
+                ingesters = [d for d in descs if d["mode"] == "ingesters"]
+                results, errors = run_jobs(tenant, blocks)
+                if clear == "before":
+                    inst.complete_and_flush()
+                else:
+                    armed.append(True)
+                r, e = run_jobs(tenant, ingesters)
+                return results + r, errors + e
+
+            monkeypatch.setattr(VtpuWalBlock, "_segments", listed_then_cleared)
+            monkeypatch.setattr(app.frontend, "_run_jobs", ordered)
+            with caplog.at_level("WARNING"):
+                got = app.find_trace(want.trace_id)
+            assert not armed and not inst.completing, "the flush did not run"
+            assert [m.block_id for m, _ in inst.flushed] == [blk.block_id]
+            assert got is not None, "the trace was in neither the WAL nor a block"
+            assert got.span_count() == want.span_count()
+            assert "dropping corrupt segment" not in caplog.text
+        finally:
+            app.shutdown()
+
+    @pytest.mark.parametrize("path", ["serial", "mesh", "compiled"])
+    def test_jobs_cut_before_a_compaction_swap(self, path, monkeypatch):
+        """Search, rate() and quantile_over_time jobs cut from a two-block
+        blocklist and run after the compaction swapped the two blocks
+        for their output answer as the un-compacted store did: no 500,
+        no block skipped."""
+        from tempo_tpu.backend import MockBackend
+        from tempo_tpu.db import TempoDB
+
+        monkeypatch.setenv("TEMPO_TPU_COMPILED", "1" if path == "compiled" else "0")
+        db = TempoDB(DBConfig(backend="mock",
+                              compaction_device_shards=0 if path == "mesh" else 1),
+                     raw_backend=MockBackend())
+        for seed in (81, 82):
+            db.write_batch("t", synth.make_batch(48, 4, seed=seed).sorted_by_trace())
+        snapshot = list(db.blocklist.metas("t"))
+        ids = [m.block_id for m in snapshot]
+        assert (db.mesh_metrics_evaluator() is not None) == (path == "mesh")
+        from tempo_tpu.modules.querier import Querier
+
+        q = Querier(db)
+        base = 1_700_000_000
+        req = SearchRequest(tags={"service.name": synth.SERVICES[1]}, limit=0)
+
+        jobs = {
+            "rate": lambda: q.query_range_blocks(
+                "t", ids, "{} | rate()", base - 60, base + 600, 60),
+            "quantile": lambda: q.query_range_blocks(
+                "t", ids, "{} | quantile_over_time(duration, 0.5, 0.99)",
+                base - 60, base + 600, 60),
+            "search": lambda: q.search_block_batch("t", ids, req),
+        }
+
+        def answer(kind):
+            out = jobs[kind]()
+            if kind == "search":
+                return sorted(t.trace_id_hex for t in out.traces)
+            return out["series"], out["compiledShape"]
+
+        before = {kind: answer(kind) for kind in jobs}
+        assert all(a and a[0] for a in before.values())
+        assert (before["rate"][1] in ("hit", "miss")) == (path == "compiled")
+        db.compactor_driver.compact_blocks("t", snapshot)
+        assert not {m.block_id for m in db.blocklist.metas("t")} & set(ids)
+        for bid in ids:
+            with pytest.raises(NotFound):
+                db.backend.block_meta("t", bid)
+        for kind in jobs:
+            got = answer(kind)
+            if kind != "search":
+                got, before[kind] = got[0], before[kind][0]
+            assert got == before[kind], kind
 
 
 class TestGenerator:

@@ -189,10 +189,48 @@ class TestMeshSearcherEngine:
         assert len(lids) == len(set(lids))
 
     def test_deleted_block_does_not_abort_search(self):
-        """Retention racing a query: one unreadable block is skipped,
-        hits from the others still come back (reference: pool.run_jobs
-        raises only when there are no results at all)."""
+        """Retention racing a query: a job cut before the first phase of
+        the two-phase delete (meta.json -> meta.compacted.json, the block
+        out of the list) still reads that block, with every hit it had
+        (TempoDB.block_meta resolves the compacted meta)."""
+        import time
+
         from tempo_tpu.backend import MockBackend
+        from tempo_tpu.backend.base import CompactedBlockMeta
+        from tempo_tpu.db import DBConfig, TempoDB
+        from tempo_tpu.encoding.common import SearchRequest
+        from tempo_tpu.model import synth
+        from tempo_tpu.model import trace as tr
+        from tempo_tpu.modules.querier import Querier
+
+        db = TempoDB(DBConfig(backend="mock"), raw_backend=MockBackend())
+        traces = []
+        for i in range(4):
+            ts = synth.make_traces(10, seed=300 + i, spans_per_trace=3)
+            db.write_batch("t", tr.traces_to_batch(ts).sorted_by_trace())
+            traces.extend(ts)
+        metas = db.blocklist.metas("t")
+        ids = [m.block_id for m in metas]
+        svc = next(t.batches[0][0]["service.name"] for t in traces
+                   if t.batches[0][0].get("service.name"))
+        req = SearchRequest(tags={"service.name": svc}, limit=0)
+        q = Querier(db)
+        want = sorted(t.trace_id_hex for t in q.search_block_batch("t", ids, req).traces)
+        assert want
+        victim, now = metas[0], time.time()
+        db.backend.mark_block_compacted("t", victim.block_id, now)
+        db.blocklist.update("t", removes=[victim], compacted_adds=[
+            CompactedBlockMeta(meta=victim, compacted_time=now)])
+        got = q.search_block_batch("t", ids, req)
+        assert sorted(t.trace_id_hex for t in got.traces) == want
+
+    def test_cleared_block_fails_the_search(self):
+        """A block whose objects are gone (cleared past its retention)
+        is an error the search surfaces, not a block it leaves out."""
+        import pytest
+
+        from tempo_tpu.backend import MockBackend
+        from tempo_tpu.backend.base import NotFound
         from tempo_tpu.db import DBConfig, TempoDB
         from tempo_tpu.encoding.common import SearchRequest
         from tempo_tpu.model import synth
@@ -200,19 +238,14 @@ class TestMeshSearcherEngine:
 
         raw = MockBackend()
         db = TempoDB(DBConfig(backend="mock"), raw_backend=raw)
-        traces = []
         for i in range(4):
             ts = synth.make_traces(10, seed=300 + i, spans_per_trace=3)
             db.write_batch("t", tr.traces_to_batch(ts).sorted_by_trace())
-            traces.extend(ts)
-        metas = db.blocklist.metas("t")
-        # simulate retention deleting one block's objects out from under us
-        victim = str(metas[0].block_id)
+        victim = str(db.blocklist.metas("t")[0].block_id)
         raw.objects = {k: v for k, v in raw.objects.items() if victim not in str(k)}
-        svc = next(t.batches[0][0]["service.name"] for t in traces
-                   if t.batches[0][0].get("service.name"))
-        got = db.search("t", SearchRequest(tags={"service.name": svc}, limit=0))
-        assert got.traces, "surviving blocks should still produce hits"
+        with pytest.raises(NotFound):
+            db.search("t", SearchRequest(tags={"service.name": synth.SERVICES[1]},
+                                         limit=0))
 
 
 class TestSharedColumnCache:
