@@ -364,7 +364,7 @@ class DeviceTier:
         return res
 
     def offer(self, key, codec: str, arrays: dict, meta: dict | None = None,
-              host_bytes: int = 0, page_keys=None) -> bool:
+              host_bytes: int = 0, page_keys=None, sharding=None) -> bool:
         """Admission path: host numpy arrays of one encoded page form go
         to device HERE (the one h2d this page pays from now on) iff the
         page is in the admission set and fits the pressure-scaled
@@ -372,7 +372,12 @@ class DeviceTier:
 
         page_keys: the (block_id, column, offset) identities backing
         this entry (defaults to [key] when key has that shape); the
-        admission set is consulted per page."""
+        admission set is consulted per page.
+
+        sharding: where the arrays live (a mesh program's operand
+        sharding: a stacked mesh entry is admitted already sharded, so a
+        resident serve ships and reshards nothing); None = the default
+        device."""
         with self._lock:
             if key in self._lru:
                 self._lru.move_to_end(key)
@@ -385,11 +390,15 @@ class DeviceTier:
         nbytes = sum(int(a.nbytes) for a in arrays.values())
         if nbytes > limit or nbytes <= 0:
             return False
+        import jax
         import jax.numpy as jnp
 
         from tempo_tpu.util import devicetiming
 
-        dev = {name: jnp.asarray(a) for name, a in arrays.items()}
+        if sharding is None:
+            dev = {name: jnp.asarray(a) for name, a in arrays.items()}
+        else:
+            dev = jax.device_put(arrays, sharding)
         # the admission copy is a real h2d ship — measured where it
         # happens, so the tier can never LOWER apparent transfer by
         # hiding its own warm-up traffic

@@ -16,7 +16,7 @@ intentionally per-window, not fully replicated.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 RANGE_AXIS = "range"
 WINDOW_AXIS = "window"
@@ -55,3 +55,26 @@ def compaction_mesh(n_devices: int | None = None) -> Mesh:
     import numpy as np
 
     return Mesh(np.asarray(devs[:n]).reshape(1, n), (WINDOW_AXIS, RANGE_AXIS))
+
+
+def mesh_program(step, mesh: Mesh, in_specs: tuple, out_specs):
+    """jit(shard_map(step)) whose operands live in the mesh's sharding.
+
+    The program declares `NamedSharding(mesh, spec)` for every input and
+    output (a committed operand placed elsewhere is refused, not resharded
+    inside the call) and carries its input shardings as
+    `mesh_in_shardings`: `devicetiming.timed_dispatch` ships host operands
+    straight into them, each device receiving only its own slice."""
+
+    def named(spec):
+        return NamedSharding(mesh, spec)
+
+    ins = tuple(named(s) for s in in_specs)
+    outs = jax.tree_util.tree_map(
+        named, out_specs, is_leaf=lambda x: isinstance(x, PartitionSpec))
+    prog = jax.jit(
+        jax.shard_map(step, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=False),
+        in_shardings=ins, out_shardings=outs)
+    prog.mesh_in_shardings = ins
+    return prog

@@ -30,11 +30,12 @@ from jax.sharding import PartitionSpec as P
 
 from tempo_tpu.parallel import accounting
 from tempo_tpu.parallel.accounting import count_dispatch
-from tempo_tpu.parallel.mesh import RANGE_AXIS, WINDOW_AXIS
+from tempo_tpu.parallel.mesh import RANGE_AXIS, WINDOW_AXIS, mesh_program
 from tempo_tpu.parallel.search import dispatch_lock as _dispatch_lock
-from tempo_tpu.util.devicetiming import timed_dispatch
+from tempo_tpu.util.devicetiming import declare_ship_series, timed_dispatch
 
 log = logging.getLogger(__name__)
+declare_ship_series("mesh_bincount")
 
 
 @lru_cache(maxsize=32)
@@ -62,15 +63,7 @@ def make_sharded_bincount(mesh, n_slots: int):
         return local(slots[0, 0], weights[0, 0])[None]
 
     spec = P(WINDOW_AXIS, RANGE_AXIS)
-    return jax.jit(
-        jax.shard_map(
-            step,
-            mesh=mesh,
-            in_specs=(spec, spec),
-            out_specs=P(WINDOW_AXIS),
-            check_vma=False,
-        )
-    )
+    return mesh_program(step, mesh, (spec, spec), P(WINDOW_AXIS))
 
 
 class MeshMetricsEvaluator:

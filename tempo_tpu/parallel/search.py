@@ -30,9 +30,9 @@ from jax.sharding import PartitionSpec as P
 from tempo_tpu.ops import bloom
 from tempo_tpu.parallel import accounting
 from tempo_tpu.parallel.accounting import count_dispatch
-from tempo_tpu.parallel.mesh import RANGE_AXIS, WINDOW_AXIS
+from tempo_tpu.parallel.mesh import RANGE_AXIS, WINDOW_AXIS, mesh_program
 from tempo_tpu.util import metrics
-from tempo_tpu.util.devicetiming import timed_dispatch
+from tempo_tpu.util.devicetiming import declare_ship_series, timed_dispatch
 
 # Serializes mesh-program dispatch across threads. Collective programs
 # (psum inside shard_map) need every participating device to run the
@@ -53,6 +53,7 @@ batched_lanes_total = metrics.counter(
     "tempo_tpu_batched_query_lanes_total",
     "Active query lanes served by fused multi-query scan dispatches",
 )
+declare_ship_series("mesh_rle_scan", "mesh_scan", "batched_rle_scan")
 
 
 @lru_cache(maxsize=32)
@@ -89,15 +90,8 @@ def make_sharded_tag_scan(mesh, n_cols: int, max_codes: int = 64):
         hit, total = local(cols[0, 0], codes, valid[0, 0])
         return hit[None, None], total[None, None]
 
-    return jax.jit(
-        jax.shard_map(
-            step,
-            mesh=mesh,
-            in_specs=(P(WINDOW_AXIS, RANGE_AXIS), P(), P(WINDOW_AXIS, RANGE_AXIS)),
-            out_specs=(P(WINDOW_AXIS, RANGE_AXIS), P(WINDOW_AXIS)),
-            check_vma=False,
-        )
-    )
+    spec = P(WINDOW_AXIS, RANGE_AXIS)
+    return mesh_program(step, mesh, (spec, P(), spec), (spec, P(WINDOW_AXIS)))
 
 
 @lru_cache(maxsize=32)
@@ -122,15 +116,8 @@ def make_sharded_bloom_test(mesh, p: bloom.BloomPlan):
     def step(words, limbs):
         return local(words[0, 0], limbs)[None, None]
 
-    return jax.jit(
-        jax.shard_map(
-            step,
-            mesh=mesh,
-            in_specs=(P(WINDOW_AXIS, RANGE_AXIS), P()),
-            out_specs=P(WINDOW_AXIS, RANGE_AXIS),
-            check_vma=False,
-        )
-    )
+    spec = P(WINDOW_AXIS, RANGE_AXIS)
+    return mesh_program(step, mesh, (spec, P()), spec)
 
 
 @lru_cache(maxsize=32)
@@ -165,15 +152,7 @@ def make_sharded_rle_scan(mesh, n_cols: int, max_codes: int, n_pad: int):
         return hit[None, None], total[None, None]
 
     spec = P(WINDOW_AXIS, RANGE_AXIS)
-    return jax.jit(
-        jax.shard_map(
-            step,
-            mesh=mesh,
-            in_specs=(spec, spec, spec, spec),
-            out_specs=(spec, P(WINDOW_AXIS)),
-            check_vma=False,
-        )
-    )
+    return mesh_program(step, mesh, (spec,) * 4, (spec, P(WINDOW_AXIS)))
 
 
 @lru_cache(maxsize=32)
@@ -213,15 +192,7 @@ def make_sharded_batched_rle_scan(mesh, n_cols: int, max_codes: int,
         return hit[None, None], total[None, None]
 
     spec = P(WINDOW_AXIS, RANGE_AXIS)
-    return jax.jit(
-        jax.shard_map(
-            step,
-            mesh=mesh,
-            in_specs=(spec, spec, spec, spec, spec),
-            out_specs=(spec, P(WINDOW_AXIS)),
-            check_vma=False,
-        )
-    )
+    return mesh_program(step, mesh, (spec,) * 5, (spec, P(WINDOW_AXIS)))
 
 
 @lru_cache(maxsize=32)
@@ -249,15 +220,7 @@ def make_sharded_tag_scan_per_shard(mesh, n_cols: int, max_codes: int = 64):
         return hit[None, None], total[None, None]
 
     spec = P(WINDOW_AXIS, RANGE_AXIS)
-    return jax.jit(
-        jax.shard_map(
-            step,
-            mesh=mesh,
-            in_specs=(spec, spec, spec),
-            out_specs=(spec, P(WINDOW_AXIS)),
-            check_vma=False,
-        )
-    )
+    return mesh_program(step, mesh, (spec,) * 3, (spec, P(WINDOW_AXIS)))
 
 
 class MeshSearcher:
@@ -472,6 +435,7 @@ class MeshSearcher:
                 if unit_encs is not None:
                     from tempo_tpu.encoding.vtpu.colcache import shared_device_tier
 
+                    scan = make_sharded_rle_scan(self.mesh, n_cols, self.max_codes, pad)
                     tier = shared_device_tier()
                     pkeys = tuple(tuple(e.resident_key() for e in row)
                                   for row in unit_encs)
@@ -540,12 +504,12 @@ class MeshSearcher:
                                         "lengths": dev_lengths},
                                        meta={"run_pad": run_pad},
                                        host_bytes=values.nbytes + lengths.nbytes,
-                                       page_keys=[k for row in pkeys for k in row])
+                                       page_keys=[k for row in pkeys for k in row],
+                                       sharding=scan.mesh_in_shardings[0])
                             got = tier.get(skey)
                             if got is not None:
                                 dev_values = got.arrays["values"]
                                 dev_lengths = got.arrays["lengths"]
-                    scan = make_sharded_rle_scan(self.mesh, n_cols, self.max_codes, pad)
                     args = (
                         dev_values,
                         dev_lengths,
@@ -798,6 +762,8 @@ class MeshSearcher:
             with clock.phase("stack"):
                 n_cols = max(1, max(len(u[6]) for u in units))
                 pad = self.bucket_for(max(u[2].n_spans for u in units))
+                scan = make_sharded_batched_rle_scan(
+                    self.mesh, n_cols, self.max_codes, batch, pad)
                 pkeys = tuple(tuple(e.resident_key() for e in u[5]) for u in units)
                 skey = ("mesh_stack", pkeys, n_cols, pad)
                 res = tier.get(skey) if tier is not None else None
@@ -844,7 +810,8 @@ class MeshSearcher:
                                    {"values": dev_values, "lengths": dev_lengths},
                                    meta={"run_pad": run_pad},
                                    host_bytes=values.nbytes + lengths.nbytes,
-                                   page_keys=pkeys_flat)
+                                   page_keys=pkeys_flat,
+                                   sharding=scan.mesh_in_shardings[0])
                         got = tier.get(skey)
                         if got is not None:
                             dev_values = got.arrays["values"]
@@ -853,8 +820,6 @@ class MeshSearcher:
                 for s, u in enumerate(units):
                     if loaded[s]:
                         valid[s, : u[2].n_spans] = True
-                scan = make_sharded_batched_rle_scan(
-                    self.mesh, n_cols, self.max_codes, batch, pad)
             shipped_payload = isinstance(dev_values, np.ndarray)
             first_dispatch = True
             for g0 in range(0, nq, batch):

@@ -10,7 +10,12 @@ pallas/mesh dispatch site wraps its call in
 1. SHIPS host-resident (numpy) argument leaves to the device itself,
    timed separately, so the waterfall's `transfer` stage is real
    measurement, not an estimate. Leaves that are already device arrays
-   ship nothing and are counted as `resident`.
+   ship nothing and are counted as `resident`. A mesh program
+   (`parallel.mesh.mesh_program`) declares its operands' shardings:
+   its host leaves go in one `device_put` straight into them, each
+   device receiving its own slice, and a device leaf placed elsewhere
+   is moved there explicitly at the same seam; every other program's
+   leaves go to the default device.
 2. Times the remaining dispatch wall clock INCLUDING
    `jax.block_until_ready` (an async dispatch that hasn't materialized
    hasn't been paid for yet) as the `kernel` stage. transfer + kernel
@@ -27,6 +32,9 @@ pallas/mesh dispatch site wraps its call in
       direction: h2d (host arrays shipped), d2h (result bytes
       fetched home), resident (args already on device — counted,
       never re-shipped)
+  tempo_tpu_device_ship_leaves_total{kernel,placement}      counter
+      one count a host leaf shipped: placement sharded (into a mesh
+      program's declared sharding) or default (the default device)
 
 and, when a query's StageTimings accumulator is active, folds times into
 its `transfer`/`kernel` stages + dispatch count, and charges the active
@@ -71,6 +79,12 @@ transfer_bytes_total = metrics.counter(
     "Bytes crossing (h2d|d2h) or parked at (resident) the host<->device "
     "boundary per host-level dispatch, by direction and kernel label",
 )
+ship_leaves_total = metrics.counter(
+    "tempo_tpu_device_ship_leaves_total",
+    "Host array leaves shipped by a host-level dispatch, by kernel label "
+    "and placement: sharded (straight into a mesh program's declared "
+    "sharding) or default (the default device)",
+)
 transfer_avoided_bytes_total = metrics.counter(
     "tempo_tpu_device_transfer_bytes_avoided_total",
     "H2D bytes NOT moved because the scan was served from the "
@@ -109,6 +123,14 @@ def count_transfer(kernel: str, h2d: int = 0, d2h: int = 0,
     moved = h2d + d2h
     if moved:
         usage.charge("transfer_bytes", moved)
+
+
+def declare_ship_series(*kernels: str) -> None:
+    """Publish both placements of each kernel's ship count at 0, so a
+    window in which the kernel ran reads 0 rather than nothing."""
+    for kernel in kernels:
+        for placement in ("sharded", "default"):
+            ship_leaves_total.inc(0, kernel=kernel, placement=placement)
 
 
 def count_avoided(kernel: str, nbytes: int) -> None:
@@ -171,6 +193,10 @@ def timed_dispatch(kernel: str, fn, *args, ship: bool = True, **kwargs):
     device HERE (timed as the `transfer` stage, sized as h2d) and fn
     receives device arrays — callers pass host arrays and drop their own
     jnp.asarray conversions. Device-array leaves are counted `resident`.
+    Where fn declares `mesh_in_shardings` (one a positional argument),
+    every host leaf goes in ONE device_put into its sharding, and a
+    device leaf whose sharding differs is moved there in the same put,
+    so the program never reshards an operand inside the call.
     ship=False: for host-side wrapper fns that need numpy inputs (they
     convert internally); movement is sized from the pytrees but the
     transfer clock stays at zero, so all time lands in `kernel` exactly
@@ -193,15 +219,18 @@ def timed_dispatch(kernel: str, fn, *args, ship: bool = True, **kwargs):
         jax = _get_jax()
         if args or kwargs:
             shipped: list = []
+            n_host = 0  # host leaves shipped
+            shardings = getattr(fn, "mesh_in_shardings", None) if ship else None
 
             def put(leaf):
-                nonlocal h2d, resident
+                nonlocal h2d, resident, n_host
                 if isinstance(leaf, np.ndarray):
                     h2d += leaf.nbytes
                     if not ship:
                         return leaf
                     import jax.numpy as jnp
 
+                    n_host += 1
                     dev = jnp.asarray(leaf)
                     shipped.append(dev)
                     return dev
@@ -209,14 +238,46 @@ def timed_dispatch(kernel: str, fn, *args, ship: bool = True, **kwargs):
                     resident += _nbytes_of(leaf)
                 return leaf
 
+            def place() -> tuple:
+                # ONE device_put a dispatch: the host leaves, and any device
+                # leaf outside its declared sharding, each into its sharding
+                nonlocal h2d, resident, n_host
+                moves = []
+                for i, (leaf, sharding) in enumerate(zip(args, shardings, strict=True)):
+                    if isinstance(leaf, np.ndarray):
+                        h2d += leaf.nbytes
+                        n_host += 1
+                    elif isinstance(leaf, jax.Array):
+                        resident += _nbytes_of(leaf)
+                        if leaf.sharding.is_equivalent_to(sharding, leaf.ndim):
+                            continue
+                    else:
+                        continue
+                    moves.append(i)
+                placed = list(args)
+                if moves:
+                    devs = jax.device_put([args[i] for i in moves],
+                                          [shardings[i] for i in moves])
+                    for i, dev in zip(moves, devs):
+                        placed[i] = dev
+                    shipped.extend(devs)
+                return tuple(placed)
+
             t_ship = time.perf_counter()
             with profiling.annotation(f"transfer/{kernel}") if ann else profiling.NULL_CONTEXT:
-                args, kwargs = jax.tree_util.tree_map(put, (args, kwargs))
+                if shardings is None:
+                    args, kwargs = jax.tree_util.tree_map(put, (args, kwargs))
+                else:
+                    args = place()
                 if shipped:
                     # the ship isn't paid for until it materializes; closing
                     # the clock here keeps transfer EXCLUSIVE of kernel
                     jax.block_until_ready(shipped)
                     transfer_s = time.perf_counter() - t_ship
+            if n_host:
+                ship_leaves_total.inc(
+                    n_host, kernel=kernel,
+                    placement="default" if shardings is None else "sharded")
         out = fn(*args, **kwargs)
         # never raises for plain numpy/scalar/pytree results, so any
         # exception here is a REAL device failure (faulted kernel, OOM)
