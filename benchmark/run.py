@@ -223,9 +223,9 @@ class Run:
                     if entry["op"] == "push":
                         self.warm_spans += rec.req.spans
         # then all clients at once: concurrent queries are folded in batches, whose
-        # shapes a single client never meets
-        burst = [tr.Client(90 + i, self.args.seed, self.src, self.child.port, [], role=role)
-                 for i, role in enumerate(self.client_roles)]
+        # shapes a single client never meets; a paced role arrives at its rate
+        burst = tr.role_threads(self.roles, 90, self.args.seed, self.src, self.child.port,
+                                [[] for _ in self.client_roles])
         for b in burst:
             b.stop_at = time.perf_counter() + self.traffic.get("warmup_burst_s", 0)
             b.start()
@@ -291,11 +291,10 @@ class Run:
         traced = bool(self.args.trace)
         done = threading.Event() if traced else None
         logs = [[] for _ in self.client_roles]
-        clients = [tr.Client(i, self.args.seed, self.src, self.child.port, logs[i], done, role)
-                   for i, role in enumerate(self.client_roles)]
+        clients = tr.role_threads(self.roles, 0, self.args.seed, self.src, self.child.port, logs, done)
         cap: dict = {}
         before, files_before = self.child.metrics(), self.cache_files()
-        t0 = time.perf_counter()
+        t0, wall_t0 = time.perf_counter(), time.time()
         for c in clients:
             c.stop_at = t0 + seconds
             c.start()
@@ -325,9 +324,10 @@ class Run:
         records = sorted((r for log in logs for r in log), key=lambda r: r.t0)
         for r in records:
             r.req.body = None  # the bytes are on the server now
-        return {"records": records, "t0": t0, "seconds": seconds, "t_end": t_end,
-                "scrapes": (before, after), "cache_files": (files_before, files_after),
-                "capture": cap}
+        return {"records": records, "t0": t0, "wall_t0": wall_t0, "seconds": seconds,
+                "t_end": t_end, "scrapes": (before, after),
+                "cache_files": (files_before, files_after), "capture": cap,
+                "pacers": [c for c in clients if isinstance(c, tr.Pacer)]}
 
     # -- after the window -----------------------------------------------------
     def readback(self, records: list) -> dict:
@@ -422,14 +422,16 @@ class Run:
         self.warm_up()
         setup_s = time.perf_counter() - T_PROCESS
         say(f"set-up {setup_s:.1f}s; window of {args.seconds}s, "
-            + " + ".join(f"{role['clients']} {role['name']}" for role in self.roles)
-            + f", closed-loop, trace={args.trace}")
+            + " + ".join(f"{role['clients']} {role['name']} " + (
+                f"paced at {role['arrival_rate_per_s']}/s" if "arrival_rate_per_s" in role
+                else "closed-loop") for role in self.roles) + f", trace={args.trace}")
 
         w = self.window()
         records = w["records"]
         for r in [r for r in records if not r.ok][:10]:  # beside the server's log, they say why
             say(f"not as expected: {r.req.op} -> {r.status} (expected {r.req.expect}) "
                 f"{r.t0 - w['t0']:.2f}s into the window, after {r.t1 - r.t0:.2f}s: {r.req.path[:120]}")
+        paced = self.paced_account(w)
         status = self.child.get_json("/status/device")["backend"]
         peak = max((d.get("peak_bytes_in_use") or 0 for d in status["devices"]), default=0)
         say(f"device memory peak: {peak} bytes")
@@ -441,6 +443,8 @@ class Run:
         numbers["log_errors"] = len(errors)
         say(f"server exit {numbers['server_exit']}; {len(errors)} ERROR lines in its log"
             + "".join("\n[bench]   log: " + e[:300] for e in errors[:5]))
+        if self.config.get("compaction_in_run"):
+            self.say_compaction_jobs(w)
 
         # the reference, once the window has closed and the server is gone
         t0 = time.perf_counter()
@@ -474,8 +478,57 @@ class Run:
             result["breakdown"] = {  # an operation's name is its whole HLO line: cut it
                 "device_ops": [[n[:160], v] for n, v in trace["device_ops"]],
                 "idle_gaps": [[n[:160], v] for n, v in trace["idle_gaps"]]}
+        if paced:
+            result["paced"] = paced
         result["compared"] = compared
         return result
+
+    def paced_account(self, w: dict) -> dict:
+        """Each paced role's arrivals over the window (a traced run's until the
+        capture was back): offered rate, sent, dropped (no free client), missed
+        (the pacer woke after the next was due), its requests' p50 and p95 from
+        each send. Neither a dropped nor a missed arrival is a request: they
+        count here, not in `attempted` or `failed`."""
+        out = {}
+        for p in w["pacers"]:
+            ms = [(r.t1 - r.t0) * 1000.0 for r in p.records]
+            out[p.clients[0].role["name"]] = {
+                "offered_per_s": p.rate, "sent": p.arrived - p.dropped - p.missed,
+                "dropped": p.dropped, "missed": p.missed,
+                "p50_ms": percentile(ms, 50) if ms else None,
+                "p95_ms": percentile(ms, 95) if ms else None}
+        for name, a in out.items():
+            say(f"paced role {name}: " + ", ".join(f"{k}={v}" for k, v in a.items()))
+        return out
+
+    def say_compaction_jobs(self, w: dict) -> None:
+        """Where the compactor's jobs ended, from the blocks on disk: each
+        compacted block's `compacted_time` against the window's start, and
+        whether it held the loaded store or the pushes of the run."""
+        root = self.config["server"]["storage"]["trace"]["backend_path"].replace(
+            "{dir}", self.workdir)
+        jobs: dict = {}
+        flushed = 0
+        for tenant in sorted(os.listdir(root)) if os.path.isdir(root) else []:
+            for block in os.listdir(os.path.join(root, tenant)):
+                d = os.path.join(root, tenant, block)
+                name = "meta.compacted.json" if os.path.exists(
+                    os.path.join(d, "meta.compacted.json")) else "meta.json"
+                try:
+                    with open(os.path.join(d, name)) as f:
+                        meta = json.load(f)
+                except (OSError, ValueError):
+                    continue  # a block still being written
+                pushed = meta["start_time"] >= self.push_base_s and "push_age_s" in self.traffic
+                flushed += pushed and meta["compaction_level"] == 0
+                if "compacted_time" in meta:
+                    at = round(meta["compacted_time"] - w["wall_t0"], 1)
+                    jobs.setdefault(at, []).append(
+                        f"{'pushed' if pushed else 'store'} L{meta['compaction_level']}")
+        say("compaction jobs, ended at (s from the window's start): "
+            + ("; ".join(f"{at:+.1f} {', '.join(sorted(b))}" for at, b in sorted(jobs.items()))
+               or "none")
+            + f"; level-0 blocks of the run's pushes flushed: {flushed}")
 
     def dry_send(self, pool: list) -> None:
         """The generator's ceiling: bodies made new a second, nothing sent."""
@@ -497,8 +550,10 @@ class Run:
                 raise BenchFailure(f"role {role['name']!r} got no answer inside the window")
         values = {"setup_s": setup_s}
         if "push" in self.ops:
-            # back-to-back writers hold the server at capacity: the rate is the metric, the
-            # pushes' tail swings with where the flushes fall ("per operation" prints it)
+            # what the server acknowledged: closed-loop writers hold it at capacity, paced
+            # ones offer a stated rate and this reads what was delivered of it (the dropped
+            # arrivals say what was not); the pushes' tail swings with where the flushes
+            # fall ("per operation" prints it)
             values["ingest_spans_per_s"] = sum(
                 r.req.spans for r in in_window if r.req.op == "push") / seconds
         if self.ops - {"push"}:  # beside the writers or alone: over the records that are no push

@@ -1,11 +1,12 @@
-"""The mixed cell of ISSUE 37, handed to run.py without touching
-BENCHMARK.json: `fixtures/mixed.json` (2 writers beside 4 readers) under
-`allinone-k6` (no job inside a run) or `fixtures/allinone-compacting.json`
-(upstream's 30 s cycle, `compaction_in_run`). The tests drive it through
-`load_cell`; the builder's rehearsal on the chip ran it as
+"""The mixed cell, handed to run.py without touching BENCHMARK.json:
+`fixtures/mixed.json` (4 writers paced at 2 pushes/s beside 4 closed-loop
+readers) under `allinone-k6` (no job inside a run) or
+`fixtures/allinone-compacting.json` (upstream's 30 s cycle,
+`compaction_in_run`). The tests drive it through `load_cell`; on the chip
+the rehearsal runs it as
 
-    python3 benchmark/tests/mixed_cell.py --config allinone-compacting \\
-        --seed <n> --seconds 40 --trace <0|1>
+    python3 benchmark/tests/mixed_cell.py --seed <n> --seconds 40 --trace <0|1>
+    python3 benchmark/tests/mixed_cell.py --config allinone-k6 --seed <n> ...
 
 (every other argument is run.py's).
 """
@@ -33,7 +34,7 @@ def load_cell(config_name: str = "allinone-compacting"):
     with open(os.path.join(run.ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     cell = {"name": NAME, "config": config_name, "traffic": "mixed", "chips": 1,
-            "why": "2 writers + 4 readers in one window (a test fixture)"}
+            "why": "4 paced writers + 4 readers in one window (a test fixture)"}
     bench["workloads"].append(cell)
     for m in bench["end_to_end"]:
         if m["name"] in END_TO_END:

@@ -91,6 +91,25 @@ def test_per_layer_metrics_have_readers_and_move_what_their_cells_report():
     assert set(os.listdir(os.path.join(BENCH, "metrics"))) == listed
 
 
+ROLE_KEYS = {"name", "clients", "deck", "deck_size", "warmup_per_op", "arrival_rate_per_s"}
+
+
+def test_roles_hold_only_the_keys_the_generator_reads():
+    paths = [os.path.join(BENCH, "traffic", f)
+             for f in sorted(os.listdir(os.path.join(BENCH, "traffic")))]
+    paced = []
+    for path in paths + [os.path.join(BENCH, "tests", "fixtures", "mixed.json")]:
+        with open(path) as f:
+            traffic = json.load(f)
+        for role in traffic.get("roles", []):
+            assert {"name", "clients", "deck", "deck_size"} <= set(role) <= ROLE_KEYS, path
+            if "arrival_rate_per_s" in role:  # arrivals a second, k6's constant-arrival-rate
+                rate = role["arrival_rate_per_s"]
+                assert isinstance(rate, (int, float)) and rate > 0, path
+                paced.append(os.path.basename(path))
+    assert "mixed.json" in paced  # the fixture's writers
+
+
 def test_files_under_paths_are_named_from_name_characters():
     for dirpath, dirs, files in os.walk(BENCH):
         dirs[:] = [d for d in dirs if d != "__pycache__"]

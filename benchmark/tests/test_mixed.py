@@ -1,21 +1,16 @@
-"""One window that holds writers and readers (ISSUE 37), on the fixture
-cell of mixed_cell.py, which no `workloads` entry names: whole runs
-rehearsed on the CPU backend, the timed path broken underneath, and the
-comparison of a store that may have been compacted, without a server.
-
-The whole runs here are NOT the fixture as its files state it: `run_mixed`
-sets the configuration's `cycle_s` to 3600 and `no_idle_cut` raises the
-traffic's `max_trace_idle_s` from 1 to 3600, because the program answers
-wrongly otherwise (PERF.md section 7, faults 1 and 2: an acknowledged trace
-that answers 404 for a moment; ROADMAP B-I.11). The PR that mends those two
-has to delete both overrides, so that the fixture is asserted `correct` as
-committed."""
+"""One window that holds writers and readers, on the fixture cell of
+mixed_cell.py, which no `workloads` entry names: whole runs rehearsed on
+the CPU backend with the fixture as its files state it (the compactor's
+30 s cycle, traces cut after 1 s idle, writers paced at 2 pushes/s), the
+timed path broken underneath, and the comparison of a store that may have
+been compacted, without a server."""
 
 import argparse
 import copy
 import functools
 import json
 import re
+import time
 
 import numpy as np
 import pytest
@@ -33,14 +28,9 @@ DRY = argparse.Namespace(cpu_dry_run=True, dry_traces=256, seed=1, seconds=5.0, 
                          keep_dir=False)  # what run.Run reads of the command line
 
 
-def run_mixed(monkeypatch, capsys, trace=0, exchange=None, tweak=None) -> tuple:
+def run_mixed(monkeypatch, capsys, trace=0, exchange=None) -> tuple:
     """(result line, stdout) of one rehearsal of the fixture cell in this process."""
     bench, cell, config, traffic = mixed_cell.load_cell("allinone-compacting")
-    # no job inside a rehearsal: one that falls between a query's plan and its run answers
-    # 500 (ROADMAP B-I.11), and whether one falls into these few seconds is the machine's
-    config["server"]["storage"]["trace"]["compaction"]["cycle_s"] = 3600
-    if tweak:
-        tweak(traffic)
     monkeypatch.setattr(run, "load_cell", lambda workload: (bench, cell, config, traffic))
     if exchange:
         monkeypatch.setattr(tr.Client, "exchange", exchange)
@@ -51,25 +41,22 @@ def run_mixed(monkeypatch, capsys, trace=0, exchange=None, tweak=None) -> tuple:
     return json.loads(out.strip().splitlines()[-1]), out
 
 
-def no_idle_cut(traffic: dict) -> None:
-    """The program takes a trace out of the live map before it appends it to
-    the head block (`ingester.py::_cut_complete_traces_traced`), so a find in
-    between answers 404 for an acknowledged trace (PERF.md, section 7). With
-    the fixture's `max_trace_idle_s: 1` a rehearsal meets that now and then."""
-    traffic["server_overlay"]["ingester"]["max_trace_idle_s"] = 3600
-
-
 @pytest.mark.parametrize("trace", [0, 1])
 def test_the_fixture_cell_reports_both_families_and_is_correct(monkeypatch, capsys, trace):
-    doc, out = run_mixed(monkeypatch, capsys, trace, tweak=no_idle_cut)
+    doc, out = run_mixed(monkeypatch, capsys, trace)
     compared = doc["compared"]
     assert doc["correct"], compared
     for number in ("readback_wrong", "count_wrong", "search_wrong", "traceql_wrong",
                    "find_wrong", "span_count_gap", "unanswered"):
         assert compared[number] == {"value": 0, "limit": 0}, number
-    assert "find_acked n=" in out and "push n=" in out and "2 writer + 4 reader" in out
+    assert "find_acked n=" in out and "push n=" in out
+    assert "4 writer paced at 2/s + 4 reader closed-loop" in out
     compactor = next(ln for ln in out.splitlines() if "compactor in the window" in ln)
-    assert "since the server's start" in compactor and "_runs_total" not in compactor  # no job
+    assert "since the server's start" in compactor
+    assert "compaction jobs, ended at (s from the window's start): " in out  # a job or none
+    arrivals = doc["paced"]["writer"]
+    assert arrivals["offered_per_s"] == 2
+    assert arrivals["sent"] + arrivals["dropped"] + arrivals["missed"] >= 10
     if trace == 0:
         assert set(doc["metrics"]) == {"ingest_spans_per_s", "queries_per_s", "query_p95_ms",
                                        "setup_s"}
@@ -77,6 +64,7 @@ def test_the_fixture_cell_reports_both_families_and_is_correct(monkeypatch, caps
         # the rates are over the same window, each over its own records
         n = {op: int(k) for op, k in re.findall(r"(\w+) n=(\d+)", out)}
         assert doc["metrics"]["ingest_spans_per_s"]["value"] <= n["push"] * 1024 / 5
+        assert doc["metrics"]["ingest_spans_per_s"]["value"] <= 2 * 1024  # delivered <= offered
         assert doc["metrics"]["queries_per_s"]["value"] <= (sum(n.values()) - n["push"]) / 5
     else:  # a reader per query and a reader per push each found its own count
         assert doc["metrics"]["host_stage_ms.read"]["value"] > 0
@@ -126,6 +114,23 @@ def test_a_broken_timed_path_is_not_correct(monkeypatch, capsys, fault, number, 
     assert doc["compared"][number]["value"] > doc["compared"][number]["limit"]
     if inside:  # seen by a find of the window, before the readback after it
         assert inside in out
+
+
+def slow_pushes(self, req, headers):
+    """A server that takes 2.5 s a push: longer than 4 writers / 2 arrivals a second."""
+    if req.op == "push":
+        time.sleep(2.5)
+    return REAL(self, req, headers)
+
+
+def test_a_server_slower_than_the_rate_drops_arrivals(monkeypatch, capsys):
+    doc, out = run_mixed(monkeypatch, capsys, exchange=slow_pushes)
+    arrivals = doc["paced"]["writer"]
+    assert arrivals["dropped"] > 0 and arrivals["p50_ms"] >= 2500
+    assert doc["metrics"]["ingest_spans_per_s"]["value"] < arrivals["offered_per_s"] * 1024
+    # a dropped arrival is no request: it counts under `paced` alone; what was sent is right
+    assert doc["failed"] == 0 and doc["correct"], doc["compared"]
+    assert f"dropped={arrivals['dropped']}" in out
 
 
 # -- the comparison, without a server ---------------------------------------------
@@ -279,7 +284,7 @@ def test_clients_are_numbered_through_the_roles_and_dealt_their_own_deck():
                                   "rate_total", "rate_by_service", "quantiles"}
     r = run.Run(DRY, {}, {"name": "x"}, config, traffic)
     try:
-        assert [role["name"] for role in r.client_roles] == ["writer"] * 2 + ["reader"] * 4
+        assert [role["name"] for role in r.client_roles] == ["writer"] * 4 + ["reader"] * 4
     finally:
         r.close()
     blocks = two_blocks()
@@ -289,7 +294,7 @@ def test_clients_are_numbered_through_the_roles_and_dealt_their_own_deck():
                     {"single-tenant": np.array(corpus.trace_hex(blocks[0]), dtype=object)}, pool)
     roles = tr.roles_of(traffic)
     writer = tr.Client(0, 1, src, 0, [], role=roles[0])
-    reader = tr.Client(2, 1, src, 0, [], role=roles[1])
+    reader = tr.Client(4, 1, src, 0, [], role=roles[1])
     assert {writer.next_request().op for _ in range(8)} == {"push"}
     dealt = [reader.next_request() for _ in range(200)]
     ops = [r.op for r in dealt]

@@ -14,13 +14,20 @@ rate_by_service, quantiles, push.
 
 A file may split its clients into `roles` (writers beside readers), each
 closed-loop over a deck of its own; without the key the file is one role.
+A role with `arrival_rate_per_s` is paced instead: k6's
+`constant-arrival-rate` executor (`timeUnit` 1 s), its clients the
+`preAllocatedVUs`; an arrival that finds every client busy is dropped and
+counted, as k6 counts `dropped_iterations`, and one the pacer wakes for
+only after the next is due is skipped and counted (`Pacer`).
 """
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import http.client
 import json
+import queue
 import threading
 import time
 import urllib.parse
@@ -76,8 +83,8 @@ def deal(deck: list, size: int) -> list:
 
 def roles_of(traffic: dict) -> list:
     """The file's roles, `{name, clients, deck, deck_size}` each (and
-    `warmup_per_op`, where a role has its own). A file without `roles` is
-    one role made of its top-level keys."""
+    `warmup_per_op` or `arrival_rate_per_s`, where a role has its own). A
+    file without `roles` is one role made of its top-level keys."""
     if "roles" in traffic:
         return traffic["roles"]
     return [{"name": "clients", **{k: traffic[k] for k in ("clients", "deck", "deck_size")}}]
@@ -301,6 +308,101 @@ class Client(threading.Thread):
             self.records.append(self.send(self.next_request()))
         if self._conn is not None:
             self._conn.close()
+
+
+class PacedClient(Client):
+    """A client of a paced role: sends the next request of its own deal,
+    drawn as a closed-loop client of its number draws it, each time its
+    role's Pacer hands it an arrival."""
+
+    def __init__(self, *args, **kwargs):
+        super().__init__(*args, **kwargs)
+        self.arrivals: queue.SimpleQueue = queue.SimpleQueue()  # True: an arrival; None: stop
+        self.pacer: Pacer | None = None
+
+    def run(self) -> None:
+        while self.arrivals.get() is not None:
+            self.records.append(self.send(self.next_request()))
+            self.pacer.done(self)
+        if self._conn is not None:
+            self._conn.close()
+
+
+class Pacer(threading.Thread):
+    """One paced role: arrivals at t0 + k / rate for k = 0, 1, ... until
+    `stop_at` (or, in a traced run, until `hold` is set), each handed to
+    the client that has been free longest. An arrival that finds every
+    client busy is dropped and counted (`dropped`); one that the pacer
+    itself wakes for only after the next arrival is due, as when the
+    interpreter or a capture holds it, is skipped and counted (`missed`).
+    So no arrival leaves later than the next one's due time, and no two
+    leave in a burst. Starts its clients and, after the last arrival,
+    waits for the requests in flight."""
+
+    def __init__(self, clients: list, rate: float, hold: threading.Event | None = None):
+        super().__init__(daemon=True, name=f"pacer-{clients[0].role['name']}")
+        self.clients, self.rate, self.hold = clients, float(rate), hold
+        self.clock, self.sleep = time.perf_counter, time.sleep  # a test hands in fakes
+        self.stop_at = 0.0
+        self.arrived = self.dropped = self.missed = 0
+        self._idle = collections.deque(clients)
+        self._lock = threading.Lock()
+        for c in clients:
+            c.pacer = self
+
+    @property
+    def records(self) -> list:
+        return [r for c in self.clients for r in c.records]
+
+    def done(self, client: PacedClient) -> None:
+        with self._lock:
+            self._idle.append(client)
+
+    def run(self) -> None:
+        for c in self.clients:
+            c.start()
+        t0 = self.clock()
+        try:
+            while True:
+                due = t0 + self.arrived / self.rate
+                if due >= self.stop_at and (self.hold is None or self.hold.is_set()):
+                    return
+                wait = due - self.clock()
+                if wait > 0:
+                    self.sleep(wait)
+                self.arrived += 1
+                if self.clock() >= t0 + self.arrived / self.rate:  # the next one is due
+                    self.missed += 1
+                    continue
+                with self._lock:
+                    client = self._idle.popleft() if self._idle else None
+                if client is None:
+                    self.dropped += 1
+                else:
+                    client.arrivals.put(True)
+        finally:
+            for c in self.clients:
+                c.arrivals.put(None)
+            for c in self.clients:
+                c.join()
+
+
+def role_threads(roles: list, first: int, seed: int, src: Source, port: int, logs: list,
+                 hold: threading.Event | None = None) -> list:
+    """The threads that drive the roles' clients, numbered from `first`
+    through the roles, client `first + i` appending to `logs[i]`: a
+    closed-loop Client a client, or for a role with `arrival_rate_per_s`
+    one Pacer over the role's PacedClients. Each is started after its
+    `stop_at` is set."""
+    out, n = [], first
+    for role in roles:
+        rate = role.get("arrival_rate_per_s")
+        kind = Client if rate is None else PacedClient
+        clients = [kind(n + i, seed, src, port, logs[n + i - first], hold, role)
+                   for i in range(role["clients"])]
+        out += clients if rate is None else [Pacer(clients, rate, hold)]
+        n += role["clients"]
+    return out
 
 
 def make_pool(traffic: dict, seed: int, spans: int, base_s: int) -> list:
